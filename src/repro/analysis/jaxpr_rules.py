@@ -250,21 +250,37 @@ _ORACLE_CANDIDATES = ("{base}_math", "{base}_ref", "{name}_math",
 
 def _kernel_entries(module) -> list[tuple[str, list[str]]]:
     """Public top-level functions of ``module`` that dispatch a
-    ``pallas_call``, with their positional parameter names (from the
-    source AST — robust to jit wrappers)."""
+    ``pallas_call`` — directly or through the module's private
+    (``_``-prefixed) helpers — with their positional parameter names
+    (from the source AST — robust to jit wrappers)."""
     src = inspect.getsource(module)
     tree = ast.parse(src)
+    funcs = {n.name: n for n in tree.body if isinstance(n, ast.FunctionDef)}
+
+    def callees(node):
+        for n in ast.walk(node):
+            if isinstance(n, ast.Call):
+                if isinstance(n.func, ast.Attribute):
+                    yield n.func.attr
+                elif isinstance(n.func, ast.Name):
+                    yield n.func.id
+
+    dispatching = {name for name, node in funcs.items()
+                   if "pallas_call" in set(callees(node))}
+    grew = True
+    while grew:                     # close over the private helpers
+        grew = False
+        for name, node in funcs.items():
+            if name not in dispatching and any(
+                    c.startswith("_") and c in dispatching
+                    for c in callees(node)):
+                dispatching.add(name)
+                grew = True
     out = []
-    for node in tree.body:
-        if not isinstance(node, ast.FunctionDef) or node.name.startswith("_"):
-            continue
-        calls_pallas = any(
-            isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
-            and n.func.attr == "pallas_call"
-            for n in ast.walk(node))
-        if calls_pallas:
+    for name, node in funcs.items():
+        if name in dispatching and not name.startswith("_"):
             pos = [a.arg for a in node.args.posonlyargs + node.args.args]
-            out.append((node.name, pos))
+            out.append((name, pos))
     return out
 
 

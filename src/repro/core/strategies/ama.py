@@ -78,9 +78,8 @@ class AMAStrategy(ServerStrategy):
 
     def reduced_server_update(self, t, prev_global, client_params, sched,
                               aux_state):
-        fl = self.fl
-        alpha = jnp.minimum(fl.alpha0 + fl.eta
-                            * jnp.asarray(t, jnp.float32), fl.alpha_cap)
+        from repro.kernels.server_plane import mix_coefs
         keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
-        return reduced_mix_update(prev_global, client_params, sched, keep,
-                                  alpha), aux_state
+        return reduced_mix_update(
+            prev_global, client_params, sched["data_sizes"], keep,
+            mix_coefs(self.fl, t)), aux_state

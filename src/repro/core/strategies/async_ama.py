@@ -71,14 +71,17 @@ class AsyncAMAStrategy(AMAStrategy):
             return self.aggregate(t, prev_global, client_params, sched,
                                   aux_state)
         from repro.kernels.server_plane import server_async_tree
-        fl = self.fl
-        hyp = jnp.asarray([fl.alpha0, fl.eta, fl.alpha_cap,
-                           fl.staleness_b], jnp.float32)
         new_global, queue = server_async_tree(
             prev_global, client_params, aux_state["queue"],
             sched["data_sizes"], sched["delayed"].astype(jnp.float32),
-            sched["delays"], t, hyp, impl=self.server_impl)
+            sched["delays"], t, self._hyp(), impl=self.server_impl)
         return new_global, {"queue": queue}
+
+    def _hyp(self):
+        """(4,) f32 = [alpha0, eta, alpha_cap, staleness_b]."""
+        fl = self.fl
+        return jnp.asarray([fl.alpha0, fl.eta, fl.alpha_cap,
+                            fl.staleness_b], jnp.float32)
 
     def reduced_server_update(self, t, prev_global, client_params, sched,
                               aux_state):
@@ -86,37 +89,21 @@ class AsyncAMAStrategy(AMAStrategy):
         pre-reduced: the on-time aggregate AND the Q ring-buffer enqueue
         sums are ONE (C, 1+Q) ``reduce_leading`` contraction, so the
         per-round collective moves (1+Q) x N bytes instead of C x N."""
-        from repro.kernels.ref import _norm_weights
+        from repro.kernels.ref import async_layout, server_async_coefs
         from repro.sharding.ctx import reduce_leading
-        fl = self.fl
         queue = aux_state["queue"]
-        Q = queue["gamma"].shape[0]
+        C, Q = sched["delays"].shape[0], queue["gamma"].shape[0]
         tt = jnp.asarray(t, jnp.int32)
-        delayed = sched["delayed"].astype(jnp.float32)
-        delays = sched["delays"]
-
-        alpha_un = 1.0 - jax.nn.sigmoid(1.0)                    # Eq. 9
-        g = (fl.staleness_b * jax.nn.sigmoid(-delays.astype(jnp.float32))
-             * delayed)                                         # gamma^-
-        arrival = (tt + delays) % Q
-        onehot = (arrival[:, None] == jnp.arange(Q)[None, :]
-                  ).astype(jnp.float32) * g[:, None]            # (C, Q)
-        qg = queue["gamma"] + jnp.sum(onehot, axis=0)
-        sel = (jnp.arange(Q) == tt % Q).astype(jnp.float32)     # pop mask
-        stale_gamma = jnp.sum(qg * sel)
-        new_qgamma = qg * (1.0 - sel)
-
-        A = jnp.minimum(fl.alpha0 + fl.eta * tt.astype(jnp.float32),
-                        fl.alpha_cap)
-        beta = 1.0 - A
-        denom = alpha_un + stale_gamma
-        alpha = alpha_un / denom * A                            # Eq. 10
-        gscale = A / denom                                      # Eq. 11
-        w, tot = _norm_weights(sched["data_sizes"], 1.0 - delayed)
-        a_eff = jnp.where(tot > 0, alpha, alpha + beta)
+        c, new_qgamma = server_async_coefs(
+            queue["gamma"], sched["data_sizes"],
+            sched["delayed"].astype(jnp.float32), sched["delays"],
+            jnp.stack([tt, tt % Q]), self._hyp())
+        o = async_layout(C, Q)
+        sel = c[o["sel"]:o["sel"] + Q]                          # pop mask
 
         # col 0: beta-weighted on-time aggregate; cols 1..Q: enqueue
-        W = jnp.concatenate([(beta * w)[:, None], onehot], axis=1)
+        W = jnp.concatenate([c[o["w"]:o["w"] + C, None],
+                             c[o["onehot"]:o["sel"]].reshape(C, Q)], axis=1)
         red = reduce_leading(client_params, W)        # leaves (1+Q, ...)
         rows = jax.tree.map(lambda qs, r: qs + r[1:], queue["sum"], red)
 
@@ -124,9 +111,9 @@ class AsyncAMAStrategy(AMAStrategy):
             return sel.reshape((Q,) + (1,) * (x.ndim - 1))
 
         new_params = jax.tree.map(
-            lambda p, r, rw: (p.astype(jnp.float32) * a_eff + r[0]
-                              + jnp.sum(rw * selb(rw), axis=0) * gscale
-                              ).astype(p.dtype),
+            lambda p, r, rw: (p.astype(jnp.float32) * c[0] + r[0]
+                              + jnp.sum(rw * selb(rw), axis=0)
+                              * c[o["gscale"]]).astype(p.dtype),
             prev_global, red, rows)
         new_qsum = jax.tree.map(lambda rw: rw * (1.0 - selb(rw)), rows)
         return new_params, {"queue": {"sum": new_qsum,

@@ -164,23 +164,21 @@ class ServerStrategy:
         return n_steps
 
 
-def reduced_mix_update(prev_global, client_params, sched, keep, alpha):
+def reduced_mix_update(prev_global, client_params, sizes, keep, coefs):
     """The mix-family server plane (``kernels.ref.server_mix_math``)
     with the client axis pre-reduced: out = a_eff*prev + sum_k
     (beta*w_k)*x_k, where the weighted sum is ONE ``reduce_leading``
-    contraction (an N-byte collective on a sharded mesh). Shared by
-    ama/fedavg/fedprox, which differ only in ``keep`` and the alpha
-    schedule."""
+    contraction (an N-byte collective on a sharded mesh). Same
+    arguments as ``server_mix_tree``; shared by ama/fedavg/fedprox,
+    which differ only in ``keep`` and the alpha schedule."""
     import jax
 
-    from repro.kernels.ref import _norm_weights
+    from repro.kernels.ref import server_mix_coefs
     from repro.sharding.ctx import reduce_leading
-    beta = 1.0 - alpha
-    w, tot = _norm_weights(sched["data_sizes"], keep)
-    a_eff = jnp.where(tot > 0, alpha, alpha + beta)
-    red = reduce_leading(client_params, beta * w)
+    c = server_mix_coefs(sizes, keep, coefs)        # [a_eff, beta*w]
+    red = reduce_leading(client_params, c[1:])
     return jax.tree.map(
-        lambda p, r: (p.astype(jnp.float32) * a_eff + r).astype(p.dtype),
+        lambda p, r: (p.astype(jnp.float32) * c[0] + r).astype(p.dtype),
         prev_global, red)
 
 

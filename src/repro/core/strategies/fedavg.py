@@ -57,10 +57,10 @@ class FedAvgStrategy(ServerStrategy):
 
     def reduced_server_update(self, t, prev_global, client_params, sched,
                               aux_state):
-        del t
+        from repro.kernels.server_plane import mix_coefs
         keep = jnp.logical_and(
             jnp.logical_not(sched["delayed"]),
             jnp.logical_not(sched["limited"])).astype(jnp.float32)
-        # alpha = 0: the plain weighted average corner of the mix plane
-        return reduced_mix_update(prev_global, client_params, sched, keep,
-                                  jnp.float32(0.0)), aux_state
+        return reduced_mix_update(
+            prev_global, client_params, sched["data_sizes"], keep,
+            mix_coefs(self.fl, t, adaptive=False)), aux_state

@@ -93,18 +93,22 @@ class FedOptStrategy(AMAStrategy):
             return self.aggregate(t, prev_global, client_params, sched,
                                   aux_state)
         from repro.kernels.server_plane import server_adam_tree
-        fl = self.fl
         keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
         step = aux_state["step"] + 1
-        scalars = jnp.stack([jnp.float32(fl.server_b1),
-                             jnp.float32(fl.server_b2),
-                             jnp.float32(fl.server_lr),
-                             jnp.float32(fl.server_tau),
-                             step.astype(jnp.float32)])
         new_global, m, v = server_adam_tree(
             prev_global, client_params, aux_state["m"], aux_state["v"],
-            sched["data_sizes"], keep, scalars, impl=self.server_impl)
+            sched["data_sizes"], keep, self._scalars(step),
+            impl=self.server_impl)
         return new_global, {"m": m, "v": v, "step": step}
+
+    def _scalars(self, step):
+        """(5,) f32 = [b1, b2, lr, tau, step] (step already incremented)."""
+        fl = self.fl
+        return jnp.stack([jnp.float32(fl.server_b1),
+                          jnp.float32(fl.server_b2),
+                          jnp.float32(fl.server_lr),
+                          jnp.float32(fl.server_tau),
+                          step.astype(jnp.float32)])
 
     def reduced_server_update(self, t, prev_global, client_params, sched,
                               aux_state):
@@ -112,32 +116,18 @@ class FedOptStrategy(AMAStrategy):
         aggregate pre-reduced over the client axis (one N-byte
         contraction); the Adam moment update is elementwise on (N,)."""
         del t
-        from repro.kernels.ref import _norm_weights
+        from repro.kernels.ref import adam_update, server_adam_coefs
         from repro.sharding.ctx import reduce_leading
-        fl = self.fl
         keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
-        w, tot = _norm_weights(sched["data_sizes"], keep)
-        agg = reduce_leading(client_params, w)
         step = aux_state["step"] + 1
-        sf = step.astype(jnp.float32)
-        bc1 = 1.0 - fl.server_b1 ** sf
-        bc2 = 1.0 - fl.server_b2 ** sf
-
-        def delta(p, a):
-            return jnp.where(tot > 0, a - p.astype(jnp.float32), 0.0)
-
-        m = jax.tree.map(
-            lambda mm, p, a: fl.server_b1 * mm
-            + (1.0 - fl.server_b1) * delta(p, a),
-            aux_state["m"], prev_global, agg)
-        v = jax.tree.map(
-            lambda vv, p, a: fl.server_b2 * vv
-            + (1.0 - fl.server_b2) * delta(p, a) ** 2,
-            aux_state["v"], prev_global, agg)
-        new_params = jax.tree.map(
-            lambda p, mm, vv: (p.astype(jnp.float32) + fl.server_lr
-                               * (mm / bc1)
-                               / (jnp.sqrt(vv / bc2) + fl.server_tau)
-                               ).astype(p.dtype),
-            prev_global, m, v)
+        c = server_adam_coefs(sched["data_sizes"], keep,
+                              self._scalars(step))
+        C = keep.shape[0]
+        agg = reduce_leading(client_params, c[:C])
+        leaves, treedef = jax.tree.flatten(prev_global)
+        outs = [adam_update(p, a, mm, vv, c, C) for p, a, mm, vv in zip(
+            leaves, jax.tree.leaves(agg), jax.tree.leaves(aux_state["m"]),
+            jax.tree.leaves(aux_state["v"]))]
+        new_params, m, v = (treedef.unflatten([o[i] for o in outs])
+                            for i in range(3))
         return new_params, {"m": m, "v": v, "step": step}

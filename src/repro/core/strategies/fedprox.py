@@ -70,7 +70,8 @@ class FedProxStrategy(ServerStrategy):
 
     def reduced_server_update(self, t, prev_global, client_params, sched,
                               aux_state):
-        del t
+        from repro.kernels.server_plane import mix_coefs
         keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
-        return reduced_mix_update(prev_global, client_params, sched, keep,
-                                  jnp.float32(0.0)), aux_state
+        return reduced_mix_update(
+            prev_global, client_params, sched["data_sizes"], keep,
+            mix_coefs(self.fl, t, adaptive=False)), aux_state

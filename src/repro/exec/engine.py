@@ -81,6 +81,14 @@ class History:
                                last)["final_accuracy"]
 
 
+def _shape_of(x):
+    """ShapeDtypeStruct of an argument; a committed array keeps its
+    sharding, an uncommitted one is placed by the program."""
+    x = jnp.asarray(x)
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+
+
 class ChunkRunner:
     """The unified round path: N rounds per call, fused scan or fallback.
 
@@ -114,6 +122,7 @@ class ChunkRunner:
         # book under "scan_dispatch" / "round_dispatch"
         self.timer = timer if timer is not None else PhaseTimes()
         self._compiled: set = set()
+        self._last_call = None      # argument shapes of the last dispatch
 
     def _dispatch(self, loop, state, batch, scheds, n: int, *,
                   scan: bool):
@@ -124,6 +133,7 @@ class ChunkRunner:
         self._compiled.add(key)
         with self.timer.phase(phase) as span, \
                 annotate(f"train_chunk_n{n}"):
+            args = (state, batch, scheds)
             if getattr(self.fl, "extended_metrics", False):
                 # extended telemetry: the loop takes a shadow tap — a
                 # device COPY of the entering {params, aux} (separate
@@ -131,17 +141,23 @@ class ChunkRunner:
                 # value-numbering the tap onto the live carry; see
                 # make_train_loop). The copy is O(model), once per
                 # dispatch — noise next to the chunk's training work.
-                tap0 = jax.tree.map(jnp.copy, {"params": state["params"],
-                                               "aux": state["aux"]})
-                out = loop(state, batch, scheds, tap0)
-            else:
-                out = loop(state, batch, scheds)
+                args += (jax.tree.map(jnp.copy, {"params": state["params"],
+                                                 "aux": state["aux"]}),)
+            self._last_call = jax.tree.map(_shape_of, args)
+            out = loop(*args)
             span.sync(out)
         return out
 
+    def lower_last(self):
+        """The program of the last dispatched chunk, lowered again from
+        the argument shapes it ran with: ``.compile().as_text()`` shows
+        which kernels and collectives a round really runs."""
+        with self._ctx():
+            return self._train_loop().lower(*self._last_call)
+
     def _ctx(self):
-        return self.mesh if self.mesh is not None else (
-            contextlib.nullcontext())
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
 
     def _train_loop(self):
         if self._loop is None:

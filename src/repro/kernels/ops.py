@@ -1,7 +1,9 @@
 """jit'd wrappers: the public kernel API used by the rest of the framework.
 
-On CPU (this container) every wrapper runs the Pallas kernel in interpret
-mode or falls back to the ref — the TPU path is the pallas_call itself.
+``ama_mix_tree`` / ``ama_mix_pairwise`` (the legacy per-leaf mix) run the
+Pallas kernel compiled on a TPU and in the Pallas interpreter elsewhere.
+The fused server plane picks its path itself (``kernels.server_plane``):
+the kernel on a TPU, the jitted jnp oracle off it.
 """
 from __future__ import annotations
 

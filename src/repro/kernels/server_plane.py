@@ -7,37 +7,48 @@ environment has delays), and the optional FedOpt server-Adam moment
 update — is purely HBM-bandwidth-bound at LLM scale. Before this module
 each stage was a separate jnp pass materialising (N,)/(K, N)/(Q, N)
 intermediates; here each round is ONE ``pl.pallas_call`` over a 1-D grid
-of flat parameter tiles:
+of parameter tiles:
 
-  * ``server_mix_flat``   — sync plane (ama / fedavg / fedprox):
-        streams K+1 rows in, 1 out; weights + alpha schedule in-kernel.
-  * ``server_async_flat`` — async plane (async_ama, Eqs. 6-11):
-        streams K+Q+1 rows in, Q+1 out; gamma^-(delays), ring-buffer
-        enqueue, slot pop and the alpha/beta/gamma mix fused.
-  * ``server_adam_flat``  — FedOpt server-Adam:
+  * ``server_mix_flat``       — sync plane (ama / fedavg / fedprox):
+        streams K+1 rows in, 1 out;
+  * ``server_mix_delta_flat`` — the same plane on compressed client
+        deltas (int8 / bf16 rows upcast inside the tile; the top-k
+        uplink is densified into it by ``server_mix_compressed_tree``);
+  * ``server_async_flat``     — async plane (async_ama, Eqs. 6-11):
+        streams K+Q+1 rows in, Q+1 out; ring-buffer enqueue, slot pop
+        and the alpha/beta/gamma mix fused;
+  * ``server_adam_flat``      — FedOpt server-Adam:
         streams K+3 rows in, 3 out; pseudo-gradient, moments and the
         model step fused.
 
-Each kernel body calls the SAME math as the pure-jnp oracle
-(``kernels/ref.py: server_*_math``), so interpret mode matches the
-reference to within 1-2 ulp (bit-exact up to XLA's shape-dependent
-multiply-add contraction); compiled TPU mode is allclose. The
-``server_*_tree`` drivers flatten a whole param pytree to one vector per
-dtype group (bf16 and f32 leaves keep their dtypes), so the engine
-dispatches ONE fused pass per round per dtype group instead of a chain
-of per-leaf jnp ops.
+Each wrapper computes the plane's O(K·Q) scalar half
+(``kernels/ref.py: server_*_coefs``) in XLA and passes it to the kernel
+as one f32 vector in SMEM; the kernel body runs the plane's O(N) half
+(``ref.mix_apply`` / ``async_apply`` / ``adam_apply``) on its tile —
+the SAME functions the jnp oracle runs on whole arrays, so interpret
+mode matches the oracle to within 1-2 ulp and compiled TPU mode is
+allclose. The ``server_*_tree`` drivers flatten a whole param pytree to
+one vector per dtype group (bf16 and f32 leaves keep their dtypes), so
+the engine dispatches ONE fused pass per round per dtype group.
 
-Dispatch policy (``impl`` below / ``fl.server_plane``): the Pallas
+Dispatch policy (``_route`` / ``fl.server_plane``): the Pallas
 pallas_call is the TPU lowering; OFF-TPU the "fused" impl runs the
 jitted flat oracle instead — XLA CPU fuses the whole flat op sequence
-into one pass, which is where the measured CPU win comes from
-(BENCH_server_plane.json), while the Pallas INTERPRETER is a pure
-emulation layer that is orders of magnitude slower and exists only to
-validate the kernel body (impl="interpret", CI parity tests).
+into one pass, while the Pallas INTERPRETER is a pure emulation layer
+that is orders of magnitude slower and exists only to validate the
+kernel body (impl="interpret", CI parity tests).
 
-Block sizing (TPU/interpret path): tiles are (block,) flat lanes;
-``(K + Q + 2) * block * 4`` bytes must fit VMEM on TPU (~16 MB) —
-128k lanes keeps K=10, Q=16 under that budget.
+Layout and block size: a flat (N,) vector is viewed as (R, 128) lane
+rows (zero-padded when 128 does not divide N) and a stacked (K, N)
+operand as (K, R, 128), so the client and ring axes are leading block
+dims that need no sublane padding. A grid step streams ``block_rows``
+lane rows of every operand. ``_block_rows`` derives that count from
+``VMEM_BUDGET``: for each operand streamed in or out, its rows times
+128 lanes times its itemsize, times 2 for the pipeline's double
+buffering, plus the f32 temporaries the body keeps live (accumulators,
+upcast rows, the Q ring rows of the async plane); the count is rounded
+down to the largest sublane tile among the operands (8 rows f32, 16
+bf16, 32 int8), and a block holding every row is the whole array.
 """
 from __future__ import annotations
 
@@ -46,15 +57,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 
-DEFAULT_BLOCK = 128 * 1024
+LANES = 128
+#: bytes of VMEM the double-buffered tiles and live temporaries of one
+#: grid step may take: half of the 16 MiB scoped-VMEM default of a v5e
+#: TensorCore, leaving the rest to Mosaic's own scratch, so no kernel
+#: raises the scoped limit
+VMEM_BUDGET = 8 * 1024 * 1024
 
 __all__ = ["server_mix_flat", "server_async_flat", "server_adam_flat",
-           "server_mix_delta_flat", "server_mix_scatter_flat",
-           "server_mix_tree", "server_async_tree", "server_adam_tree",
-           "server_mix_compressed_tree", "mix_coefs", "DEFAULT_BLOCK"]
+           "server_mix_delta_flat", "server_mix_tree", "server_async_tree",
+           "server_adam_tree", "server_mix_compressed_tree", "mix_coefs",
+           "VMEM_BUDGET"]
 
 
 def _interpret_default() -> bool:
@@ -102,244 +119,174 @@ def mix_coefs(fl, t, *, adaptive: bool = True):
 
 
 # ---------------------------------------------------------------------------
-# kernel bodies: load the tile, run the SHARED oracle math, store
+# tiling: (N,) -> (R, 128) lane rows, block rows from the VMEM budget
 # ---------------------------------------------------------------------------
 
-def _mix_kernel(prev_ref, stacked_ref, sizes_ref, keep_ref, coefs_ref,
-                out_ref):
-    out_ref[...] = ref.server_mix_math(
-        prev_ref[...], stacked_ref[...], sizes_ref[...], keep_ref[...],
-        coefs_ref[...])
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane, 128) VMEM tile: 8 f32, 16 bf16, 32 int8."""
+    return 32 // jnp.dtype(dtype).itemsize
 
 
-def _mix_delta_kernel(prev_ref, dstacked_ref, rowscale_ref, sizes_ref,
-                      keep_ref, coefs_ref, out_ref):
-    out_ref[...] = ref.server_mix_delta_math(
-        prev_ref[...], dstacked_ref[...], rowscale_ref[...], sizes_ref[...],
-        keep_ref[...], coefs_ref[...])
+def _block_rows(R: int, streams, temps: int, block: int | None) -> int:
+    """Lane rows per grid step (see the module docstring).
+
+    ``streams``: (rows, dtype) for every operand streamed in or out per
+    lane row — (K, stacked.dtype) for the client rows. ``temps``: f32
+    lane rows the body keeps live. ``block`` (elements, a multiple of
+    128) overrides the budget, for tests that exercise many tiles on a
+    small N."""
+    if block is not None:
+        br = max(1, block // LANES)
+    else:
+        tile = max(_sublanes(d) for _, d in streams)
+        per_row = LANES * (2 * sum(n * jnp.dtype(d).itemsize
+                                   for n, d in streams) + 4 * temps)
+        br = max(tile, VMEM_BUDGET // per_row // tile * tile)
+    return min(br, R)
 
 
-def _mix_scatter_kernel(block, prev_ref, vals_ref, idx_ref, sizes_ref,
-                        keep_ref, coefs_ref, out_ref):
-    # the tile's global offset: positions outside [start, start+block)
-    # are masked inside the shared math, so the scatter composes with
-    # the 1-D tiling exactly like the dense accumulation does
-    start = pl.program_id(0) * block
-    out_ref[...] = ref.server_mix_scatter_math(
-        prev_ref[...], vals_ref[...], idx_ref[...], sizes_ref[...],
-        keep_ref[...], coefs_ref[...], start=start)
+def _lanes(x):
+    """(..., N) -> (..., R, 128), zero-padding N to a multiple of 128."""
+    pad = (-x.shape[-1]) % LANES
+    if pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(x.shape[:-1] + (-1, LANES))
 
 
-def _async_kernel(prev_ref, stacked_ref, qsum_ref, qgamma_ref, sizes_ref,
-                  delayed_ref, delays_ref, tq_ref, hyp_ref,
-                  out_ref, qsum_out_ref, qgamma_out_ref):
-    out, new_qsum, new_qgamma = ref.server_async_math(
-        prev_ref[...], stacked_ref[...], qsum_ref[...], qgamma_ref[...],
-        sizes_ref[...], delayed_ref[...], delays_ref[...], tq_ref[...],
-        hyp_ref[...])
+def _unlanes(x, N: int):
+    """Inverse of ``_lanes``."""
+    x = x.reshape(x.shape[:-2] + (-1,))
+    return x[..., :N] if x.shape[-1] != N else x
+
+
+def _rows(br: int, lead: int = 0):
+    """BlockSpec of ``br`` lane rows (behind ``lead`` whole rows)."""
+    if lead:
+        return pl.BlockSpec((lead, br, LANES), lambda i: (0, i, 0))
+    return pl.BlockSpec((br, LANES), lambda i: (i, 0))
+
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _pallas(kernel, R, br, in_specs, out_specs, out_shape, interpret):
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(R, br),), in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# kernel bodies: the shared O(N) half on one tile; coefficients in SMEM
+# ---------------------------------------------------------------------------
+
+def _mix_kernel(c_ref, prev_ref, rows_ref, out_ref):
+    out_ref[...] = ref.mix_apply(prev_ref[...], rows_ref, c_ref)
+
+
+def _async_kernel(c_ref, prev_ref, rows_ref, qrows_ref, out_ref,
+                  qout_ref):
+    out, ring = ref.async_apply(prev_ref[...], rows_ref, qrows_ref, c_ref)
     out_ref[...] = out
-    qsum_out_ref[...] = new_qsum
-    qgamma_out_ref[...] = new_qgamma
+    for q, row in enumerate(ring):
+        qout_ref[q] = row
 
 
-def _adam_kernel(prev_ref, stacked_ref, m_ref, v_ref, sizes_ref, keep_ref,
-                 scalars_ref, out_ref, m_out_ref, v_out_ref):
-    out, new_m, new_v = ref.server_adam_math(
-        prev_ref[...], stacked_ref[...], m_ref[...], v_ref[...],
-        sizes_ref[...], keep_ref[...], scalars_ref[...])
+def _adam_kernel(c_ref, prev_ref, rows_ref, m_ref, v_ref, out_ref,
+                 m_out_ref, v_out_ref):
+    out, new_m, new_v = ref.adam_apply(prev_ref[...], rows_ref, m_ref[...],
+                                       v_ref[...], c_ref)
     out_ref[...] = out
     m_out_ref[...] = new_m
     v_out_ref[...] = new_v
 
 
 # ---------------------------------------------------------------------------
-# flat wrappers: pad to the tile grid, one pallas_call, slice back
+# flat wrappers: coefficients in XLA, one pallas_call over lane rows
 # ---------------------------------------------------------------------------
 
-def _grid(N: int, block: int) -> tuple[int, int, int]:
-    block = min(block, N)
-    pad = (-N) % block
-    return block, pad, (N + pad) // block
+def _mix_pass(prev, rows, c, block, interpret):
+    """out = prev*c[0] + sum_k rows[k]*c[k+1] as one kernel pass."""
+    N, K = prev.shape[0], rows.shape[0]
+    p, r = _lanes(prev), _lanes(rows)
+    R = p.shape[0]
+    br = _block_rows(R, [(2, prev.dtype), (K, rows.dtype)], 3, block)
+    out = _pallas(_mix_kernel, R, br, [_SMEM, _rows(br), _rows(br, K)],
+                  _rows(br), jax.ShapeDtypeStruct(p.shape, p.dtype),
+                  interpret)(c, p, r)
+    return _unlanes(out, N)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def server_mix_flat(prev, stacked, sizes, keep, coefs, *,
-                    block: int = DEFAULT_BLOCK, interpret: bool = False):
+                    block: int | None = None, interpret: bool = False):
     """prev: (N,); stacked: (K, N); sizes/keep: (K,) f32; coefs: (4,)."""
-    (N,) = prev.shape
-    K = stacked.shape[0]
-    block, pad, n_blocks = _grid(N, block)
-    if pad:
-        prev = jnp.pad(prev, (0, pad))
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-    out = pl.pallas_call(
-        _mix_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((K, block), lambda i: (0, i)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(prev.shape, prev.dtype),
-        interpret=interpret,
-    )(prev, stacked, sizes, keep, coefs)
-    return out[:N] if pad else out
+    return _mix_pass(prev, stacked, ref.server_mix_coefs(sizes, keep, coefs),
+                     block, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def server_mix_delta_flat(prev, dstacked, rowscale, sizes, keep, coefs, *,
-                          block: int = DEFAULT_BLOCK,
+                          block: int | None = None,
                           interpret: bool = False):
     """Compressed-uplink sync plane: prev (N,); dstacked (K, N) quantized
     deltas (int8 / bf16 / f32); rowscale (K,) f32 dequantization scales;
     sizes/keep (K,) f32; coefs (4,). Dequantize-accumulate fused: the
     int8/bf16 rows upcast INSIDE the kernel tile, so the server's HBM
     pass streams the compressed bytes, not a dense f32 copy."""
-    (N,) = prev.shape
-    K = dstacked.shape[0]
-    block, pad, n_blocks = _grid(N, block)
-    if pad:
-        prev = jnp.pad(prev, (0, pad))
-        dstacked = jnp.pad(dstacked, ((0, 0), (0, pad)))
-    out = pl.pallas_call(
-        _mix_delta_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((K, block), lambda i: (0, i)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(prev.shape, prev.dtype),
-        interpret=interpret,
-    )(prev, dstacked, rowscale, sizes, keep, coefs)
-    return out[:N] if pad else out
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def server_mix_scatter_flat(prev, vals, idx, sizes, keep, coefs, *,
-                            block: int = DEFAULT_BLOCK,
-                            interpret: bool = False):
-    """Top-k sparsified sync plane: prev (N,); vals (K, kk) f32 surviving
-    delta values at GLOBAL flat positions idx (K, kk) int32; sizes/keep
-    (K,) f32; coefs (4,). Every tile sees the full (K, kk) coordinate
-    list (kk << N) and scatters only the in-tile positions."""
-    (N,) = prev.shape
-    K, kk = vals.shape
-    block, pad, n_blocks = _grid(N, block)
-    if pad:
-        prev = jnp.pad(prev, (0, pad))
-    out = pl.pallas_call(
-        functools.partial(_mix_scatter_kernel, block),
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((K, kk), lambda i: (0, 0)),
-            pl.BlockSpec((K, kk), lambda i: (0, 0)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(prev.shape, prev.dtype),
-        interpret=interpret,
-    )(prev, vals, idx, sizes, keep, coefs)
-    return out[:N] if pad else out
+    return _mix_pass(prev, dstacked,
+                     ref.server_mix_delta_coefs(rowscale, sizes, keep,
+                                                coefs),
+                     block, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def server_async_flat(prev, stacked, qsum, qgamma, sizes, delayed, delays,
-                      tq, hyp, *, block: int = DEFAULT_BLOCK,
+                      tq, hyp, *, block: int | None = None,
                       interpret: bool = False):
     """prev: (N,); stacked: (K, N); qsum: (Q, N) f32; qgamma: (Q,) f32;
     sizes/delayed: (K,) f32; delays: (K,) i32; tq: (2,) i32 = [t, t % Q];
     hyp: (4,) f32 = [alpha0, eta, alpha_cap, staleness_b].
     Returns (out (N,), new_qsum (Q, N) f32, new_qgamma (Q,) f32)."""
-    (N,) = prev.shape
-    K, Q = stacked.shape[0], qgamma.shape[0]
-    block, pad, n_blocks = _grid(N, block)
-    if pad:
-        prev = jnp.pad(prev, (0, pad))
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-        qsum = jnp.pad(qsum, ((0, 0), (0, pad)))
-    out, new_qsum, new_qgamma = pl.pallas_call(
-        _async_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((K, block), lambda i: (0, i)),
-            pl.BlockSpec((Q, block), lambda i: (0, i)),
-            pl.BlockSpec((Q,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((Q, block), lambda i: (0, i)),
-            pl.BlockSpec((Q,), lambda i: (0,)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(prev.shape, prev.dtype),
-            jax.ShapeDtypeStruct(qsum.shape, jnp.float32),
-            jax.ShapeDtypeStruct((Q,), jnp.float32),
-        ),
-        interpret=interpret,
-    )(prev, stacked, qsum, qgamma, sizes, delayed, delays, tq, hyp)
-    if pad:
-        return out[:N], new_qsum[:, :N], new_qgamma
-    return out, new_qsum, new_qgamma
+    N, K, Q = prev.shape[0], stacked.shape[0], qgamma.shape[0]
+    c, new_qgamma = ref.server_async_coefs(qgamma, sizes, delayed, delays,
+                                           tq, hyp)
+    p, r, q = _lanes(prev), _lanes(stacked), _lanes(qsum)
+    R = p.shape[0]
+    br = _block_rows(R, [(2, prev.dtype), (K, stacked.dtype),
+                         (2 * Q, jnp.float32)], Q + 3, block)
+    out, new_q = _pallas(
+        _async_kernel, R, br,
+        [_SMEM, _rows(br), _rows(br, K), _rows(br, Q)],
+        (_rows(br), _rows(br, Q)),
+        (jax.ShapeDtypeStruct(p.shape, p.dtype),
+         jax.ShapeDtypeStruct(q.shape, jnp.float32)),
+        interpret)(c, p, r, q)
+    return _unlanes(out, N), _unlanes(new_q, N), new_qgamma
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def server_adam_flat(prev, stacked, m, v, sizes, keep, scalars, *,
-                     block: int = DEFAULT_BLOCK, interpret: bool = False):
+                     block: int | None = None, interpret: bool = False):
     """prev: (N,); stacked: (K, N); m/v: (N,) f32; sizes/keep: (K,) f32;
     scalars: (5,) f32 = [b1, b2, lr, tau, step] (step pre-incremented).
     Returns (out (N,), new_m (N,) f32, new_v (N,) f32)."""
-    (N,) = prev.shape
-    K = stacked.shape[0]
-    block, pad, n_blocks = _grid(N, block)
-    if pad:
-        prev = jnp.pad(prev, (0, pad))
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-        m = jnp.pad(m, (0, pad))
-        v = jnp.pad(v, (0, pad))
-    out, new_m, new_v = pl.pallas_call(
-        _adam_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((K, block), lambda i: (0, i)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((5,), lambda i: (0,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(prev.shape, prev.dtype),
-            jax.ShapeDtypeStruct(prev.shape, jnp.float32),
-            jax.ShapeDtypeStruct(prev.shape, jnp.float32),
-        ),
-        interpret=interpret,
-    )(prev, stacked, m, v, sizes, keep, scalars)
-    if pad:
-        return out[:N], new_m[:N], new_v[:N]
-    return out, new_m, new_v
+    N, K = prev.shape[0], stacked.shape[0]
+    c = ref.server_adam_coefs(sizes, keep, scalars)
+    p, r, lm, lv = _lanes(prev), _lanes(stacked), _lanes(m), _lanes(v)
+    R = p.shape[0]
+    br = _block_rows(R, [(2, prev.dtype), (K, stacked.dtype),
+                         (4, jnp.float32)], 7, block)
+    f32_rows = jax.ShapeDtypeStruct(p.shape, jnp.float32)
+    out, new_m, new_v = _pallas(
+        _adam_kernel, R, br,
+        [_SMEM, _rows(br), _rows(br, K), _rows(br), _rows(br)],
+        (_rows(br), _rows(br), _rows(br)),
+        (jax.ShapeDtypeStruct(p.shape, p.dtype), f32_rows, f32_rows),
+        interpret)(c, p, r, lm, lv)
+    return _unlanes(out, N), _unlanes(new_m, N), _unlanes(new_v, N)
 
 
 # ---------------------------------------------------------------------------
@@ -377,7 +324,7 @@ def _co_leaves(tree, treedef):
 
 
 def server_mix_tree(prev, stacked, sizes, keep, coefs, *, impl: str = "fused",
-                    block: int = DEFAULT_BLOCK):
+                    block: int | None = None):
     """Sync server plane over pytrees. ``stacked`` leaves carry a leading
     client axis. ``impl``: see ``_route``.
 
@@ -410,9 +357,9 @@ def server_mix_tree(prev, stacked, sizes, keep, coefs, *, impl: str = "fused",
 
 def server_mix_compressed_tree(prev, groups, sizes, keep, coefs, *,
                                impl: str = "fused",
-                               block: int = DEFAULT_BLOCK):
-    """Sync server plane consuming compressed client deltas directly —
-    the fused dequantize-accumulate dispatch behind the mix family's
+                               block: int | None = None):
+    """Sync server plane consuming compressed client deltas — the fused
+    dequantize-accumulate dispatch behind the mix family's
     ``ServerStrategy.compressed_server_update``.
 
     ``groups`` is the flat per-dtype-group payload list a
@@ -423,36 +370,34 @@ def server_mix_compressed_tree(prev, groups, sizes, keep, coefs, *,
     "i": (K, kk) int32}`` (top-k sparsification). The leaf grouping is
     the SAME ``_dtype_groups(prev leaves)`` split the dense tree
     drivers use, so one kernel call per round per group consumes the
-    compressed bytes with no dense intermediate."""
+    compressed bytes. A top-k payload is first densified into a (K, N)
+    f32 delta (an XLA scatter) and then takes the delta plane on every
+    backend: the Pallas TPU lowering has no scatter-add."""
+    from repro.comm.plane import decode     # comm.plane imports this module
     kernel, interpret = _route(impl)
     leaves_p, treedef = jax.tree.flatten(prev)
     out_leaves = [None] * len(leaves_p)
     for idxs, payload in groups:
         fp = _cat([leaves_p[i].reshape(-1) for i in idxs])
         if payload["kind"] == "topk":
-            if kernel:
-                of = server_mix_scatter_flat(
-                    fp, payload["v"], payload["i"], sizes, keep, coefs,
-                    block=block, interpret=interpret)
-            else:
-                of = ref.server_mix_scatter_math(
-                    fp, payload["v"], payload["i"], sizes, keep, coefs)
-        elif payload["kind"] == "delta":
-            if kernel:
-                of = server_mix_delta_flat(
-                    fp, payload["d"], payload["scale"], sizes, keep, coefs,
-                    block=block, interpret=interpret)
-            else:
-                of = ref.server_mix_delta_math(
-                    fp, payload["d"], payload["scale"], sizes, keep, coefs)
-        else:
+            payload = {"kind": "delta", "d": decode(payload, fp.shape[0]),
+                       "scale": jnp.ones(payload["v"].shape[:1],
+                                         jnp.float32)}
+        if payload["kind"] != "delta":
             raise ValueError(f"unknown payload kind {payload['kind']!r}")
+        if kernel:
+            of = server_mix_delta_flat(
+                fp, payload["d"], payload["scale"], sizes, keep, coefs,
+                block=block, interpret=interpret)
+        else:
+            of = ref.server_mix_delta_math(
+                fp, payload["d"], payload["scale"], sizes, keep, coefs)
         _split_back(of, leaves_p, idxs, out_leaves)
     return treedef.unflatten(out_leaves)
 
 
 def server_async_tree(prev, stacked, queue, sizes, delayed, delays, t, hyp,
-                      *, impl: str = "fused", block: int = DEFAULT_BLOCK):
+                      *, impl: str = "fused", block: int | None = None):
     """Async server plane over pytrees: one fused enqueue+pop+mix per
     round. ``queue`` = {"sum": pytree with leading (Q,), "gamma": (Q,)}.
     Returns (new_global, new_queue)."""
@@ -490,7 +435,7 @@ def server_async_tree(prev, stacked, queue, sizes, delayed, delays, t, hyp,
 
 
 def server_adam_tree(prev, stacked, m, v, sizes, keep, scalars, *,
-                     impl: str = "fused", block: int = DEFAULT_BLOCK):
+                     impl: str = "fused", block: int | None = None):
     """FedOpt server plane over pytrees. ``m``/``v`` are f32 trees shaped
     like ``prev``. Returns (new_global, new_m, new_v)."""
     kernel, interpret = _route(impl)

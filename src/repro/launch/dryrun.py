@@ -111,7 +111,7 @@ def train_lowering(cfg: ModelConfig, shape: ShapeConfig, mesh, fl: FLConfig):
     step = make_train_step_for_lowering(model, fl)
     jitted = jax.jit(step, in_shardings=in_shardings,
                      out_shardings=(p_sh, None))
-    with fmesh:
+    with jax.set_mesh(fmesh):
         lowered = jitted.lower(params_like, t_like, batch, sched)
     return lowered
 
@@ -126,7 +126,7 @@ def prefill_lowering(cfg: ModelConfig, shape: ShapeConfig, mesh):
 
     jitted = jax.jit(model.prefill_logits, in_shardings=(p_sh, b_sh),
                      out_shardings=None)
-    with smesh:
+    with jax.set_mesh(smesh):
         lowered = jitted.lower(params_like, batch)
     return lowered
 
@@ -144,7 +144,7 @@ def decode_lowering(cfg: ModelConfig, shape: ShapeConfig, mesh):
     jitted = jax.jit(model.decode_step,
                      in_shardings=(p_sh, tok_sh, pos_sh, c_sh),
                      out_shardings=(None, c_sh))
-    with smesh:
+    with jax.set_mesh(smesh):
         lowered = jitted.lower(params_like, ins["token"], ins["position"],
                                ins["cache"])
     return lowered

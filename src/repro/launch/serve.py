@@ -35,6 +35,7 @@ import numpy as np
 from repro.checkpoint.io import restore_params
 from repro.configs.base import reduced
 from repro.configs.registry import serving_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.obs.timing import profile_trace, sync_time
 from repro.serve import LoopEngine, PagedEngine, Request
@@ -107,7 +108,7 @@ def build_engine(model, params, args):
     return LoopEngine(model, params, prefill_chunk=args.prefill_chunk)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minitron-8b")
     ap.add_argument("--reduced", action="store_true")
@@ -132,13 +133,17 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None, metavar="JSONL")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="wrap serving in jax.profiler.trace(DIR)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build_workload(args, seed: int = 0):
+    """(model, params, requests) from parsed ``build_parser`` arguments;
+    random weights and prompts are drawn from ``seed``."""
     cfg = serving_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(seed))
     if args.checkpoint:
         # accepts bare params files AND the {params, t, aux} round-state
         # files the trainer's --checkpoint writes (params subtree sliced)
@@ -149,11 +154,17 @@ def main(argv=None):
         reqs = _trace_requests(args.trace, args.tokens, cfg.vocab_size)
     elif args.prompt_mix:
         reqs = _mixture_requests(args.prompt_mix, args.tokens,
-                                 cfg.vocab_size)
+                                 cfg.vocab_size, seed)
     else:
         reqs = _mixture_requests(f"{args.prompt_len}x{args.batch}",
-                                 args.tokens, cfg.vocab_size)
+                                 args.tokens, cfg.vocab_size, seed)
+    return model, params, reqs
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    model, params, reqs = build_workload(args)
     engine = build_engine(model, params, args)
     with profile_trace(args.profile):
         dt, results = sync_time(engine.run, reqs)

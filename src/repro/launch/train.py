@@ -11,9 +11,9 @@ Two configurations of ONE execution engine (``repro.exec``):
 
 ``--no-scan`` falls back to the bit-identical per-round-jit loop at
 either scale (the configuration the engine benchmarks compare against).
-Both scales run under ``launch.mesh.engine_mesh``: on this CPU container
-that is a degenerate (1, 1, 1) mesh; on a v5e pod the identical program
-spans 256 chips with the stacked client axis sharded.
+Both scales run under ``launch.mesh.engine_mesh``: on one device that is
+a degenerate (1, 1, 1) mesh; on a four-chip v5e host the identical
+program shards the stacked client axis over the chips.
 
 ``--checkpoint`` saves and ``--resume`` restores the FULL round state
 {params, t, aux} (async ring buffer, fedopt moments), so continuation
@@ -61,6 +61,7 @@ from repro.data.pipeline import VirtualClientShards, build_clients
 from repro.env.virtual import is_virtual
 from repro.data.synth import make_image_classification, make_lm_tokens
 from repro.exec import ChunkRunner
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import engine_mesh
 from repro.models.api import build_model
 from repro.obs.log import MetricsLogger
@@ -117,7 +118,7 @@ def paper_scale(args, fl: FLConfig):
         logger.close()
         print(f"metrics -> {args.metrics_out} "
               f"(python -m repro.obs.report {args.metrics_out})")
-    return hist
+    return sim, hist
 
 
 def _pod_batch(cfg, fl: FLConfig, args):
@@ -137,7 +138,9 @@ def _pod_batch(cfg, fl: FLConfig, args):
     return batch
 
 
-def pod_scale(args, fl: FLConfig):
+def pod_scale(args, fl: FLConfig, mesh=None):
+    """The pod path; ``mesh`` defaults to ``engine_mesh`` over every
+    device. Returns (state, per-round metrics, the ChunkRunner)."""
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -157,7 +160,8 @@ def pod_scale(args, fl: FLConfig):
         fl.with_(num_clients=C, clients_per_round=C))
     batch = _pod_batch(cfg, fl, args)
     runner = ChunkRunner(model, fl, strategy, per_round_batch=False,
-                         use_scan=not args.no_scan, mesh=engine_mesh(C))
+                         use_scan=not args.no_scan,
+                         mesh=mesh if mesh is not None else engine_mesh(C))
 
     logger = _logger(args)
     if logger is not None:
@@ -173,16 +177,20 @@ def pod_scale(args, fl: FLConfig):
         if args.no_scan:
             # stream per-round progress (a multi-hour pod run must not
             # be silent): one-round chunks through the same runner
+            rows = []
             for r in range(args.rounds):
                 tr, (state, m) = sync_time(
                     runner.run_chunk, state, batch,
                     environment.batch(t_start + r, 1), scan_ok=False)
                 dt += tr
+                rows.append(m)
                 if logger is not None:
                     logger.rounds(t_start + r, m)
                 print(f"round {r}: loss={float(m['loss'][0]):.4f} "
                       f"on_time={int(m['n_on_time'][0])}/{C} "
                       f"({tr:.2f}s)")
+            metrics = {k: np.concatenate([m[k] for m in rows])
+                       for k in rows[0]}
         else:
             dt, (state, metrics) = sync_time(
                 runner.run_chunk, state, batch,
@@ -206,10 +214,10 @@ def pod_scale(args, fl: FLConfig):
         logger.phases(runner.timer)
         logger.close()
         print(f"metrics -> {args.metrics_out}")
-    return state
+    return state, metrics, runner
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-cnn")
     ap.add_argument("--rounds", type=int, default=20)
@@ -306,8 +314,11 @@ def main():
                     help="restore a full round state and continue "
                          "(bit-identical to an uninterrupted run)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def fl_config(args) -> FLConfig:
+    """The run's FLConfig from parsed ``build_parser`` arguments."""
     fl = FLConfig(num_clients=args.clients,
                   clients_per_round=(args.clients_per_round
                                      or max(2, args.clients // 4)),
@@ -332,6 +343,13 @@ def main():
             fl = fl.with_(trace_path=args.trace_path)  # scenario default
     if args.metrics_out:
         fl = fl.with_(extended_metrics=True)
+    return fl
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    fl = fl_config(args)
     if args.pod:
         pod_scale(args, fl)
     else:

@@ -4,8 +4,9 @@ Two execution paths:
   * ``chunked_attention`` — XLA-native online-softmax over KV chunks
     (lax.scan). O(S * chunk) transient memory, compiles on any backend;
     this is what the multi-pod dry-run lowers.
-  * ``kernels.flash_attention`` — Pallas TPU kernel (same math), used on
-    real TPU hardware and validated in interpret mode by tests.
+  * ``kernels.flash_attention`` — a Pallas TPU kernel of the same math,
+    tested in interpret mode only. No model imports it: every model
+    path, on a TPU too, runs ``chunked_attention``.
 
 Decode uses a KV cache; sliding-window archs use a ring-buffer cache of
 size ``window`` so the long_500k cache is O(window), not O(S).

@@ -3,7 +3,10 @@
 Model code is mesh-agnostic; ``constrain`` applies a
 with_sharding_constraint only when a mesh with the named axes is active
 and every named dim divides its axis — otherwise it is a no-op (CPU
-tests, reduced configs)."""
+tests, reduced configs). The active mesh is the one entered with
+``jax.set_mesh`` (the engine and the dry-run do), read through the
+public ``jax.sharding.get_abstract_mesh``; a bare ``with mesh:`` is not
+seen."""
 from __future__ import annotations
 
 import jax
@@ -12,20 +15,8 @@ from jax.sharding import PartitionSpec as P
 
 
 def _active_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:  # `with mesh:` context managers set the thread-resources env
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def mesh_axis_names() -> tuple:
@@ -69,7 +60,9 @@ def reduce_leading(tree, weights):
     input is constrained onto the mesh's "client" axis first, so on a
     sharded mesh XLA lowers this as a LOCAL partial sum followed by one
     N-byte (or R x N) all-reduce — the per-round collective moves the
-    model size, not cohorts x model size.
+    model size, not cohorts x model size. The contraction runs at
+    HIGHEST precision: a TPU's default f32 dot takes one bf16 pass,
+    which would round the aggregated model to 8 mantissa bits.
     """
     w = weights.astype(jnp.float32)
     eq = "c...,cr->r..." if w.ndim == 2 else "c...,c->..."
@@ -78,7 +71,8 @@ def reduce_leading(tree, weights):
         if not getattr(x, "ndim", 0):
             return x
         xc = constrain(x, "client", *([None] * (x.ndim - 1)))
-        return jnp.einsum(eq, xc.astype(jnp.float32), w)
+        return jnp.einsum(eq, xc.astype(jnp.float32), w,
+                          precision=jax.lax.Precision.HIGHEST)
 
     return jax.tree.map(red, tree)
 

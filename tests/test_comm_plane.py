@@ -4,10 +4,10 @@ Five nets, mirroring the plane's layering:
 
   * codec units — registry/resolve contract, nominal wire fractions,
     exact payload byte accounting (topk < q8 < bf16 < dense);
-  * kernel parity — the fused dequantize-accumulate Pallas bodies
-    (``server_mix_delta_flat`` int8 AND bf16 payloads,
-    ``server_mix_scatter_flat``) against their jnp oracles in interpret
-    mode: padding path, K=1 edge;
+  * kernel parity — the fused dequantize-accumulate Pallas body
+    (``server_mix_delta_flat``: int8 AND bf16 payloads, and top-k pairs
+    densified into it) against the jnp oracles in interpret mode:
+    padding path, K=1 edge;
   * fused == densify — ``server_mix_compressed_tree`` must equal
     reconstruct-then-dense-mix for every payload kind (the strategies'
     ``compressed_server_update`` is only a dispatch around this);
@@ -44,7 +44,6 @@ from repro.data.synth import make_image_classification
 from repro.kernels import ref
 from repro.kernels.server_plane import (server_mix_compressed_tree,
                                         server_mix_delta_flat,
-                                        server_mix_scatter_flat,
                                         server_mix_tree)
 from repro.models.api import build_model
 from repro.obs.log import MetricsLogger
@@ -210,18 +209,19 @@ def test_mix_delta_kernel_matches_oracle(N, block, K, qdtype):
 @pytest.mark.parametrize("N,block", [(2048, 512), (2048 + 31, 512)])
 @pytest.mark.parametrize("K", [1, 6])
 def test_mix_scatter_kernel_matches_oracle(N, block, K):
-    """Top-k scatter plane: every tile sees the full coordinate list and
-    applies only in-tile positions — incl. positions landing in the
-    padded tail tile."""
+    """Top-k plane: the (value, position) pairs densified into the
+    delta kernel — the top-k route on every backend — equal the
+    scatter oracle, incl. positions landing in the padded tail tile."""
     rng = np.random.RandomState(N + K)
     w = _mix_world(rng, K, N)
     kk = 37
     idx = jnp.asarray(np.stack([rng.choice(N, kk, replace=False)
                                 for _ in range(K)]), jnp.int32)
     vals = jnp.asarray(rng.randn(K, kk), jnp.float32)
-    got = server_mix_scatter_flat(w["prev"], vals, idx, w["sizes"],
-                                  w["keep"], w["coefs"], block=block,
-                                  interpret=True)
+    groups = [([0], {"kind": "topk", "v": vals, "i": idx})]
+    got = server_mix_compressed_tree(w["prev"], groups, w["sizes"],
+                                     w["keep"], w["coefs"],
+                                     impl="interpret", block=block)
     want = ref.server_mix_scatter_math(w["prev"], vals, idx, w["sizes"],
                                        w["keep"], w["coefs"])
     assert got.dtype == w["prev"].dtype
