@@ -127,13 +127,19 @@ def make_round_step(model, fl: FLConfig, strategy=None):
     from repro import comm
     comm_plane = comm.resolve(fl)
 
+    # the client, comm and server planes run under named scopes of
+    # those names, so every op of the compiled round carries its plane
+    # in its ``op_name`` metadata and a device trace can be read by
+    # plane; metadata only, the program is otherwise unchanged
     def round_step(state, batch, sched, _tap=None):
         t = state["t"]
         prev_global = state["params"]
-        # stacked client axis over the FL mesh ("client"); no-op off-mesh
-        batch = constrain_leading(batch, "client")
-        client_params, losses = local_train(prev_global, batch, sched)
-        client_params = constrain_leading(client_params, "client")
+        with jax.named_scope("client_plane"):
+            # stacked client axis over the FL mesh ("client"); no-op
+            # off-mesh
+            batch = constrain_leading(batch, "client")
+            client_params, losses = local_train(prev_global, batch, sched)
+            client_params = constrain_leading(client_params, "client")
         # compressed uplink: quantize/sparsify the deltas (plus carried
         # error-feedback residual), then hand the SERVER only what the
         # wire would deliver. The residual is comm-plane state, not
@@ -142,8 +148,10 @@ def make_round_step(model, fl: FLConfig, strategy=None):
         groups = new_res = None
         if comm_plane is not None:
             srv_aux = {k: v for k, v in state["aux"].items() if k != "comm"}
-            groups, new_res = comm_plane.compress(
-                t, prev_global, client_params, state["aux"].get("comm", {}))
+            with jax.named_scope("comm_plane"):
+                groups, new_res = comm_plane.compress(
+                    t, prev_global, client_params,
+                    state["aux"].get("comm", {}))
         # pre-reduce the stacked client axis when it is actually
         # distributed (fl.client_reduce: "auto" checks the ACTIVE mesh at
         # trace time; "force" for CPU equivalence tests): the weighted
@@ -154,10 +162,13 @@ def make_round_step(model, fl: FLConfig, strategy=None):
         mode = getattr(fl, "client_reduce", "auto")
         new_params = aux = None
         if mode == "force" or (mode == "auto" and axis_size("client") > 1):
-            cp = (comm_plane.reconstruct(prev_global, groups)
-                  if comm_plane is not None else client_params)
-            out = strategy.reduced_server_update(
-                t, prev_global, cp, sched, srv_aux)
+            cp = client_params
+            if comm_plane is not None:
+                with jax.named_scope("comm_plane"):
+                    cp = comm_plane.reconstruct(prev_global, groups)
+            with jax.named_scope("server_plane"):
+                out = strategy.reduced_server_update(
+                    t, prev_global, cp, sched, srv_aux)
             if out is not NotImplemented:
                 new_params, aux = out
         elif mode not in ("auto", "off"):
@@ -168,18 +179,22 @@ def make_round_step(model, fl: FLConfig, strategy=None):
             # compressed payload in-kernel; strategies whose update is
             # not linear in the deltas return NotImplemented and take
             # the densified fallback below
-            out = strategy.compressed_server_update(
-                t, prev_global, groups, sched, srv_aux)
+            with jax.named_scope("server_plane"):
+                out = strategy.compressed_server_update(
+                    t, prev_global, groups, sched, srv_aux)
             if out is not NotImplemented:
                 new_params, aux = out
             else:
-                client_params = comm_plane.reconstruct(prev_global, groups)
+                with jax.named_scope("comm_plane"):
+                    client_params = comm_plane.reconstruct(prev_global,
+                                                           groups)
         if new_params is None:
             # ONE fused server-plane pass: staleness weights, delta
             # accumulation, ring-buffer mix and (fedopt) server-Adam in
             # a single kernel dispatch (fl.server_plane selects the impl)
-            new_params, aux = strategy.fused_server_update(
-                t, prev_global, client_params, sched, srv_aux)
+            with jax.named_scope("server_plane"):
+                new_params, aux = strategy.fused_server_update(
+                    t, prev_global, client_params, sched, srv_aux)
         if new_res:
             aux = dict(aux)
             aux["comm"] = new_res

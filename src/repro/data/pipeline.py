@@ -154,21 +154,41 @@ def stage_round_indices(clients, selected: np.ndarray,
                      for i in selected])
 
 
+def stage_chunk_indices(clients, selected: np.ndarray, seed: int,
+                        t0: int, steps: int,
+                        batch_size: int) -> np.ndarray:
+    """The index draw of ``stage_chunk``: (n_rounds, C, steps, batch)
+    global sample indices, row i drawn as round ``t0 + i`` alone."""
+    selected = np.asarray(selected)
+    return np.stack([stage_round_indices(clients, selected[i], seed, t0 + i,
+                                         steps, batch_size)
+                     for i in range(selected.shape[0])])
+
+
+def gather_chunk(data: dict, idx: np.ndarray) -> dict:
+    """The gather of ``stage_chunk``: ONE fancy-gather per data field,
+    {field: (*idx.shape, ...)}."""
+    return {k: v[idx] for k, v in data.items()}
+
+
 def stage_chunk(data: dict, clients,
                 selected: np.ndarray, seed: int, t0: int, steps: int,
-                batch_size: int) -> dict:
+                batch_size: int, *, timer=None) -> dict:
     """Stage a whole chunk of rounds with ONE gather per data field.
 
     selected: (n_rounds, C) client indices (``Environment.batch`` rows).
     Returns {field: (n_rounds, C, steps, batch, ...)} numpy arrays —
     exactly the ``per_round_batch`` layout ``make_train_loop`` scans
     over. Row i is bit-identical to staging round ``t0 + i`` alone.
+    A ``timer`` (``obs.timing.PhaseTimes``) books the gather as the
+    phase ``stage_gather``.
     """
-    selected = np.asarray(selected)
-    idx = np.stack([stage_round_indices(clients, selected[i], seed, t0 + i,
-                                        steps, batch_size)
-                    for i in range(selected.shape[0])])
-    return {k: v[idx] for k, v in data.items()}
+    idx = stage_chunk_indices(clients, selected, seed, t0, steps,
+                              batch_size)
+    if timer is None:
+        return gather_chunk(data, idx)
+    with timer.phase("stage_gather"):
+        return gather_chunk(data, idx)
 
 
 def partition_plan(limited: np.ndarray) -> dict:

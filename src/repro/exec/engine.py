@@ -49,7 +49,7 @@ from repro.core.round import as_scan_scheds, init_state, make_train_loop
 from repro.data.pipeline import ChunkPrefetcher, partition_plan, stage_chunk
 from repro.exec.evals import Evaluator
 from repro.obs.metrics import stability_stats
-from repro.obs.timing import PhaseTimes, annotate
+from repro.obs.timing import PhaseTimes
 
 
 @dataclass
@@ -131,8 +131,7 @@ class ChunkRunner:
                  else ("scan_dispatch" if scan and n > 1
                        else "round_dispatch"))
         self._compiled.add(key)
-        with self.timer.phase(phase) as span, \
-                annotate(f"train_chunk_n{n}"):
+        with self.timer.phase(phase, region=f"train_chunk_n{n}") as span:
             args = (state, batch, scheds)
             if getattr(self.fl, "extended_metrics", False):
                 # extended telemetry: the loop takes a shadow tap — a
@@ -196,7 +195,10 @@ class ChunkRunner:
                            **partition_plan(sched_batch["limited"])}
         scheds = as_scan_scheds(sched_batch)
         n = int(jax.tree.leaves(scheds)[0].shape[0])
-        batch = jax.tree.map(jnp.asarray, batch)
+        # the copy completes before the dispatch, so "h2d" is the copy
+        # alone and the train-loop phase the program alone
+        with self.timer.phase("h2d") as span:
+            batch = span.sync(jax.tree.map(jnp.asarray, batch))
         with self._ctx():
             loop = self._train_loop()
             if self.use_scan and scan_ok:
@@ -309,13 +311,14 @@ class SimulationEngine:
     def _stage(self, t0: int, n: int):
         # runs on the prefetcher's worker thread during overlapped
         # execution — PhaseTimes is thread-safe, so "stage" seconds
-        # accumulate either way (they OVERLAP device phases by design)
-        with self.timer.phase("stage"), annotate(f"stage_t{t0}"):
+        # accumulate either way (they OVERLAP device phases by design);
+        # "stage_cpu" books this thread's CPU seconds inside "stage"
+        with self.timer.phase("stage", region=f"stage_t{t0}", cpu=True):
             sb = self.env.batch(t0, n)
             batch = stage_chunk(self.data, self.clients, sb["selected"],
                                 self.fl.seed, t0,
                                 self._steps_per_round(),
-                                self.fl.local_batch_size)
+                                self.fl.local_batch_size, timer=self.timer)
         return sb, batch
 
     def run_round(self) -> float:
@@ -327,7 +330,7 @@ class SimulationEngine:
         return float(metrics["loss"][0])
 
     def evaluate(self) -> tuple[float, float]:
-        with self.timer.phase("eval"), annotate("eval"):
+        with self.timer.phase("eval"):
             if self._eval_fn is not None:
                 return self._eval_fn(self.state["params"],
                                      self.test_data)
@@ -352,12 +355,18 @@ class SimulationEngine:
             n = min((t // eval_every + 1) * eval_every, end) - t
             chunks.append((t, n))
             t += n
-        staged = (ChunkPrefetcher(lambda c: self._stage(*c), chunks,
-                                  depth=getattr(self.fl, "prefetch_depth",
-                                                1))
-                  if self.prefetch else (self._stage(*c) for c in chunks))
+        prefetcher = (ChunkPrefetcher(lambda c: self._stage(*c), chunks,
+                                      depth=getattr(self.fl,
+                                                    "prefetch_depth", 1))
+                      if self.prefetch else None)
+        staged = (iter(prefetcher) if prefetcher is not None
+                  else (self._stage(*c) for c in chunks))
         try:
-            for (t, n), (sb, batch) in zip(chunks, staged):
+            for t, n in chunks:
+                # the round waits for its staged chunk; without prefetch
+                # this span holds the chunk's whole inline staging
+                with self.timer.phase("stage_wait"):
+                    sb, batch = next(staged)
                 self.state, metrics = self.runner.run_chunk(
                     self.state, batch, sb, scan_ok=(n == eval_every))
                 hist.train_loss.extend(float(x) for x in metrics["loss"])
@@ -376,8 +385,8 @@ class SimulationEngine:
                               f"train_loss={hist.train_loss[-1]:.4f} "
                               f"test_acc={acc:.4f}")
         finally:
-            if isinstance(staged, ChunkPrefetcher):
-                staged.close()           # abandoned mid-run: release the
+            if prefetcher is not None:
+                prefetcher.close()       # abandoned mid-run: release the
             if self.logger is not None:  # worker + buffered chunks
                 self.logger.phases(self.timer)
         return hist

@@ -138,13 +138,21 @@ def render(s: dict, label: str = "") -> str:
                      f"uploaded on time "
                      f"({s['bytes_on_wire_total'] / 1e6 / max(s['rounds'], 1):.3f} MB/round)")
     if "phases" in s:
-        total = sum(v["seconds"] for v in s["phases"].values()) or 1.0
+        # "*_cpu" keys are CPU-seconds counters, not wall-clock phases:
+        # they take no share of the wall total and print on their own
+        wall = {k: v for k, v in s["phases"].items()
+                if not k.endswith("_cpu")}
+        cpu = {k: v for k, v in s["phases"].items() if k.endswith("_cpu")}
+        total = sum(v["seconds"] for v in wall.values()) or 1.0
         breakdown = "  ".join(
             f"{k}={v['seconds']:.2f}s({v['seconds'] / total:.0%})"
-            for k, v in s["phases"].items())
+            for k, v in wall.items())
         tput = (f" | {s['rounds_per_sec']:.2f} rounds/s"
                 if "rounds_per_sec" in s else "")
         lines.append(f"phases: {breakdown}{tput}")
+        if cpu:
+            lines.append("cpu: " + "  ".join(
+                f"{k}={v['seconds']:.2f}s" for k, v in cpu.items()))
     return "\n".join(lines)
 
 

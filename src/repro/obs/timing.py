@@ -8,16 +8,40 @@ clock steps) and closes its span with ``jax.block_until_ready`` on the
 computation's outputs, so a phase's seconds are the seconds the device
 actually spent.
 
-``PhaseTimes`` accumulates named phases (staging / compile / scan
-dispatch / eval / checkpoint ...) across a run; the execution engine
-carries one and the ``MetricsLogger`` serializes its summary. "compile"
-is first-call wall time for a given program shape (trace + XLA compile
-+ the first execution — the honest definition without AOT plumbing);
-steady-state dispatches accumulate under their own phase.
+``PhaseTimes`` accumulates named phases across a run; the execution
+engine carries one and the ``MetricsLogger`` serializes its summary.
+Every phase is also a ``TraceAnnotation`` region (``annotate``) opened
+by the same call, so a ``--profile`` trace shows each phase on the
+device trace's clock and the two cannot drift apart. The engine's
+phases, by thread:
 
-``profile_trace`` / ``annotate`` are the ``--profile <dir>`` hooks:
-a ``jax.profiler.trace`` context around the run and named
-``TraceAnnotation`` regions around chunks/eval, so the resulting
+  main thread (``SimulationEngine.run`` / ``ChunkRunner``):
+    ``stage_wait``    asking the staged iterator for the next chunk until
+                      holding it; with ``prefetch=False`` it holds the
+                      chunk's whole inline staging
+    ``h2d``           the staged chunk's host-to-device copy, closed on
+                      the device arrays before the dispatch
+    ``compile`` / ``scan_dispatch`` / ``round_dispatch``
+                      [region ``train_chunk_n<n>``] one train-loop
+                      dispatch to its outputs; "compile" is the first
+                      call per chunk shape (trace + XLA compile + the
+                      first execution)
+    ``eval``          the evaluation, to accuracy and loss on the host
+    ``checkpoint``    saving the round state
+  staging thread (``ChunkPrefetcher``; the main thread without prefetch):
+    ``stage``         [region ``stage_t<t0>``] schedules, index draw and
+                      gather of one chunk
+    ``stage_gather``  the gather alone, inside ``stage``
+    ``stage_cpu``     a counter, not a span: the staging thread's CPU
+                      seconds inside ``stage`` (``cpu=True``)
+
+A phase's region has the phase's name unless given in brackets.
+The evaluator adds its own region, ``evaluator``, inside ``eval``.
+Phases of distinct names may overlap (staging runs beside dispatch);
+a ``*_cpu`` key holds CPU seconds, not wall time.
+
+``profile_trace`` is the ``--profile <dir>`` hook: a
+``jax.profiler.trace`` context around the run, so the resulting
 TensorBoard trace carries the engine's own phase structure.
 """
 from __future__ import annotations
@@ -82,17 +106,25 @@ class PhaseTimes:
             self.calls[name] = self.calls.get(name, 0) + 1
 
     @contextlib.contextmanager
-    def phase(self, name: str):
+    def phase(self, name: str, region: str | None = None,
+              cpu: bool = False):
         """``with times.phase("eval") as span: span.sync(out)`` — the
-        span closes only after the synced outputs are ready."""
+        span closes only after the synced outputs are ready. It runs
+        inside the profiler region ``region`` (default: ``name``); with
+        ``cpu=True`` it also books the calling thread's CPU seconds
+        under ``f"{name}_cpu"``."""
         span = _Span()
-        t0 = time.perf_counter()
-        try:
-            yield span
-        finally:
-            if span._tree is not None:
-                _block(span._tree)
-            self.add(name, time.perf_counter() - t0)
+        with annotate(region or name):
+            c0 = time.thread_time() if cpu else 0.0
+            t0 = time.perf_counter()
+            try:
+                yield span
+            finally:
+                if span._tree is not None:
+                    _block(span._tree)
+                self.add(name, time.perf_counter() - t0)
+                if cpu:
+                    self.add(f"{name}_cpu", time.thread_time() - c0)
 
     def summary(self) -> dict:
         """{phase: {"seconds": s, "calls": n}}, insertion-ordered."""

@@ -16,7 +16,9 @@ from repro.configs.registry import ARCHS
 from repro.core.simulation import FederatedSimulation
 from repro.data.partition import shard_partition
 from repro.data.pipeline import (ChunkPrefetcher, build_clients,
-                                 stage_chunk, stage_round_indices)
+                                 gather_chunk, stage_chunk,
+                                 stage_chunk_indices, stage_round_indices)
+from repro.obs.timing import PhaseTimes
 from repro.data.synth import make_image_classification
 from repro.exec.evals import Evaluator
 from repro.launch.mesh import engine_mesh
@@ -90,6 +92,22 @@ def test_stage_chunk_rows_match_per_round_staging(small_world):
         # every drawn index belongs to the client's own shard
         for c in range(3):
             assert set(idx[c].ravel()) <= set(clients[sel[i][c]].indices)
+
+
+def test_stage_chunk_is_index_draw_then_gather(small_world):
+    """stage_chunk is its index draw composed with its gather, with or
+    without a timer, which books the gather alone."""
+    model, train, clients, _ = small_world
+    sel = np.array([[0, 3, 5], [7, 1, 2]])
+    idx = stage_chunk_indices(clients, sel, 4, 9, 3, 4)
+    assert idx.shape == (2, 3, 3, 4)
+    timer = PhaseTimes()
+    for chunk in (stage_chunk(train, clients, sel, 4, 9, 3, 4),
+                  stage_chunk(train, clients, sel, 4, 9, 3, 4,
+                              timer=timer)):
+        for k, v in gather_chunk(train, idx).items():
+            np.testing.assert_array_equal(chunk[k], v)
+    assert list(timer.calls) == ["stage_gather"]
 
 
 def test_staging_pure_in_t_chunking_invariant(small_world):

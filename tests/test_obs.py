@@ -247,6 +247,18 @@ def test_report_cli_render_and_compare(logged_run, capsys):
     assert "provenance mismatch" not in out     # same file, same env
 
 
+def test_report_prints_cpu_counters_apart():
+    """A ``*_cpu`` counter is CPU seconds: it takes no share of the
+    phases' wall total and prints on a line of its own."""
+    from repro.obs.report import render, summarize
+    ph = {"stage": {"seconds": 3.0, "calls": 1},
+          "eval": {"seconds": 1.0, "calls": 1},
+          "stage_cpu": {"seconds": 2.0, "calls": 1}}
+    out = render(summarize([{"kind": "phases", "phases": ph}]))
+    assert "stage=3.00s(75%)" in out and "eval=1.00s(25%)" in out
+    assert "cpu: stage_cpu=2.00s" in out and "stage_cpu=2.00s(" not in out
+
+
 def test_report_cli_rejects_invalid_file(tmp_path):
     from repro.obs.report import main
     bad = tmp_path / "bad.jsonl"
@@ -337,6 +349,84 @@ def test_engine_populates_phase_timer(small_world):
         assert phase in s and s[phase]["seconds"] > 0
     assert s["compile"]["calls"] == 1
     assert s["scan_dispatch"]["calls"] == 1      # the second 2-chunk
+
+
+def test_engine_books_host_path_spans(small_world):
+    """The round's host path: one wait for staging and one host-to-device
+    copy per chunk, the gather inside staging, and the staging thread's
+    CPU seconds."""
+    model, clients, test = small_world
+    sim = FederatedSimulation(model, _fl(), clients, test)
+    sim.run(rounds=4, eval_every=2)
+    s = sim.timer.summary()
+    for phase in ("stage_wait", "h2d", "stage_gather", "stage_cpu"):
+        assert phase in s and s[phase]["seconds"] >= 0
+    assert s["stage_wait"]["calls"] == 2 and s["h2d"]["calls"] == 2
+    assert s["stage_gather"]["calls"] == s["stage"]["calls"] == 2
+    assert s["stage_gather"]["seconds"] <= s["stage"]["seconds"]
+
+
+def test_slow_staging_shows_as_wait_not_cpu(small_world, monkeypatch):
+    """Staging that sleeps: the first chunk waits its whole staging, and
+    the sleep is wall time of "stage" but not CPU time."""
+    import time
+
+    from repro.exec import engine
+    stage = engine.stage_chunk
+
+    def slow(*args, **kwargs):
+        time.sleep(0.3)
+        return stage(*args, **kwargs)
+    monkeypatch.setattr(engine, "stage_chunk", slow)
+    model, clients, test = small_world
+    sim = FederatedSimulation(model, _fl(), clients, test)
+    sim.run(rounds=2, eval_every=2)
+    sec = sim.timer.seconds
+    assert sec["stage_wait"] >= 0.2
+    assert sec["stage"] >= 0.3
+    assert sec["stage_cpu"] < sec["stage"]
+
+
+def test_phase_opens_its_profiler_region(small_world, monkeypatch):
+    """A phase opens a region named ``region`` (default: the phase), and
+    the engine still opens the regions the benchmark's trace names."""
+    import contextlib
+    import threading
+
+    from repro.obs import timing
+    opened, lock = [], threading.Lock()
+
+    def record(name):
+        with lock:
+            opened.append(name)
+        return contextlib.nullcontext()
+    monkeypatch.setattr(timing, "annotate", record)
+    pt = PhaseTimes()
+    with pt.phase("compile", region="train_chunk_n3"):
+        pass
+    with pt.phase("eval"):
+        pass
+    assert opened == ["train_chunk_n3", "eval"]
+    model, clients, test = small_world
+    sim = FederatedSimulation(model, _fl(), clients, test)
+    sim.run(rounds=2, eval_every=2)
+    for prefix in ("train_chunk_n", "stage_t", "eval", "stage_wait", "h2d",
+                   "stage_gather"):
+        assert any(n.startswith(prefix) for n in opened[2:]), prefix
+
+
+def test_round_step_hlo_names_its_planes():
+    """The compiled masked-plane train loop (with a comm plane) carries
+    the client, comm and server planes in its ops' ``op_name``."""
+    import re
+
+    from repro.analysis import jaxpr_rules as jr
+    h = jr.TraceHarness(jr._tiny_fl(comm_plane="q8"))
+    assert h.fl.client_plane == "masked"
+    txt = h.train_loop().lower(*h.loop_args()).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', txt)
+    for plane in ("client_plane", "comm_plane", "server_plane"):
+        assert any(f"/{plane}/" in n for n in names), plane
 
 
 def test_provenance_block_and_diff():
