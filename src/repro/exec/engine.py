@@ -11,12 +11,13 @@ path with a different data plane:
     bit-identical (the ``--no-scan`` safety net — see
     tests/test_engine.py);
   * ``SimulationEngine`` adds the vectorized data plane
-    (``data.pipeline.stage_chunk`` — one fancy-gather per chunk of
-    rounds, next chunk prefetched host-side while the current chunk runs
-    on device), the jitted batched eval (``exec.evals.Evaluator``) at an
-    ``eval_every`` cadence, full round-state checkpointing
-    ({params, t, aux}: async ring buffer, fedopt moments, ...) and the
-    ``History`` stability metrics;
+    (``data.pipeline.stage_chunk`` — one gather per chunk of rounds,
+    from a sample store kept on the device where it fits, else on the
+    host; the next chunk is staged on a host thread while the current
+    chunk runs on device), the jitted batched eval
+    (``exec.evals.Evaluator``) at an ``eval_every`` cadence, full
+    round-state checkpointing ({params, t, aux}: async ring buffer,
+    fedopt moments, ...) and the ``History`` stability metrics;
   * both run under the FL mesh (``launch.mesh.engine_mesh``) so the
     stacked client axis of params and batches is sharded on a pod and a
     degenerate no-op on this CPU container — the identical program at
@@ -46,7 +47,8 @@ from repro.checkpoint.io import restore_state, save_state
 from repro.configs.base import FLConfig
 from repro.core import strategies
 from repro.core.round import as_scan_scheds, init_state, make_train_loop
-from repro.data.pipeline import ChunkPrefetcher, partition_plan, stage_chunk
+from repro.data.pipeline import (ChunkPrefetcher, partition_plan,
+                                 place_store, stage_chunk)
 from repro.exec.evals import Evaluator
 from repro.obs.metrics import stability_stats
 from repro.obs.timing import PhaseTimes
@@ -196,7 +198,8 @@ class ChunkRunner:
         scheds = as_scan_scheds(sched_batch)
         n = int(jax.tree.leaves(scheds)[0].shape[0])
         # the copy completes before the dispatch, so "h2d" is the copy
-        # alone and the train-loop phase the program alone
+        # alone and the train-loop phase the program alone; a batch
+        # already on the device copies nothing and "h2d" waits for it
         with self.timer.phase("h2d") as span:
             batch = span.sync(jax.tree.map(jnp.asarray, batch))
         with self._ctx():
@@ -224,9 +227,12 @@ class SimulationEngine:
     Drives ``eval_every``-round chunks through ``ChunkRunner`` over any
     registered environment: schedules from ``Environment.batch``, client
     batches staged in one gather per chunk (``stage_chunk``) with the
-    next chunk prefetched host-side, eval through the jitted batched
-    ``Evaluator``. ``use_scan=False`` is the per-round fallback
-    (bit-identical; the refactor's safety net).
+    next chunk prefetched on a host thread, eval through the jitted
+    batched ``Evaluator``. The shared sample store is placed once, here:
+    on the device (replicated over ``mesh``) where it fits, so staging
+    gathers there, else on the host (``data.pipeline.place_store``).
+    ``use_scan=False`` is the per-round fallback (bit-identical; the
+    refactor's safety net).
     """
 
     def __init__(self, model, fl: FLConfig, clients, test_data,
@@ -273,6 +279,7 @@ class SimulationEngine:
                 "the chunked data plane stages every client from ONE "
                 "shared sample store (build clients with "
                 "data.pipeline.build_clients(data, partition))")
+        self._store = place_store(self.data, mesh)
         self.state = init_state(model, fl, jax.random.PRNGKey(fl.seed),
                                 self.strategy)
 
@@ -315,7 +322,7 @@ class SimulationEngine:
         # "stage_cpu" books this thread's CPU seconds inside "stage"
         with self.timer.phase("stage", region=f"stage_t{t0}", cpu=True):
             sb = self.env.batch(t0, n)
-            batch = stage_chunk(self.data, self.clients, sb["selected"],
+            batch = stage_chunk(self._store, self.clients, sb["selected"],
                                 self.fl.seed, t0,
                                 self._steps_per_round(),
                                 self.fl.local_batch_size, timer=self.timer)

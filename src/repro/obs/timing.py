@@ -20,7 +20,9 @@ phases, by thread:
                       holding it; with ``prefetch=False`` it holds the
                       chunk's whole inline staging
     ``h2d``           the staged chunk's host-to-device copy, closed on
-                      the device arrays before the dispatch
+                      the device arrays before the dispatch; a chunk
+                      gathered from a device store copies nothing, and
+                      the span holds the wait for the gather's output
     ``compile`` / ``scan_dispatch`` / ``round_dispatch``
                       [region ``train_chunk_n<n>``] one train-loop
                       dispatch to its outputs; "compile" is the first
@@ -31,14 +33,20 @@ phases, by thread:
   staging thread (``ChunkPrefetcher``; the main thread without prefetch):
     ``stage``         [region ``stage_t<t0>``] schedules, index draw and
                       gather of one chunk
-    ``stage_gather``  the gather alone, inside ``stage``
+    ``stage_gather``  the gather alone, inside ``stage``: the host
+                      fancy-gather, or from a device store the index
+                      upload and the gather's dispatch
     ``stage_cpu``     a counter, not a span: the staging thread's CPU
                       seconds inside ``stage`` (``cpu=True``)
+    ``stage_device``  a counter, not a span: the seconds of
+                      ``stage_gather`` that gathered from a device store;
+                      its calls count those chunks
 
 A phase's region has the phase's name unless given in brackets.
 The evaluator adds its own region, ``evaluator``, inside ``eval``.
 Phases of distinct names may overlap (staging runs beside dispatch);
-a ``*_cpu`` key holds CPU seconds, not wall time.
+a ``*_cpu`` key holds CPU seconds, not wall time, and ``stage_device``
+repeats part of ``stage_gather``.
 
 ``profile_trace`` is the ``--profile <dir>`` hook: a
 ``jax.profiler.trace`` context around the run, so the resulting
@@ -74,12 +82,14 @@ def sync_time(fn, *args, **kwargs):
 
 class _Span:
     """Yielded by ``PhaseTimes.phase``; call ``sync(tree)`` with the
-    device outputs whose completion closes the span."""
+    device outputs whose completion closes the span. Once closed,
+    ``seconds`` holds what the phase booked."""
 
-    __slots__ = ("_tree",)
+    __slots__ = ("_tree", "seconds")
 
     def __init__(self):
         self._tree = None
+        self.seconds = None
 
     def sync(self, tree):
         self._tree = tree
@@ -122,7 +132,8 @@ class PhaseTimes:
             finally:
                 if span._tree is not None:
                     _block(span._tree)
-                self.add(name, time.perf_counter() - t0)
+                span.seconds = time.perf_counter() - t0
+                self.add(name, span.seconds)
                 if cpu:
                     self.add(f"{name}_cpu", time.thread_time() - c0)
 

@@ -15,9 +15,12 @@ from repro.configs.base import FLConfig
 from repro.configs.registry import ARCHS
 from repro.core.simulation import FederatedSimulation
 from repro.data.partition import shard_partition
-from repro.data.pipeline import (ChunkPrefetcher, build_clients,
-                                 gather_chunk, stage_chunk,
-                                 stage_chunk_indices, stage_round_indices)
+from repro.data import pipeline
+from repro.data.pipeline import (ChunkPrefetcher, DeviceStore,
+                                 VirtualClientShards, build_clients,
+                                 gather_chunk, place_store, stage_chunk,
+                                 stage_chunk_indices, stage_round_indices,
+                                 store_fits_device)
 from repro.obs.timing import PhaseTimes
 from repro.data.synth import make_image_classification
 from repro.exec.evals import Evaluator
@@ -122,6 +125,106 @@ def test_staging_pure_in_t_chunking_invariant(small_world):
     for k in whole:
         np.testing.assert_array_equal(
             whole[k], np.concatenate([p[k] for p in parts]))
+
+
+@pytest.mark.parametrize("population,meshed", [("dense", False),
+                                               ("virtual", False),
+                                               ("dense", True)])
+def test_device_store_stages_as_the_host_gather(small_world, population,
+                                                meshed):
+    """The device gather returns the host gather's batches bit for bit,
+    for a dense client list and for virtual shards over the same store,
+    and books "stage_gather" and the "stage_device" counter alike."""
+    _, train, clients, _ = small_world
+    if population == "virtual":
+        clients = VirtualClientShards(train, 20, shard_size=24, seed=0)
+    store = DeviceStore(train, engine_mesh(3) if meshed else None)
+    sel = np.array([[0, 3, 5], [7, 1, 2]])
+    timer = PhaseTimes()
+    got = stage_chunk(store, clients, sel, 4, 9, 3, 4, timer=timer)
+    want = stage_chunk(train, clients, sel, 4, 9, 3, 4)
+    assert set(got) == set(want)
+    for k in want:
+        assert isinstance(got[k], jax.Array)
+        np.testing.assert_array_equal(np.asarray(got[k]), want[k])
+    assert timer.calls == {"stage_gather": 1, "stage_device": 1}
+    assert timer.seconds["stage_device"] == timer.seconds["stage_gather"]
+
+
+def test_device_store_rejects_indices_outside_the_store(small_world):
+    """The device gather is told its indices are in bounds, so it
+    refuses what the host gather would refuse."""
+    _, train, _, _ = small_world
+    store = DeviceStore(train)
+    n = len(train["label"])
+    with pytest.raises(IndexError):
+        train["label"][np.array([[0, n]])]
+    with pytest.raises(IndexError, match="outside a store"):
+        store.gather(np.array([[0, n]]))
+    np.testing.assert_array_equal(
+        np.asarray(store.gather(np.array([[-1, 2]]))["label"]),
+        train["label"][np.array([[-1, 2]])])
+
+
+@pytest.mark.parametrize("store_bytes,limit,fits", [
+    (100, 400, True), (101, 400, False), (10**9, 16 * 10**9, True),
+    (100, None, False)])
+def test_store_placement_rule(store_bytes, limit, fits):
+    """On the device at up to a quarter of its memory; on the host past
+    it, or where the backend reports no memory (the CPU)."""
+    assert store_fits_device(store_bytes, limit) is fits
+
+
+@pytest.mark.parametrize("limit,on_device", [(None, False), (10**12, True),
+                                             (1000, False)])
+def test_place_store_follows_the_reported_memory(small_world, monkeypatch,
+                                                 limit, on_device):
+    _, train, _, _ = small_world
+    monkeypatch.setattr(pipeline, "device_bytes_limit", lambda devs: limit)
+    store = place_store(train)
+    assert isinstance(store, DeviceStore) is on_device
+    if not on_device:
+        assert store is train
+
+
+def _sim_on(monkeypatch, device: bool, *args, **kwargs):
+    """A simulation whose sample store the placement rule puts on the
+    device (``device``) or leaves on the host."""
+    with monkeypatch.context() as m:
+        m.setattr(pipeline, "device_bytes_limit",
+                  lambda devs: 10**12 if device else None)
+        sim = FederatedSimulation(*args, **kwargs)
+    assert isinstance(sim._store, DeviceStore) is device
+    return sim
+
+
+@pytest.mark.parametrize("use_scan", [True, False])
+def test_device_store_engine_bit_identical_to_host_gather(small_world,
+                                                          monkeypatch,
+                                                          use_scan):
+    """A run gathering from the device store and one gathering on the
+    host give the same params, aux and History bit for bit, on the
+    fused scan and the per-round path, over a chunk boundary."""
+    model, _, clients, test = small_world
+    fl = _fl(algorithm="async_ama", max_delay=3, p_delay=0.4)
+    sims = {d: _sim_on(monkeypatch, d, model, fl, clients, test,
+                       use_scan=use_scan) for d in (True, False)}
+    hists = {d: sim.run(rounds=3, eval_every=2) for d, sim in sims.items()}
+    assert_states_identical(sims[True].state, sims[False].state)
+    for k in ("train_loss", "test_acc", "test_loss", "eval_rounds"):
+        assert getattr(hists[True], k) == getattr(hists[False], k)
+
+
+@pytest.mark.parametrize("device", [True, False])
+def test_stage_device_counts_device_chunks(small_world, monkeypatch, device):
+    """"stage_device" is booked once per chunk gathered on the device,
+    and never by the host gather."""
+    model, _, clients, test = small_world
+    sim = _sim_on(monkeypatch, device, model, _fl(), clients, test)
+    sim.run(rounds=3, eval_every=2)
+    calls = sim.timer.calls
+    assert calls["stage"] == calls["stage_gather"] == 2
+    assert calls.get("stage_device", 0) == (2 if device else 0)
 
 
 def test_chunk_prefetcher_orders_and_propagates_errors():
