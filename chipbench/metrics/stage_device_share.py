@@ -1,13 +1,14 @@
-"""stage_device_share: the share of the window's "stage_gather" seconds
-that gathered from a sample store on the device, in percent. The
-program books each device gather's "stage_gather" seconds again under
-the counter "stage_device", so this reads 100 when every chunk of the
-window was gathered on the device; it is ``None`` where the program
-books no "stage_device" (a host-gather run, or a program without the
-device store)."""
+"""stage_device_share: the share of the window's staged chunks gathered
+from a sample store on the device, in percent. The program books the
+counter "stage_device" (zero seconds) for each chunk it gathers on the
+device, and places the store once for a run's chunks, so every chunk of
+the window takes the same path: this reads 100 where the window's
+phases hold "stage_device" beside "stage_gather" seconds, and ``None``
+where the program books no "stage_device" (a host-gather run, or a
+program without the device store)."""
 
 
 def read(r):
-    dev = r.win["phases"].get("stage_device")
-    gather = r.win["phases"].get("stage_gather")
-    return 100.0 * dev / gather if dev is not None and gather else None
+    phases = r.win["phases"]
+    return 100.0 if "stage_device" in phases and phases.get(
+        "stage_gather") else None

@@ -133,7 +133,8 @@ class FLConfig:
     # "dense"/"virtual" force either at any K
     population: str = "auto"
     # staging look-ahead: how many chunks ChunkPrefetcher keeps in
-    # flight ahead of the device (host memory ~ depth x chunk bytes)
+    # flight ahead of the device (depth + 2 chunks held at once, in
+    # device memory from a device sample store, else in host memory)
     prefetch_depth: int = 1
     # pre-reduce the stacked (C, N) client plane to the (N,) weighted
     # sums the server planes actually consume BEFORE the server update,

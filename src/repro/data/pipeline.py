@@ -7,9 +7,11 @@ per-round Python staging loops. Index computation is host-side numpy
 (cheap); the gather touches the actual sample arrays exactly once per
 chunk.
 
-WHERE THE GATHER RUNS: the shared sample store is placed once, at
-set-up (``place_store``). Where it fits a quarter of the device's
-memory it lives on the device (``DeviceStore``), and staging uploads a
+WHERE THE GATHER RUNS: each ``SimulationEngine.run`` places the
+shared sample store for its chunks (``place_store``). Where staging's
+whole device footprint (``staging_bytes``: the store, the chunks in
+flight and one gather's scratch) fits a quarter of the device's memory,
+the store lives on the device (``DeviceStore``), and staging uploads a
 chunk's indices and gathers there: the samples never cross the host
 again, only the int32 indices do. Otherwise, and where the backend
 reports no memory (the CPU), the store stays a numpy dict and
@@ -186,14 +188,15 @@ def gather_chunk(data: dict, idx: np.ndarray) -> dict:
     return {k: v[idx] for k, v in data.items()}
 
 
-def store_fits_device(store_bytes: int, bytes_limit: int | None) -> bool:
-    """THE placement rule of the sample store: on the device when it
-    takes at most a quarter of the device's memory, which leaves room
-    for three staged chunks in flight and the model. A backend that
-    reports no memory (the CPU, whose device memory is host memory)
-    keeps the host gather: nothing there says the store fits, and a
-    host "device" copy saves no transfer."""
-    return bytes_limit is not None and store_bytes <= bytes_limit // 4
+def store_fits_device(nbytes: int, bytes_limit: int | None) -> bool:
+    """THE placement rule of the sample store: on the device when
+    staging's device footprint (``nbytes``, from ``staging_bytes``)
+    takes at most a quarter of the device's memory, which leaves three
+    quarters to the model, its state and the round program. A backend
+    that reports no memory (the CPU, whose device memory is host
+    memory) keeps the host gather: nothing there says the store fits,
+    and a host "device" copy saves no transfer."""
+    return bytes_limit is not None and nbytes <= bytes_limit // 4
 
 
 def device_bytes_limit(devices) -> int | None:
@@ -212,6 +215,26 @@ def device_bytes_limit(devices) -> int | None:
 #: (measured on a v5e: 9.8 ms a chunk of the paper CNN, 3.5 ms padded,
 #: 83 ms gathering (N, 28, 28, 1) as it is)
 ROW_ALIGN = 128
+
+
+def _row_bytes(v: np.ndarray, padded: bool) -> int:
+    width = math.prod(v.shape[1:])
+    if padded and v.ndim >= 2:
+        width += -width % ROW_ALIGN
+    return width * v.dtype.itemsize
+
+
+def staging_bytes(data: dict, chunk_samples: int, in_flight: int) -> int:
+    """The device bytes staging holds at once from a device store of
+    ``data``: the store (one padded row a sample), ``in_flight`` staged
+    chunks of ``chunk_samples`` samples, and one chunk of padded rows,
+    the gather's scratch before it cuts them back. A chunk is counted
+    whole on every device, though a mesh shards it over its cohorts."""
+    fields = [np.asarray(v) for v in data.values()]
+    n = len(fields[0])
+    row = sum(_row_bytes(v, padded=False) for v in fields)
+    padded = sum(_row_bytes(v, padded=True) for v in fields)
+    return n * padded + chunk_samples * (in_flight * row + padded)
 
 
 def _as_rows(v: np.ndarray) -> np.ndarray:
@@ -254,7 +277,10 @@ class DeviceStore:
         self.fields = {k: jax.device_put(_as_rows(v), sharding)
                        for k, v in data.items()}
         shapes = {k: np.shape(v)[1:] for k, v in data.items()}
-        self._gather = jax.jit(lambda f, i: _take_rows(f, i, shapes, mesh))
+
+        def device_gather(fields, idx):
+            return _take_rows(fields, idx, shapes, mesh)
+        self._gather = jax.jit(device_gather)
 
     def gather(self, idx: np.ndarray) -> dict:
         # the device gather is told its indices are in bounds: hold them
@@ -264,16 +290,20 @@ class DeviceStore:
         return self._gather(self.fields, np.asarray(idx, np.int32))
 
 
-def place_store(data: dict, mesh=None):
-    """Where staging gathers from: a ``DeviceStore`` of ``data`` when
-    ``store_fits_device`` on every device that holds it (the mesh's,
-    else the default device), else ``data`` itself (host gather)."""
+def place_store(data: dict, chunk_samples: int, in_flight: int,
+                mesh=None, placed=None):
+    """Where staging gathers chunks of ``chunk_samples`` samples from,
+    ``in_flight`` of them held at once: a ``DeviceStore`` of ``data``
+    when ``store_fits_device`` on every device that holds it (the
+    mesh's, else the default device), else ``data`` itself (host
+    gather). ``placed``, a ``DeviceStore`` of ``data`` placed before,
+    is returned where it still fits instead of uploading again."""
     devices = (list(mesh.devices.flat) if mesh is not None
                else jax.devices()[:1])
-    nbytes = sum(np.asarray(v).nbytes for v in data.values())
-    if store_fits_device(nbytes, device_bytes_limit(devices)):
-        return DeviceStore(data, mesh)
-    return data
+    need = staging_bytes(data, chunk_samples, in_flight)
+    if not store_fits_device(need, device_bytes_limit(devices)):
+        return data
+    return placed if placed is not None else DeviceStore(data, mesh)
 
 
 def stage_chunk(store, clients,
@@ -290,8 +320,8 @@ def stage_chunk(store, clients,
     scans over. Row i is bit-identical to staging round ``t0 + i``
     alone, on either path. A ``timer`` (``obs.timing.PhaseTimes``)
     books the gather as the phase ``stage_gather`` (on the device path:
-    the index upload and the dispatch), and a device gather also as
-    the counter ``stage_device`` with the same seconds.
+    the index upload and the dispatch), and a device gather also as a
+    call of the counter ``stage_device`` (zero seconds).
     """
     idx = stage_chunk_indices(clients, selected, seed, t0, steps,
                               batch_size)
@@ -299,10 +329,10 @@ def stage_chunk(store, clients,
     gather = store.gather if on_device else (lambda i: gather_chunk(store, i))
     if timer is None:
         return gather(idx)
-    with timer.phase("stage_gather") as span:
+    with timer.phase("stage_gather"):
         out = gather(idx)
     if on_device:
-        timer.add("stage_device", span.seconds)
+        timer.add("stage_device", 0.0)
     return out
 
 

@@ -12,10 +12,10 @@ path with a different data plane:
     tests/test_engine.py);
   * ``SimulationEngine`` adds the vectorized data plane
     (``data.pipeline.stage_chunk`` — one gather per chunk of rounds,
-    from a sample store kept on the device where it fits, else on the
-    host; the next chunk is staged on a host thread while the current
-    chunk runs on device), the jitted batched eval
-    (``exec.evals.Evaluator``) at an ``eval_every`` cadence, full
+    from a sample store kept on the device where it and the chunks in
+    flight fit, else on the host; the next chunk is staged on a host
+    thread while the current chunk runs on device), the jitted batched
+    eval (``exec.evals.Evaluator``) at an ``eval_every`` cadence, full
     round-state checkpointing ({params, t, aux}: async ring buffer,
     fedopt moments, ...) and the ``History`` stability metrics;
   * both run under the FL mesh (``launch.mesh.engine_mesh``) so the
@@ -47,8 +47,8 @@ from repro.checkpoint.io import restore_state, save_state
 from repro.configs.base import FLConfig
 from repro.core import strategies
 from repro.core.round import as_scan_scheds, init_state, make_train_loop
-from repro.data.pipeline import (ChunkPrefetcher, partition_plan,
-                                 place_store, stage_chunk)
+from repro.data.pipeline import (ChunkPrefetcher, DeviceStore,
+                                 partition_plan, place_store, stage_chunk)
 from repro.exec.evals import Evaluator
 from repro.obs.metrics import stability_stats
 from repro.obs.timing import PhaseTimes
@@ -228,8 +228,9 @@ class SimulationEngine:
     registered environment: schedules from ``Environment.batch``, client
     batches staged in one gather per chunk (``stage_chunk``) with the
     next chunk prefetched on a host thread, eval through the jitted
-    batched ``Evaluator``. The shared sample store is placed once, here:
-    on the device (replicated over ``mesh``) where it fits, so staging
+    batched ``Evaluator``. Each ``run`` places the shared sample store
+    for its chunks (``_place_store``): on the device (replicated over
+    ``mesh``) where it fits with the chunks staging holds, so staging
     gathers there, else on the host (``data.pipeline.place_store``).
     ``use_scan=False`` is the per-round fallback (bit-identical; the
     refactor's safety net).
@@ -279,7 +280,8 @@ class SimulationEngine:
                 "the chunked data plane stages every client from ONE "
                 "shared sample store (build clients with "
                 "data.pipeline.build_clients(data, partition))")
-        self._store = place_store(self.data, mesh)
+        # where staging gathers from: the host until a run places it
+        self._store = self.data
         self.state = init_state(model, fl, jax.random.PRNGKey(fl.seed),
                                 self.strategy)
 
@@ -315,6 +317,21 @@ class SimulationEngine:
         per_epoch = max(1, n_min // self.fl.local_batch_size)
         return self.fl.local_epochs * per_epoch
 
+    def _place_store(self, n_rounds: int) -> None:
+        """Place the sample store for chunks of up to ``n_rounds``
+        rounds (``data.pipeline.place_store``). Staging holds
+        ``prefetch_depth + 2`` chunks at once: the running one, the
+        queue's and the one the worker waits to queue; without prefetch
+        two, the running one and the next. A store already on the
+        device stays where it still fits, and is dropped where not."""
+        in_flight = (max(getattr(self.fl, "prefetch_depth", 1), 1) + 2
+                     if self.prefetch else 2)
+        samples = (n_rounds * self.fl.clients_per_round
+                   * self._steps_per_round() * self.fl.local_batch_size)
+        placed = self._store if isinstance(self._store, DeviceStore) else None
+        self._store = place_store(self.data, samples, in_flight,
+                                  self.runner.mesh, placed)
+
     def _stage(self, t0: int, n: int):
         # runs on the prefetcher's worker thread during overlapped
         # execution — PhaseTimes is thread-safe, so "stage" seconds
@@ -331,6 +348,7 @@ class SimulationEngine:
     def run_round(self) -> float:
         """One round through the engine (a chunk of 1; per-round step —
         no one-off scan program for a standalone round)."""
+        self._place_store(1)
         sb, batch = self._stage(self.t, 1)
         self.state, metrics = self.runner.run_chunk(self.state, batch, sb,
                                                     scan_ok=False)
@@ -362,6 +380,7 @@ class SimulationEngine:
             n = min((t // eval_every + 1) * eval_every, end) - t
             chunks.append((t, n))
             t += n
+        self._place_store(max((n for _, n in chunks), default=1))
         prefetcher = (ChunkPrefetcher(lambda c: self._stage(*c), chunks,
                                       depth=getattr(self.fl,
                                                     "prefetch_depth", 1))
@@ -376,6 +395,7 @@ class SimulationEngine:
                     sb, batch = next(staged)
                 self.state, metrics = self.runner.run_chunk(
                     self.state, batch, sb, scan_ok=(n == eval_every))
+                del batch       # free the chunk before staging takes more
                 hist.train_loss.extend(float(x) for x in metrics["loss"])
                 if self.logger is not None:
                     self.logger.rounds(t, metrics)

@@ -268,7 +268,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "'dense'/'virtual' force either at any K")
     ap.add_argument("--prefetch-depth", type=int, default=1,
                     help="staged chunks buffered ahead of the device "
-                         "(host memory ~ depth x chunk bytes)")
+                         "(depth + 2 chunks held at once, on the host or, "
+                         "from a device sample store, on the device)")
     ap.add_argument("--comm-plane", default="none",
                     choices=("none", "bf16", "q8", "topk"),
                     help="compressed client->server uplink (repro.comm): "

@@ -138,13 +138,14 @@ def render(s: dict, label: str = "") -> str:
                      f"uploaded on time "
                      f"({s['bytes_on_wire_total'] / 1e6 / max(s['rounds'], 1):.3f} MB/round)")
     if "phases" in s:
-        # "*_cpu" keys are CPU-seconds counters and "stage_device"
-        # repeats part of "stage_gather", not wall-clock phases: they
-        # take no share of the wall total and print on their own
-        wall = {k: v for k, v in s["phases"].items()
-                if not k.endswith("_cpu") and k != "stage_device"}
+        # "*_cpu" keys are CPU-seconds counters and zero-second keys
+        # count calls ("stage_device"), not wall-clock phases: they take
+        # no share of the wall total and print on their own
         cpu = {k: v for k, v in s["phases"].items() if k.endswith("_cpu")}
-        device = s["phases"].get("stage_device")
+        counts = {k: v for k, v in s["phases"].items()
+                  if k not in cpu and not v["seconds"]}
+        wall = {k: v for k, v in s["phases"].items()
+                if k not in cpu and k not in counts}
         total = sum(v["seconds"] for v in wall.values()) or 1.0
         breakdown = "  ".join(
             f"{k}={v['seconds']:.2f}s({v['seconds'] / total:.0%})"
@@ -155,9 +156,9 @@ def render(s: dict, label: str = "") -> str:
         if cpu:
             lines.append("cpu: " + "  ".join(
                 f"{k}={v['seconds']:.2f}s" for k, v in cpu.items()))
-        if device:
-            lines.append(f"device store: stage_device={device['calls']} "
-                         f"chunks gathered on the device")
+        if counts:
+            lines.append("calls: " + "  ".join(
+                f"{k}={v['calls']}" for k, v in counts.items()))
     return "\n".join(lines)
 
 

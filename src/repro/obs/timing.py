@@ -38,15 +38,13 @@ phases, by thread:
                       upload and the gather's dispatch
     ``stage_cpu``     a counter, not a span: the staging thread's CPU
                       seconds inside ``stage`` (``cpu=True``)
-    ``stage_device``  a counter, not a span: the seconds of
-                      ``stage_gather`` that gathered from a device store;
-                      its calls count those chunks
+    ``stage_device``  a counter, not a span: zero seconds, one call
+                      for each chunk gathered from a device store
 
 A phase's region has the phase's name unless given in brackets.
 The evaluator adds its own region, ``evaluator``, inside ``eval``.
 Phases of distinct names may overlap (staging runs beside dispatch);
-a ``*_cpu`` key holds CPU seconds, not wall time, and ``stage_device``
-repeats part of ``stage_gather``.
+a ``*_cpu`` key holds CPU seconds, not wall time.
 
 ``profile_trace`` is the ``--profile <dir>`` hook: a
 ``jax.profiler.trace`` context around the run, so the resulting
@@ -82,14 +80,12 @@ def sync_time(fn, *args, **kwargs):
 
 class _Span:
     """Yielded by ``PhaseTimes.phase``; call ``sync(tree)`` with the
-    device outputs whose completion closes the span. Once closed,
-    ``seconds`` holds what the phase booked."""
+    device outputs whose completion closes the span."""
 
-    __slots__ = ("_tree", "seconds")
+    __slots__ = ("_tree",)
 
     def __init__(self):
         self._tree = None
-        self.seconds = None
 
     def sync(self, tree):
         self._tree = tree
@@ -132,8 +128,7 @@ class PhaseTimes:
             finally:
                 if span._tree is not None:
                     _block(span._tree)
-                span.seconds = time.perf_counter() - t0
-                self.add(name, span.seconds)
+                self.add(name, time.perf_counter() - t0)
                 if cpu:
                     self.add(f"{name}_cpu", time.thread_time() - c0)
 

@@ -20,7 +20,7 @@ from repro.data.pipeline import (ChunkPrefetcher, DeviceStore,
                                  VirtualClientShards, build_clients,
                                  gather_chunk, place_store, stage_chunk,
                                  stage_chunk_indices, stage_round_indices,
-                                 store_fits_device)
+                                 staging_bytes, store_fits_device)
 from repro.obs.timing import PhaseTimes
 from repro.data.synth import make_image_classification
 from repro.exec.evals import Evaluator
@@ -134,7 +134,7 @@ def test_device_store_stages_as_the_host_gather(small_world, population,
                                                 meshed):
     """The device gather returns the host gather's batches bit for bit,
     for a dense client list and for virtual shards over the same store,
-    and books "stage_gather" and the "stage_device" counter alike."""
+    and books "stage_gather" and a call of the "stage_device" counter."""
     _, train, clients, _ = small_world
     if population == "virtual":
         clients = VirtualClientShards(train, 20, shard_size=24, seed=0)
@@ -148,7 +148,7 @@ def test_device_store_stages_as_the_host_gather(small_world, population,
         assert isinstance(got[k], jax.Array)
         np.testing.assert_array_equal(np.asarray(got[k]), want[k])
     assert timer.calls == {"stage_gather": 1, "stage_device": 1}
-    assert timer.seconds["stage_device"] == timer.seconds["stage_gather"]
+    assert timer.seconds["stage_device"] == 0.0
 
 
 def test_device_store_rejects_indices_outside_the_store(small_world):
@@ -170,8 +170,9 @@ def test_device_store_rejects_indices_outside_the_store(small_world):
     (100, 400, True), (101, 400, False), (10**9, 16 * 10**9, True),
     (100, None, False)])
 def test_store_placement_rule(store_bytes, limit, fits):
-    """On the device at up to a quarter of its memory; on the host past
-    it, or where the backend reports no memory (the CPU)."""
+    """On the device while staging takes up to a quarter of its memory;
+    on the host past it, or where the backend reports no memory (the
+    CPU)."""
     assert store_fits_device(store_bytes, limit) is fits
 
 
@@ -180,22 +181,52 @@ def test_store_placement_rule(store_bytes, limit, fits):
 def test_place_store_follows_the_reported_memory(small_world, monkeypatch,
                                                  limit, on_device):
     _, train, _, _ = small_world
-    monkeypatch.setattr(pipeline, "device_bytes_limit", lambda devs: limit)
-    store = place_store(train)
+    _limit(monkeypatch, limit)
+    store = place_store(train, 8, 3)
     assert isinstance(store, DeviceStore) is on_device
-    if not on_device:
+    if on_device:
+        assert place_store(train, 8, 3, placed=store) is store
+    else:
         assert store is train
 
 
-def _sim_on(monkeypatch, device: bool, *args, **kwargs):
-    """A simulation whose sample store the placement rule puts on the
+def test_staging_bytes_counts_store_chunks_and_scratch(small_world):
+    """The store and the gather's scratch in padded rows, the chunks in
+    flight in the samples' own shape."""
+    _, train, _, _ = small_world
+    n, img = len(train["label"]), train["image"]
+    row = img[0].size * img.itemsize + train["label"].itemsize
+    padded = (-(-img[0].size // pipeline.ROW_ALIGN) * pipeline.ROW_ALIGN
+              * img.itemsize + train["label"].itemsize)
+    assert staging_bytes(train, 100, 3) == (n + 100) * padded + 300 * row
+
+
+@pytest.mark.parametrize("samples,in_flight,on_device", [
+    (1000, 3, True), (1001, 3, False), (1000, 4, False)])
+def test_place_store_counts_the_chunks_in_flight(small_world, monkeypatch,
+                                                 samples, in_flight,
+                                                 on_device):
+    """A store that fits alone still gathers on the host where the
+    chunks staging holds beside it, and their scratch, would not fit."""
+    _, train, _, _ = small_world
+    _limit(monkeypatch, 4 * staging_bytes(train, 1000, 3))
+    assert isinstance(place_store(train, samples, in_flight),
+                      DeviceStore) is on_device
+
+
+def _limit(monkeypatch, limit):
+    """Every device reports ``limit`` bytes of memory (None: no memory
+    stats, as the CPU)."""
+    monkeypatch.setattr(pipeline, "device_bytes_limit", lambda devs: limit)
+
+
+def _run_on(monkeypatch, device: bool, sim, **kwargs):
+    """``sim.run`` with the sample store the placement rule puts on the
     device (``device``) or leaves on the host."""
-    with monkeypatch.context() as m:
-        m.setattr(pipeline, "device_bytes_limit",
-                  lambda devs: 10**12 if device else None)
-        sim = FederatedSimulation(*args, **kwargs)
+    _limit(monkeypatch, 10**12 if device else None)
+    hist = sim.run(**kwargs)
     assert isinstance(sim._store, DeviceStore) is device
-    return sim
+    return hist
 
 
 @pytest.mark.parametrize("use_scan", [True, False])
@@ -207,9 +238,10 @@ def test_device_store_engine_bit_identical_to_host_gather(small_world,
     fused scan and the per-round path, over a chunk boundary."""
     model, _, clients, test = small_world
     fl = _fl(algorithm="async_ama", max_delay=3, p_delay=0.4)
-    sims = {d: _sim_on(monkeypatch, d, model, fl, clients, test,
-                       use_scan=use_scan) for d in (True, False)}
-    hists = {d: sim.run(rounds=3, eval_every=2) for d, sim in sims.items()}
+    sims = {d: FederatedSimulation(model, fl, clients, test,
+                                   use_scan=use_scan) for d in (True, False)}
+    hists = {d: _run_on(monkeypatch, d, sim, rounds=3, eval_every=2)
+             for d, sim in sims.items()}
     assert_states_identical(sims[True].state, sims[False].state)
     for k in ("train_loss", "test_acc", "test_loss", "eval_rounds"):
         assert getattr(hists[True], k) == getattr(hists[False], k)
@@ -220,11 +252,33 @@ def test_stage_device_counts_device_chunks(small_world, monkeypatch, device):
     """"stage_device" is booked once per chunk gathered on the device,
     and never by the host gather."""
     model, _, clients, test = small_world
-    sim = _sim_on(monkeypatch, device, model, _fl(), clients, test)
-    sim.run(rounds=3, eval_every=2)
+    sim = FederatedSimulation(model, _fl(), clients, test)
+    _run_on(monkeypatch, device, sim, rounds=3, eval_every=2)
     calls = sim.timer.calls
     assert calls["stage"] == calls["stage_gather"] == 2
     assert calls.get("stage_device", 0) == (2 if device else 0)
+
+
+@pytest.mark.parametrize("depth", [1, 3])
+def test_engine_places_the_store_for_its_chunks(small_world, monkeypatch,
+                                                depth):
+    """Each run counts its own chunks in flight, prefetch_depth + 2 of
+    them: on a device that holds the store and three one-round chunks,
+    one-round chunks at depth 1 gather on the device; two-round chunks,
+    or a deeper prefetch, take the host gather, and the device store is
+    dropped."""
+    model, train, clients, test = small_world
+    sim = FederatedSimulation(model, _fl(prefetch_depth=depth), clients,
+                              test)
+    per_round = 4 * sim._steps_per_round() * 10
+    _limit(monkeypatch, 4 * staging_bytes(train, per_round, 3))
+    sim.run(rounds=2, eval_every=1)
+    assert isinstance(sim._store, DeviceStore) is (depth == 1)
+    sim.run(rounds=2, eval_every=2)
+    assert sim._store is sim.data
+    assert sim.timer.calls["stage"] == 3
+    assert sim.timer.calls.get("stage_device", 0) == (2 if depth == 1
+                                                      else 0)
 
 
 def test_chunk_prefetcher_orders_and_propagates_errors():

@@ -260,17 +260,17 @@ def test_report_prints_cpu_counters_apart():
 
 
 def test_report_prints_device_store_counter_apart():
-    """``stage_device`` repeats the device gathers' share of
-    ``stage_gather``: it takes no share of the wall total and prints
-    the chunks it counts on a line of its own."""
+    """``stage_device`` counts device-gathered chunks with zero
+    seconds: it takes no share of the wall total and prints the chunks
+    it counts on a line of its own."""
     from repro.obs.report import render, summarize
     ph = {"stage": {"seconds": 3.0, "calls": 4},
           "stage_gather": {"seconds": 1.0, "calls": 4},
-          "stage_device": {"seconds": 1.0, "calls": 4}}
+          "stage_device": {"seconds": 0.0, "calls": 4}}
     out = render(summarize([{"kind": "phases", "phases": ph}]))
     assert "stage=3.00s(75%)" in out and "stage_gather=1.00s(25%)" in out
-    assert "device store: stage_device=4 chunks" in out
-    assert "stage_device=1.00s(" not in out
+    assert "calls: stage_device=4" in out
+    assert "stage_device=0.00s(" not in out
 
 
 def test_report_cli_rejects_invalid_file(tmp_path):
