@@ -28,7 +28,9 @@ class ModelConfig:
     sliding_window: int = 0     # 0 = full attention
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
-    mlp_gated: bool = True      # SwiGLU vs plain GELU MLP
+    rotary_frac: float = 1.0    # leading share of each head's dims RoPE turns
+    mlp_act: str = "swiglu"     # swiglu (gated) | gelu | relu2 (squared ReLU)
+    norm: str = "rmsnorm"       # rmsnorm | layernorm1p (scale = weight + 1)
     # --- SSM / linear attention ---
     ssm_state: int = 0          # mamba2 state size
     conv_width: int = 4
@@ -42,7 +44,10 @@ class ModelConfig:
     num_patches: int = 0        # precomputed patch embeddings length
     vision_dim: int = 0         # stub frontend output dim (projected to d_model)
     # --- numerics / sharding ---
-    dtype: str = "bfloat16"
+    dtype: str = "bfloat16"     # compute dtype
+    param_dtype: str = ""       # master weights ("" = the compute dtype);
+                                # the client plane differentiates a
+                                # compute-dtype copy and steps the masters
     train_fsdp: bool = False    # shard params over the dsub axis during training
     serve_2d: bool = False      # 2-D tensor parallel at serving time (very large)
     remat: bool = True
@@ -66,6 +71,11 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.num_heads, 1)
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of each head that RoPE rotates (even)."""
+        return 2 * int(self.resolved_head_dim * self.rotary_frac / 2)
 
     @property
     def is_subquadratic(self) -> bool:

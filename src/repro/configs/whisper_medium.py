@@ -18,6 +18,6 @@ CONFIG = ModelConfig(
     num_heads=16,
     num_kv_heads=16,
     head_dim=64,
-    mlp_gated=False,
+    mlp_act="gelu",
     source="arXiv:2212.04356",
 )

@@ -6,6 +6,13 @@ One engine serves both scales:
     axis; no cross-client collectives inside the local scan (this is the
     defining difference from data-parallel training).
 
+Master weights: where the config keeps them in another dtype than it
+computes in (``cfg.param_dtype``, e.g. f32 masters under bf16 compute),
+each step differentiates ``model.compute_copy`` of the carry (gradients
+in the compute dtype) and applies the SGD step to the masters in f32;
+the carry stays in the masters' dtype. Where the two agree the copy is
+the params themselves and the program is unchanged.
+
 Algorithm behaviour is injected through the ServerStrategy client hooks
 (``local_grad_transform``, ``local_steps``, ``limited_mode``,
 ``static_local_steps``) — the AMA family masks FES gradients, FedProx
@@ -60,7 +67,7 @@ def make_local_train(model, fl: FLConfig, strategy=None):
 
         def step(carry, mb):
             params, i = carry
-            loss, g = grad_fn(params, mb)
+            loss, g = grad_fn(model.compute_copy(params), mb)
             g = strategy.local_grad_transform(g, params, global_params,
                                               mask, limited)
             active = i < n_active
@@ -124,9 +131,10 @@ def make_limited_local_train(model, fl: FLConfig, strategy=None):
             clf0, body = fes_lib.split_params(params0)
             clf_mask, _ = fes_lib.split_params(model.fes_mask(params0))
             clf_global, _ = fes_lib.split_params(global_params)
+            body_c = model.compute_copy(body)
 
             def step(clf, mb):
-                loss, g = grad_fn(clf, body, mb)
+                loss, g = grad_fn(model.compute_copy(clf), body_c, mb)
                 g = strategy.local_grad_transform(g, clf, clf_global,
                                                   clf_mask, True)
                 return _sgd(clf, g, fl.lr), loss
@@ -144,7 +152,7 @@ def make_limited_local_train(model, fl: FLConfig, strategy=None):
             batches = jax.tree.map(lambda x: x[:n_active], batches)
 
             def step(params, mb):
-                loss, g = grad_fn(params, mb)
+                loss, g = grad_fn(model.compute_copy(params), mb)
                 g = strategy.local_grad_transform(g, params, global_params,
                                                   mask, True)
                 return _sgd(params, g, fl.lr), loss
@@ -227,9 +235,10 @@ def make_fes_local_train(model, fl: FLConfig):
 
     def one_client(params0, batches):
         clf0, body = fes_lib.split_params(params0)
+        body_c = model.compute_copy(body)
 
         def step(clf, mb):
-            loss, g = grad_fn(clf, body, mb)
+            loss, g = grad_fn(model.compute_copy(clf), body_c, mb)
             return _sgd(clf, g, fl.lr), loss
 
         clf, losses = jax.lax.scan(step, clf0, batches)

@@ -35,7 +35,7 @@ from repro.configs.base import FLConfig
 from repro.core import strategies
 from repro.core.client import (make_fes_local_train, make_local_train,
                                make_partitioned_local_train)
-from repro.sharding.ctx import axis_size, constrain_leading
+from repro.sharding.ctx import axis_size, constrain_leading, gather_server
 
 #: partitioned-client-plane dispatch arrays (data.pipeline.partition_plan)
 #: that ride the schedule dict when fl.client_plane == "partitioned"
@@ -59,15 +59,17 @@ def as_scan_scheds(sb: dict) -> dict:
     return out
 
 
-def init_state(model, fl: FLConfig, key, strategy=None):
-    """Round-loop carry: global params, round index, strategy aux state
+def init_state(model, fl: FLConfig, key, strategy=None, params=None):
+    """Round-loop carry: global params (``model.init(key)`` unless
+    ``params`` are given), round index, strategy aux state
     (async ring buffer, fedopt moments, ... — {} for stateless rules).
     With a comm plane active (``fl.comm_plane != "none"``) the
     error-feedback residual rides the same carry under ``aux["comm"]``
     — one (C, N_g) f32 array per dtype group, C the stacked cohort
     width — so checkpoints/resume carry it like any strategy state."""
     strategy = strategy or strategies.resolve(fl)
-    params = model.init(key)
+    if params is None:
+        params = model.init(key)
     aux = strategy.init_state(params)
     from repro import comm
     plane = comm.resolve(fl)
@@ -77,6 +79,17 @@ def init_state(model, fl: FLConfig, key, strategy=None):
             aux = dict(aux)
             aux["comm"] = res
     return {"params": params, "t": jnp.zeros((), jnp.int32), "aux": aux}
+
+
+def reduces_client_axis(fl: FLConfig) -> bool:
+    """Whether the round pre-reduces the stacked client axis before the
+    server plane under the ACTIVE mesh (``fl.client_reduce``: "auto"
+    where the mesh's client axis is wider than 1, "force" always)."""
+    mode = getattr(fl, "client_reduce", "auto")
+    if mode not in ("auto", "off", "force"):
+        raise ValueError(f"unknown client_reduce {mode!r}; "
+                         "expected 'auto' | 'off' | 'force'")
+    return mode == "force" or (mode == "auto" and axis_size("client") > 1)
 
 
 def make_round_step(model, fl: FLConfig, strategy=None):
@@ -134,11 +147,27 @@ def make_round_step(model, fl: FLConfig, strategy=None):
     def round_step(state, batch, sched, _tap=None):
         t = state["t"]
         prev_global = state["params"]
+        # pre-reduce the stacked client axis when it is actually
+        # distributed (fl.client_reduce: "auto" checks the ACTIVE mesh at
+        # trace time; "force" for CPU equivalence tests): the weighted
+        # delta reduction happens BEFORE the server plane, so the
+        # per-round collective moves N, not C x N, bytes. On a 1-device
+        # mesh "auto" stays off and the fused plane keeps its
+        # bit-identity contract. On that path the server's state lives
+        # split over the client axis (sharding.ctx.server_spec): it is
+        # gathered whole here for local training, and the reduction
+        # scatters the clients' sum back onto the shards.
+        reduce = reduces_client_axis(fl)
+        train_from = prev_global
+        if reduce:
+            with jax.named_scope("server_plane"), \
+                    jax.named_scope("client_reduce"):
+                train_from = gather_server(prev_global)
         with jax.named_scope("client_plane"):
             # stacked client axis over the FL mesh ("client"); no-op
             # off-mesh
             batch = constrain_leading(batch, "client")
-            client_params, losses = local_train(prev_global, batch, sched)
+            client_params, losses = local_train(train_from, batch, sched)
             client_params = constrain_leading(client_params, "client")
         # compressed uplink: quantize/sparsify the deltas (plus carried
         # error-feedback residual), then hand the SERVER only what the
@@ -152,16 +181,8 @@ def make_round_step(model, fl: FLConfig, strategy=None):
                 groups, new_res = comm_plane.compress(
                     t, prev_global, client_params,
                     state["aux"].get("comm", {}))
-        # pre-reduce the stacked client axis when it is actually
-        # distributed (fl.client_reduce: "auto" checks the ACTIVE mesh at
-        # trace time; "force" for CPU equivalence tests): the weighted
-        # delta reduction happens BEFORE the server plane, so the
-        # per-round collective moves N, not C x N, bytes. On a 1-device
-        # mesh "auto" stays off and the fused plane keeps its
-        # bit-identity contract.
-        mode = getattr(fl, "client_reduce", "auto")
         new_params = aux = None
-        if mode == "force" or (mode == "auto" and axis_size("client") > 1):
+        if reduce:
             cp = client_params
             if comm_plane is not None:
                 with jax.named_scope("comm_plane"):
@@ -171,9 +192,6 @@ def make_round_step(model, fl: FLConfig, strategy=None):
                     t, prev_global, cp, sched, srv_aux)
             if out is not NotImplemented:
                 new_params, aux = out
-        elif mode not in ("auto", "off"):
-            raise ValueError(f"unknown client_reduce {mode!r}; "
-                             "expected 'auto' | 'off' | 'force'")
         if new_params is None and comm_plane is not None:
             # fused dequantize-accumulate: the mix family consumes the
             # compressed payload in-kernel; strategies whose update is

@@ -46,7 +46,8 @@ from repro import env as env_mod
 from repro.checkpoint.io import restore_state, save_state
 from repro.configs.base import FLConfig
 from repro.core import strategies
-from repro.core.round import as_scan_scheds, init_state, make_train_loop
+from repro.core.round import (as_scan_scheds, init_state, make_train_loop,
+                              reduces_client_axis)
 from repro.data.pipeline import (ChunkPrefetcher, DeviceStore,
                                  partition_plan, place_store, stage_chunk)
 from repro.exec.evals import Evaluator
@@ -156,6 +157,18 @@ class ChunkRunner:
         with self._ctx():
             return self._train_loop().lower(*self._last_call)
 
+    def _place(self, state):
+        """Where the round reduces over a sharded client axis, the
+        server's state lives split over it (``sharding.ctx.
+        server_spec``), as the program returns it: placed so before the
+        first dispatch, every dispatch takes one sharding (a no-op once
+        it is)."""
+        if self.mesh is None or not reduces_client_axis(self.fl):
+            return state
+        from repro.sharding.ctx import server_shardings
+        return {**state, "params": jax.device_put(
+            state["params"], server_shardings(state["params"], self.mesh))}
+
     def _ctx(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
@@ -203,6 +216,7 @@ class ChunkRunner:
         with self.timer.phase("h2d") as span:
             batch = span.sync(jax.tree.map(jnp.asarray, batch))
         with self._ctx():
+            state = self._place(state)
             loop = self._train_loop()
             if self.use_scan and scan_ok:
                 state, metrics = self._dispatch(loop, state, batch,

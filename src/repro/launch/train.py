@@ -6,8 +6,11 @@ Two configurations of ONE execution engine (``repro.exec``):
     ``--eval-every``-round chunks through the fused ``lax.scan`` engine
     (batches for a whole chunk staged in one gather, next chunk
     prefetched host-side while the device runs).
-  * --pod: C cohorts over the FL mesh view. The WHOLE run is one fused
+  * --pod: C cohorts (silos) over the FL mesh view, each trained every
+    round on fresh tokens of its own. The WHOLE run is one fused
     ``lax.scan`` program — one compile, zero per-round dispatch.
+    ``build_pod`` builds this path; the chip benchmark's cross-silo
+    driver builds through it too.
 
 ``--no-scan`` falls back to the bit-identical per-round-jit loop at
 either scale (the configuration the engine benchmarks compare against).
@@ -43,6 +46,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -121,21 +125,61 @@ def paper_scale(args, fl: FLConfig):
     return sim, hist
 
 
-def _pod_batch(cfg, fl: FLConfig, args):
-    C, steps, b, S = fl.cohorts, fl.local_steps, args.batch, args.seq
-    data = make_lm_tokens(C * steps * b, S + 1, cfg.vocab_size,
-                          n_topics=C, seed=fl.seed)
+def _pod_batches(cfg, fl: FLConfig, args, t0: int, rounds: int):
+    """Fresh tokens for every round of ``[t0, t0 + rounds)``: leaves
+    (rounds, C, steps, b, ...)."""
+    n, C, steps, b, S = rounds, fl.cohorts, fl.local_steps, args.batch, \
+        args.seq
+    data = make_lm_tokens(n * C * steps * b, S + 1, cfg.vocab_size,
+                          n_topics=C, seed=(fl.seed * 1_000_003 + t0)
+                          % 2**32)
     tokens = jnp.asarray(
-        data["tokens"][:, :S].reshape(C, steps, b, S), jnp.int32)
+        data["tokens"][:, :S].reshape(n, C, steps, b, S), jnp.int32)
     batch = {"tokens": tokens}
     if cfg.family == "vlm":
         batch["patch_emb"] = jnp.zeros(
-            (C, steps, b, cfg.num_patches, cfg.vision_dim),
+            (n, C, steps, b, cfg.num_patches, cfg.vision_dim),
             jnp.dtype(cfg.dtype))
     if cfg.family == "audio":
         batch["frame_emb"] = jnp.zeros(
-            (C, steps, b, cfg.encoder_seq, cfg.d_model), jnp.dtype(cfg.dtype))
+            (n, C, steps, b, cfg.encoder_seq, cfg.d_model),
+            jnp.dtype(cfg.dtype))
     return batch
+
+
+@dataclass
+class Pod:
+    """What the pod path runs: built once by ``build_pod``."""
+    model: object
+    fl: FLConfig
+    strategy: object
+    environment: object
+    state: dict
+    runner: ChunkRunner
+
+
+def build_pod(cfg, fl: FLConfig, *, mesh=None, params=None,
+              use_scan: bool = True) -> Pod:
+    """The cross-silo pod path for ``cfg``: ``fl.cohorts`` silos, each
+    trained every round (K = m = C) on fresh data of its own, through a
+    ``ChunkRunner`` over ``mesh`` (default ``engine_mesh(C)``, one silo
+    per chip where there are C chips). ``params`` replace
+    ``model.init``'s weights."""
+    model = build_model(cfg)
+    # pod scale's stacked client axis is the cohort count — align the
+    # config so comm-plane residual state (aux["comm"], sized by
+    # fl.clients_per_round in core.round.init_state) matches the (C, ...)
+    # client axis the round step actually carries
+    C = fl.cohorts
+    fl = fl.with_(clients_per_round=C)
+    strategy = strategies.resolve(fl)
+    state = init_state(model, fl, jax.random.PRNGKey(fl.seed), strategy,
+                       params=params)
+    environment = env_mod.resolve(fl.with_(num_clients=C))
+    runner = ChunkRunner(model, fl, strategy, per_round_batch=True,
+                         use_scan=use_scan,
+                         mesh=mesh if mesh is not None else engine_mesh(C))
+    return Pod(model, fl, strategy, environment, state, runner)
 
 
 def pod_scale(args, fl: FLConfig, mesh=None):
@@ -144,24 +188,13 @@ def pod_scale(args, fl: FLConfig, mesh=None):
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    model = build_model(cfg)
-    # pod scale's stacked client axis is the cohort count — align the
-    # config so comm-plane residual state (aux["comm"], sized by
-    # fl.clients_per_round in core.round.init_state) matches the (C, ...)
-    # client axis the round step actually carries
-    fl = fl.with_(clients_per_round=fl.cohorts)
-    strategy = strategies.resolve(fl)
-    state = init_state(model, fl, jax.random.PRNGKey(fl.seed), strategy)
+    pod = build_pod(cfg, fl, mesh=mesh, use_scan=not args.no_scan)
+    fl, state, runner = pod.fl, pod.state, pod.runner
+    environment = pod.environment
     if args.resume:
         state = restore_state(args.resume, state)
         print(f"resumed {args.resume} at round {int(state['t'])}")
     C = fl.cohorts
-    environment = env_mod.resolve(
-        fl.with_(num_clients=C, clients_per_round=C))
-    batch = _pod_batch(cfg, fl, args)
-    runner = ChunkRunner(model, fl, strategy, per_round_batch=False,
-                         use_scan=not args.no_scan,
-                         mesh=mesh if mesh is not None else engine_mesh(C))
 
     logger = _logger(args)
     if logger is not None:
@@ -169,6 +202,7 @@ def pod_scale(args, fl: FLConfig, mesh=None):
                       resumed_at=int(state["t"]) or None)
 
     t_start = int(state["t"])
+    batches = _pod_batches(runner.model.cfg, fl, args, t_start, args.rounds)
     # timing through obs.timing: perf_counter spans closed by
     # block_until_ready — JAX dispatch is async, so the seed's bare
     # time.time() around run_chunk measured enqueue, not execution
@@ -180,7 +214,8 @@ def pod_scale(args, fl: FLConfig, mesh=None):
             rows = []
             for r in range(args.rounds):
                 tr, (state, m) = sync_time(
-                    runner.run_chunk, state, batch,
+                    runner.run_chunk, state,
+                    jax.tree.map(lambda x: x[r:r + 1], batches),
                     environment.batch(t_start + r, 1), scan_ok=False)
                 dt += tr
                 rows.append(m)
@@ -193,7 +228,7 @@ def pod_scale(args, fl: FLConfig, mesh=None):
                        for k in rows[0]}
         else:
             dt, (state, metrics) = sync_time(
-                runner.run_chunk, state, batch,
+                runner.run_chunk, state, batches,
                 environment.batch(t_start, args.rounds))
             if logger is not None:
                 logger.rounds(t_start, metrics)

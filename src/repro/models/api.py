@@ -6,6 +6,7 @@
     logits, aux = model.forward(params, batch)    # full-seq (prefill)
     logits, cache = model.decode_step(params, token, position, cache)
     mask   = model.fes_mask(params)               # paper Eq.(2) split: True = classifier
+    cp     = model.compute_copy(params)           # master weights -> compute dtype
 
 ``input_specs`` builds ShapeDtypeStruct stand-ins for the multi-pod dry-run
 (no allocation). Modality frontends (audio conv codec, ViT) are stubs per
@@ -44,6 +45,20 @@ class Model:
     init_paged_pool: Callable[..., Any] | None = None
     decode_step_paged: Callable[..., Any] | None = None
     prefill_paged: Callable[..., Any] | None = None
+
+    def compute_copy(self, params):
+        """The weights in the compute dtype (``cfg.dtype``) where the
+        config keeps masters in another (``cfg.param_dtype``); the
+        client plane differentiates this copy, so gradients take the
+        compute dtype's bytes; the serving engines serve it. Where the
+        two agree, ``params`` itself (no op traced)."""
+        cfg = self.cfg
+        if not cfg.param_dtype or cfg.param_dtype == cfg.dtype:
+            return params
+        dt = jnp.dtype(cfg.dtype)
+        return jax.tree.map(
+            lambda p: p.astype(dt) if jnp.issubdtype(p.dtype, jnp.floating)
+            else p, params)
 
     def fes_mask(self, params):
         """True leaves = trainable under FES (the classifier omega^c)."""

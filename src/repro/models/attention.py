@@ -1,4 +1,6 @@
-"""Attention: GQA + RoPE + optional sliding window.
+"""Attention: GQA + RoPE (on a leading share of each head's dims,
+``cfg.rotary_dim``) + optional sliding window. The query width is
+``num_heads * head_dim``, which need not equal ``d_model``.
 
 Two execution paths:
   * ``chunked_attention`` — XLA-native online-softmax over KV chunks
@@ -141,8 +143,8 @@ def attention_fwd(p, cfg, x, positions, *, causal=True, kv_x=None,
     k = _split_heads(dense(p["wk"], kv_src), cfg.num_kv_heads, hd)
     v = _split_heads(dense(p["wv"], kv_src), cfg.num_kv_heads, hd)
     if causal or kv_x is None:           # rope only for self-attention
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, kv_pos, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+        k = apply_rope(k, kv_pos, cfg.rope_theta, cfg.rotary_dim)
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
     w = cfg.sliding_window if window is None else window
@@ -152,6 +154,23 @@ def attention_fwd(p, cfg, x, positions, *, causal=True, kv_x=None,
 
 
 # ------------------------------------------------------------- decoding ----
+
+def _row_scores(q, k):
+    """Scores (B, H, Sq, Skv) of q (B, Sq, H, hd) against k (B, Skv, H,
+    hd), as products summed over hd: each query row reduces its own
+    products in one order whatever the number of rows, so the per-token
+    decode and the chunked prefill give bitwise the same scores (a dot's
+    summation order follows its shape)."""
+    return jnp.sum(jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None]
+                   * jnp.transpose(k, (0, 2, 1, 3))[:, :, None], axis=-1)
+
+
+def _row_values(a, v):
+    """Weights a (B, H, Sq, Skv) over values v (B, Skv, H, hd) ->
+    (B, Sq, H, hd), summed per query row as ``_row_scores`` is."""
+    return jnp.sum(jnp.transpose(a, (0, 2, 1, 3))[..., None]
+                   * jnp.transpose(v, (0, 2, 1, 3))[:, None], axis=3)
+
 
 def init_kv_cache(cfg, batch, max_len, dtype):
     """Ring-buffer cache when sliding_window > 0, else linear cache."""
@@ -172,8 +191,8 @@ def attention_decode(p, cfg, x, cache, position):
     q = _split_heads(dense(p["wq"], x), cfg.num_heads, hd)
     k = _split_heads(dense(p["wk"], x), cfg.num_kv_heads, hd)
     v = _split_heads(dense(p["wv"], x), cfg.num_kv_heads, hd)
-    q = apply_rope(q, position[:, None], cfg.rope_theta)
-    k = apply_rope(k, position[:, None], cfg.rope_theta)
+    q = apply_rope(q, position[:, None], cfg.rope_theta, cfg.rotary_dim)
+    k = apply_rope(k, position[:, None], cfg.rope_theta, cfg.rotary_dim)
 
     L = cache["k"].shape[1]
     slot = (position % L).astype(jnp.int32)            # ring slot
@@ -185,7 +204,7 @@ def attention_decode(p, cfg, x, cache, position):
 
     kk = _repeat_kv(cache["k"], n_rep).astype(jnp.float32)
     vv = _repeat_kv(cache["v"], n_rep).astype(jnp.float32)
-    s = jnp.einsum("bqhd,bkhd->bhqk", (q * hd**-0.5).astype(jnp.float32), kk)
+    s = _row_scores((q * hd**-0.5).astype(jnp.float32), kk)
     valid = cache["pos"] >= 0
     mask = jnp.logical_and(valid, cache["pos"] <= position[:, None])
     if cfg.sliding_window:
@@ -193,7 +212,7 @@ def attention_decode(p, cfg, x, cache, position):
             mask, cache["pos"] > position[:, None] - cfg.sliding_window)
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
     a = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", a, vv).astype(x.dtype)
+    out = _row_values(a, vv).astype(x.dtype)
     out = out.reshape(B, 1, cfg.num_heads * hd)
     return dense(p["wo"], out), cache
 
@@ -262,8 +281,8 @@ def attention_prefill(p, cfg, x, cache, positions):
     q = _split_heads(dense(p["wq"], x), cfg.num_heads, hd)
     k = _split_heads(dense(p["wk"], x), cfg.num_kv_heads, hd)
     v = _split_heads(dense(p["wv"], x), cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
 
     L = cache["k"].shape[1]
     slots = _chunk_slots(positions, L)
@@ -282,17 +301,17 @@ def attention_prefill(p, cfg, x, cache, positions):
     kk = _repeat_kv(new["k"], n_rep).astype(jnp.float32)
     vv = _repeat_kv(new["v"], n_rep).astype(jnp.float32)
     if not cfg.sliding_window:
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
+        s = _row_scores(qf, kk)
         mask = jnp.logical_and(new["pos"][:, None, :] >= 0,
                                new["pos"][:, None, :] <= positions[..., None])
         s = jnp.where(mask[:, None], s, NEG_INF)
         a = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", a, vv).astype(x.dtype)
+        out = _row_values(a, vv).astype(x.dtype)
     else:
         kk_old = _repeat_kv(cache["k"], n_rep).astype(jnp.float32)
         vv_old = _repeat_kv(cache["v"], n_rep).astype(jnp.float32)
-        s_new = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
-        s_old = jnp.einsum("bqhd,bkhd->bhqk", qf, kk_old)
+        s_new = _row_scores(qf, kk)
+        s_old = _row_scores(qf, kk_old)
         # written[t, s]: slot s's in-chunk write happened at position <= t
         # (untouched slots keep new == old, so either branch is fine)
         written = jnp.logical_and(
@@ -308,7 +327,8 @@ def attention_prefill(p, cfg, x, cache, positions):
         a = jax.nn.softmax(s, axis=-1)
         v_eff = jnp.where(written[..., None, None],
                           vv[:, None], vv_old[:, None])
-        out = jnp.einsum("bhqk,bqkhd->bqhd", a, v_eff).astype(x.dtype)
+        out = jnp.sum(jnp.transpose(a, (0, 2, 3, 1))[..., None] * v_eff,
+                      axis=2).astype(x.dtype)
     out = out.reshape(B, c, cfg.num_heads * hd)
     return dense(p["wo"], out), new
 
@@ -366,8 +386,8 @@ def attention_decode_paged(p, cfg, x, pool, table, ring_len, position):
     q = _split_heads(dense(p["wq"], x), cfg.num_heads, hd)
     k = _split_heads(dense(p["wk"], x), cfg.num_kv_heads, hd)
     v = _split_heads(dense(p["wv"], x), cfg.num_kv_heads, hd)
-    q = apply_rope(q, position[:, None], cfg.rope_theta)
-    k = apply_rope(k, position[:, None], cfg.rope_theta)
+    q = apply_rope(q, position[:, None], cfg.rope_theta, cfg.rotary_dim)
+    k = apply_rope(k, position[:, None], cfg.rope_theta, cfg.rotary_dim)
 
     slots = (position % ring_len).astype(jnp.int32)[:, None]
     pool = _paged_write(pool, table, slots, k, v, position[:, None])
@@ -375,14 +395,14 @@ def attention_decode_paged(p, cfg, x, pool, table, ring_len, position):
     kk, vv, kpos = paged_view(pool, table)
     kk = _repeat_kv(kk, n_rep).astype(jnp.float32)
     vv = _repeat_kv(vv, n_rep).astype(jnp.float32)
-    s = jnp.einsum("bqhd,bkhd->bhqk", (q * hd**-0.5).astype(jnp.float32), kk)
+    s = _row_scores((q * hd**-0.5).astype(jnp.float32), kk)
     mask = jnp.logical_and(kpos >= 0, kpos <= position[:, None])
     if cfg.sliding_window:
         mask = jnp.logical_and(
             mask, kpos > position[:, None] - cfg.sliding_window)
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
     a = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", a, vv).astype(x.dtype)
+    out = _row_values(a, vv).astype(x.dtype)
     out = out.reshape(B, 1, cfg.num_heads * hd)
     return dense(p["wo"], out), pool
 
@@ -398,8 +418,8 @@ def attention_prefill_paged(p, cfg, x, pool, table, ring_len, positions):
     q = _split_heads(dense(p["wq"], x), cfg.num_heads, hd)
     k = _split_heads(dense(p["wk"], x), cfg.num_kv_heads, hd)
     v = _split_heads(dense(p["wv"], x), cfg.num_kv_heads, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
 
     nb, bs = pool["pos"].shape
     slots = ((positions[:, :1] + jnp.arange(c, dtype=jnp.int32))
@@ -422,17 +442,17 @@ def attention_prefill_paged(p, cfg, x, pool, table, ring_len, positions):
     kk = _repeat_kv(new_k, n_rep).astype(jnp.float32)
     vv = _repeat_kv(new_v, n_rep).astype(jnp.float32)
     if not cfg.sliding_window:
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
+        s = _row_scores(qf, kk)
         mask = jnp.logical_and(new_pos[:, None, :] >= 0,
                                new_pos[:, None, :] <= positions[..., None])
         s = jnp.where(mask[:, None], s, NEG_INF)
         a = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", a, vv).astype(x.dtype)
+        out = _row_values(a, vv).astype(x.dtype)
     else:
         kk_old = _repeat_kv(old_k, n_rep).astype(jnp.float32)
         vv_old = _repeat_kv(old_v, n_rep).astype(jnp.float32)
-        s_new = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
-        s_old = jnp.einsum("bqhd,bkhd->bhqk", qf, kk_old)
+        s_new = _row_scores(qf, kk)
+        s_old = _row_scores(qf, kk_old)
         written = jnp.logical_and(
             new_pos[:, None, :] != old_pos[:, None, :],
             new_pos[:, None, :] <= positions[..., None])
@@ -446,6 +466,7 @@ def attention_prefill_paged(p, cfg, x, pool, table, ring_len, positions):
         a = jax.nn.softmax(s, axis=-1)
         v_eff = jnp.where(written[..., None, None],
                           vv[:, None], vv_old[:, None])
-        out = jnp.einsum("bhqk,bqkhd->bqhd", a, v_eff).astype(x.dtype)
+        out = jnp.sum(jnp.transpose(a, (0, 2, 3, 1))[..., None] * v_eff,
+                      axis=2).astype(x.dtype)
     out = out.reshape(B, c, cfg.num_heads * hd)
     return dense(p["wo"], out), pool

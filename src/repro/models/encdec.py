@@ -21,7 +21,7 @@ def _enc_block_init(key, cfg, dtype):
     return {"ln1": rmsnorm_init(cfg.d_model, dtype),
             "attn": attn.attn_init(ks[0], cfg, dtype),
             "ln2": rmsnorm_init(cfg.d_model, dtype),
-            "mlp": mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype, cfg.mlp_gated)}
+            "mlp": mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype, cfg.mlp_act)}
 
 
 def _dec_block_init(key, cfg, dtype):
@@ -31,7 +31,7 @@ def _dec_block_init(key, cfg, dtype):
             "ln_x": rmsnorm_init(cfg.d_model, dtype),
             "cross_attn": attn.attn_init(ks[1], cfg, dtype),
             "ln2": rmsnorm_init(cfg.d_model, dtype),
-            "mlp": mlp_init(ks[2], cfg.d_model, cfg.d_ff, dtype, cfg.mlp_gated)}
+            "mlp": mlp_init(ks[2], cfg.d_model, cfg.d_ff, dtype, cfg.mlp_act)}
 
 
 def _stack(key, n, init_fn):
@@ -67,7 +67,7 @@ def encode(params, cfg, frame_emb):
         h = attn.attention_fwd(p["attn"], cfg, rmsnorm(p["ln1"], x), pos,
                                causal=False, window=0)
         x = x + h
-        return x + mlp(p["mlp"], rmsnorm(p["ln2"], x)), None
+        return x + mlp(p["mlp"], rmsnorm(p["ln2"], x), cfg.mlp_act), None
 
     if cfg.remat:
         body = jax.checkpoint(body)
@@ -84,7 +84,7 @@ def _dec_block_fwd(p, cfg, x, pos, enc_out, enc_pos):
                            causal=False, kv_x=enc_out, kv_positions=enc_pos,
                            window=0)
     x = x + h
-    return x + mlp(p["mlp"], rmsnorm(p["ln2"], x))
+    return x + mlp(p["mlp"], rmsnorm(p["ln2"], x), cfg.mlp_act)
 
 
 def _dec_scan(stacked, cfg, x, pos, enc_out, enc_pos):
@@ -185,7 +185,7 @@ def _dec_scan_decode(stacked, cfg, x, position, self_c, cross_c):
         h = attn.cross_attention_decode(p["cross_attn"], cfg,
                                         rmsnorm(p["ln_x"], x), ck, cv)
         x = x + h
-        x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x))
+        x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x), cfg.mlp_act)
         return x, sc
 
     n = jax.tree.leaves(stacked)[0].shape[0]
@@ -217,7 +217,7 @@ def _dec_scan_prefill(stacked, cfg, x, positions, self_c, cross_c):
         h = attn.cross_attention_decode(p["cross_attn"], cfg,
                                         rmsnorm(p["ln_x"], x), ck, cv)
         x = x + h
-        x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x))
+        x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x), cfg.mlp_act)
         return x, sc
 
     n = jax.tree.leaves(stacked)[0].shape[0]

@@ -57,41 +57,80 @@ def layernorm(p, x, eps: float = 1e-5):
     return (y * p["g"].astype(jnp.float32) + p["b"].astype(jnp.float32)).astype(x.dtype)
 
 
+def layernorm1p_init(d, dtype):
+    """LayerNorm1p (Nemotron): the stored scale is ``weight`` and the
+    applied one ``weight + 1``, so both start at zero."""
+    return {"g": jnp.zeros((d,), dtype), "b": jnp.zeros((d,), dtype)}
+
+
+def layernorm1p(p, x, eps: float = 1e-5):
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * (p["g"].astype(jnp.float32) + 1.0)
+            + p["b"].astype(jnp.float32)).astype(x.dtype)
+
+
+def norm_init(cfg, d, dtype):
+    """The block norm the config names (``cfg.norm``)."""
+    if cfg.norm == "layernorm1p":
+        return layernorm1p_init(d, dtype)
+    if cfg.norm == "rmsnorm":
+        return rmsnorm_init(d, dtype)
+    raise ValueError(f"unknown norm {cfg.norm!r}")
+
+
+def norm(cfg, p, x):
+    return layernorm1p(p, x) if cfg.norm == "layernorm1p" else rmsnorm(p, x)
+
+
 # ---------------------------------------------------------------- rotary ----
 
 def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x, positions, theta: float, rotary_dim: int = 0):
+    """x: (..., S, H, hd); positions: broadcastable to (..., S). Rotates
+    the leading ``rotary_dim`` dims of each head (0: all of them) and
+    passes the rest through (partial rotary, as in Nemotron)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
-    ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, hd/2)
-    cos = jnp.cos(ang)[..., None, :]                    # (..., S, 1, hd/2)
+    rd = rotary_dim or hd
+    freqs = rope_freqs(rd, theta)                       # (rd/2,)
+    ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, rd/2)
+    cos = jnp.cos(ang)[..., None, :]                    # (..., S, 1, rd/2)
     sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf[..., :rd], 2, axis=-1)
+    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if rd < hd:
+        parts.append(xf[..., rd:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 # ------------------------------------------------------------------- MLP ----
 
-def mlp_init(key, d_model, d_ff, dtype, gated: bool = True):
+def mlp_init(key, d_model, d_ff, dtype, act: str = "swiglu"):
     ks = jax.random.split(key, 3)
     p = {"w_in": dense_init(ks[0], d_model, d_ff, dtype),
          "w_out": dense_init(ks[1], d_ff, d_model, dtype)}
-    if gated:
+    if act == "swiglu":
         p["w_gate"] = dense_init(ks[2], d_model, d_ff, dtype)
     return p
 
 
-def mlp(p, x):
+def mlp(p, x, act: str = "swiglu"):
+    """``act``: swiglu (gated SiLU), gelu, or relu2 (squared ReLU)."""
     h = dense(p["w_in"], x)
-    if "w_gate" in p:
+    if act == "swiglu":
         h = jax.nn.silu(dense(p["w_gate"], x)) * h
-    else:
+    elif act == "relu2":
+        h = jnp.square(jax.nn.relu(h))
+    elif act == "gelu":
         h = jax.nn.gelu(h)
+    else:
+        raise ValueError(f"unknown mlp_act {act!r}")
     return dense(p["w_out"], h)
 
 

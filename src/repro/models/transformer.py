@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.models import attention as attn
 from repro.models import mamba2, moe, rwkv6
 from repro.models.layers import (dense, dense_init, embedding, embedding_init,
-                                 mlp, mlp_init, rmsnorm, rmsnorm_init)
+                                 mlp, mlp_init, norm, norm_init)
 
 
 # ------------------------------------------------------------- blocks ------
@@ -24,19 +24,19 @@ def block_init(key, cfg, dtype):
     """One block of the arch's family."""
     if cfg.family == "ssm":                       # rwkv6
         return {"rwkv": rwkv6.rwkv6_init(key, cfg, dtype),
-                "ln1": rmsnorm_init(cfg.d_model, dtype),
-                "ln2": rmsnorm_init(cfg.d_model, dtype)}
+                "ln1": norm_init(cfg, cfg.d_model, dtype),
+                "ln2": norm_init(cfg, cfg.d_model, dtype)}
     if cfg.family == "hybrid":                    # zamba2 mamba block
         return {"mamba": mamba2.mamba2_init(key, cfg, dtype),
-                "ln": rmsnorm_init(cfg.d_model, dtype)}
+                "ln": norm_init(cfg, cfg.d_model, dtype)}
     ks = jax.random.split(key, 2)
-    p = {"ln1": rmsnorm_init(cfg.d_model, dtype),
-         "ln2": rmsnorm_init(cfg.d_model, dtype),
+    p = {"ln1": norm_init(cfg, cfg.d_model, dtype),
+         "ln2": norm_init(cfg, cfg.d_model, dtype),
          "attn": attn.attn_init(ks[0], cfg, dtype)}
     if cfg.num_experts:
         p["moe"] = moe.moe_init(ks[1], cfg, dtype)
     else:
-        p["mlp"] = mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype, cfg.mlp_gated)
+        p["mlp"] = mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype, cfg.mlp_act)
     return p
 
 
@@ -52,22 +52,22 @@ def block_fwd(p, cfg, x, positions, aux):
     if cfg.family == "ssm":
         B = x.shape[0]
         st = rwkv6.init_rwkv_state(cfg, B, x.dtype)
-        h, st = rwkv6.time_mix(p["rwkv"], cfg, rmsnorm(p["ln1"], x), st)
+        h, st = rwkv6.time_mix(p["rwkv"], cfg, norm(cfg, p["ln1"], x), st)
         x = x + h
-        h, _ = rwkv6.channel_mix(p["rwkv"], rmsnorm(p["ln2"], x), st)
+        h, _ = rwkv6.channel_mix(p["rwkv"], norm(cfg, p["ln2"], x), st)
         return x + h, aux
     if cfg.family == "hybrid":
         B = x.shape[0]
         st = mamba2.init_mamba_state(cfg, B, x.dtype)
-        h, _ = mamba2.mamba2_fwd(p["mamba"], cfg, rmsnorm(p["ln"], x), st)
+        h, _ = mamba2.mamba2_fwd(p["mamba"], cfg, norm(cfg, p["ln"], x), st)
         return x + h, aux
-    h = attn.attention_fwd(p["attn"], cfg, rmsnorm(p["ln1"], x), positions)
+    h = attn.attention_fwd(p["attn"], cfg, norm(cfg, p["ln1"], x), positions)
     x = x + h
     if cfg.num_experts:
-        h, a = moe.moe_apply(p["moe"], cfg, rmsnorm(p["ln2"], x))
+        h, a = moe.moe_apply(p["moe"], cfg, norm(cfg, p["ln2"], x))
         aux = aux + a
     else:
-        h = mlp(p["mlp"], rmsnorm(p["ln2"], x))
+        h = mlp(p["mlp"], norm(cfg, p["ln2"], x), cfg.mlp_act)
     return x + h, aux
 
 
@@ -108,7 +108,7 @@ def _scan_blocks(stacked, cfg, x, positions, aux, shared_attn=None):
             x, aux = carry
             (x, aux), _ = _scan(body, (x, aux), group_p)
             h = attn.attention_fwd(
-                shared_attn["attn"], cfg, rmsnorm(shared_attn["ln"], x),
+                shared_attn["attn"], cfg, norm(cfg, shared_attn["ln"], x),
                 positions)
             return (x + h, aux), None
 
@@ -125,7 +125,7 @@ def _scan_blocks(stacked, cfg, x, positions, aux, shared_attn=None):
 # ------------------------------------------------------------- params ------
 
 def init_params(cfg, key):
-    dtype = jnp.dtype(cfg.dtype)
+    dtype = jnp.dtype(cfg.param_dtype or cfg.dtype)
     ks = jax.random.split(key, 8)
     n_tail = min(cfg.fes_tail_layers, cfg.num_layers)
     n_body = cfg.num_layers - n_tail
@@ -133,7 +133,7 @@ def init_params(cfg, key):
         "embed": embedding_init(ks[0], cfg.vocab_size, cfg.d_model, dtype),
         "body": _stacked_block_init(ks[1], cfg, n_body, dtype),
         "tail": _stacked_block_init(ks[2], cfg, n_tail, dtype),
-        "final_norm": rmsnorm_init(cfg.d_model, dtype),
+        "final_norm": norm_init(cfg, cfg.d_model, dtype),
         "lm_head": dense_init(ks[3], cfg.d_model, cfg.vocab_size, dtype),
     }
     if cfg.family == "hybrid" and cfg.attn_every:
@@ -141,7 +141,7 @@ def init_params(cfg, key):
                          num_kv_heads=cfg.num_kv_heads or 32)
         params["shared_attn"] = {
             "attn": attn.attn_init(ks[4], acfg, dtype),
-            "ln": rmsnorm_init(cfg.d_model, dtype),
+            "ln": norm_init(cfg, cfg.d_model, dtype),
         }
     if cfg.family == "vlm":
         params["vision_proj"] = dense_init(
@@ -171,7 +171,7 @@ def forward(params, cfg, batch):
                           params.get("shared_attn"))
     x, aux = _scan_blocks(params["tail"], cfg, x, positions, aux,
                           params.get("shared_attn"))
-    x = rmsnorm(params["final_norm"], x)
+    x = norm(cfg, params["final_norm"], x)
     logits = dense(params["lm_head"], x)
     return logits, aux
 
@@ -184,7 +184,7 @@ def hidden_states(params, cfg, batch):
                           params.get("shared_attn"))
     x, aux = _scan_blocks(params["tail"], cfg, x, positions, aux,
                           params.get("shared_attn"))
-    return rmsnorm(params["final_norm"], x), aux
+    return norm(cfg, params["final_norm"], x), aux
 
 
 def loss_fn(params, cfg, batch):
@@ -247,22 +247,22 @@ def block_decode(p, cfg, x, cache, position):
     """One-token block application. x: (B, 1, d)."""
     if cfg.family == "ssm":
         h, cache = rwkv6.time_mix_step(p["rwkv"], cfg,
-                                       rmsnorm(p["ln1"], x)[:, 0], cache)
+                                       norm(cfg, p["ln1"], x)[:, 0], cache)
         x = x + h[:, None]
-        h, cache = rwkv6.channel_mix(p["rwkv"], rmsnorm(p["ln2"], x)[:, 0],
+        h, cache = rwkv6.channel_mix(p["rwkv"], norm(cfg, p["ln2"], x)[:, 0],
                                      cache, single=True)
         return x + h[:, None], cache
     if cfg.family == "hybrid":
         h, cache = mamba2.mamba2_step(p["mamba"], cfg,
-                                      rmsnorm(p["ln"], x)[:, 0], cache)
+                                      norm(cfg, p["ln"], x)[:, 0], cache)
         return x + h[:, None], cache
-    h, cache = attn.attention_decode(p["attn"], cfg, rmsnorm(p["ln1"], x),
+    h, cache = attn.attention_decode(p["attn"], cfg, norm(cfg, p["ln1"], x),
                                      cache, position)
     x = x + h
     if cfg.num_experts:
-        h, _ = moe.moe_apply_dense(p["moe"], cfg, rmsnorm(p["ln2"], x))
+        h, _ = moe.moe_apply_dense(p["moe"], cfg, norm(cfg, p["ln2"], x))
     else:
-        h = mlp(p["mlp"], rmsnorm(p["ln2"], x))
+        h = mlp(p["mlp"], norm(cfg, p["ln2"], x), cfg.mlp_act)
     return x + h, cache
 
 
@@ -295,7 +295,7 @@ def _scan_blocks_decode(stacked, cfg, x, cache, position, shared_attn=None,
             gp, gc, sc = inp
             x, gc = _scan(body, x, (gp, gc))
             h, sc = attn.attention_decode(
-                shared_attn["attn"], cfg, rmsnorm(shared_attn["ln"], x), sc,
+                shared_attn["attn"], cfg, norm(cfg, shared_attn["ln"], x), sc,
                 position)
             return x + h, (gc, sc)
 
@@ -324,7 +324,7 @@ def decode_step(params, cfg, token, position, cache):
         params.get("shared_attn"), cache.get("shared"))
     x, tail_c, _ = _scan_blocks_decode(
         params["tail"], cfg, x, cache["tail"], position)
-    x = rmsnorm(params["final_norm"], x)
+    x = norm(cfg, params["final_norm"], x)
     logits = dense(params["lm_head"], x)[:, 0]
     new_cache = {"body": body_c, "tail": tail_c}
     if shared_c is not None:
@@ -339,13 +339,13 @@ def block_prefill(p, cfg, x, cache, positions):
     blocks only (ssm/hybrid keep the per-token path); the FFN half reuses
     the decode-path ops (moe_apply_dense / mlp) so the residual stream
     matches ``block_decode`` bitwise row-for-row."""
-    h, cache = attn.attention_prefill(p["attn"], cfg, rmsnorm(p["ln1"], x),
+    h, cache = attn.attention_prefill(p["attn"], cfg, norm(cfg, p["ln1"], x),
                                       cache, positions)
     x = x + h
     if cfg.num_experts:
-        h, _ = moe.moe_apply_dense(p["moe"], cfg, rmsnorm(p["ln2"], x))
+        h, _ = moe.moe_apply_dense(p["moe"], cfg, norm(cfg, p["ln2"], x))
     else:
-        h = mlp(p["mlp"], rmsnorm(p["ln2"], x))
+        h = mlp(p["mlp"], norm(cfg, p["ln2"], x), cfg.mlp_act)
     return x + h, cache
 
 
@@ -375,7 +375,7 @@ def prefill(params, cfg, tokens, positions, cache):
                                      cache["body"], positions)
     x, tail_c = _scan_blocks_prefill(params["tail"], cfg, x,
                                      cache["tail"], positions)
-    x = rmsnorm(params["final_norm"], x)
+    x = norm(cfg, params["final_norm"], x)
     logits = dense(params["lm_head"], x)
     return logits, {"body": body_c, "tail": tail_c}
 
@@ -413,18 +413,18 @@ def _scan_blocks_paged(stacked, cfg, x, pool, table, ring_len, positions,
         layer_p, layer_pool = inp
         if prefill_chunk:
             h, layer_pool = attn.attention_prefill_paged(
-                layer_p["attn"], cfg, rmsnorm(layer_p["ln1"], x),
+                layer_p["attn"], cfg, norm(cfg, layer_p["ln1"], x),
                 layer_pool, table, ring_len, positions)
         else:
             h, layer_pool = attn.attention_decode_paged(
-                layer_p["attn"], cfg, rmsnorm(layer_p["ln1"], x),
+                layer_p["attn"], cfg, norm(cfg, layer_p["ln1"], x),
                 layer_pool, table, ring_len, positions)
         x = x + h
         if cfg.num_experts:
             h, _ = moe.moe_apply_dense(layer_p["moe"], cfg,
-                                       rmsnorm(layer_p["ln2"], x))
+                                       norm(cfg, layer_p["ln2"], x))
         else:
-            h = mlp(layer_p["mlp"], rmsnorm(layer_p["ln2"], x))
+            h = mlp(layer_p["mlp"], norm(cfg, layer_p["ln2"], x), cfg.mlp_act)
         return x + h, layer_pool
 
     n = jax.tree.leaves(stacked)[0].shape[0]
@@ -442,7 +442,7 @@ def decode_step_paged(params, cfg, token, position, pool, table, ring_len):
                                    table, ring_len, position, False)
     x, tail_p = _scan_blocks_paged(params["tail"], cfg, x, pool["tail"],
                                    table, ring_len, position, False)
-    x = rmsnorm(params["final_norm"], x)
+    x = norm(cfg, params["final_norm"], x)
     logits = dense(params["lm_head"], x)[:, 0]
     return logits, {"body": body_p, "tail": tail_p}
 
@@ -455,6 +455,6 @@ def prefill_paged(params, cfg, tokens, positions, pool, table, ring_len):
                                    table, ring_len, positions, True)
     x, tail_p = _scan_blocks_paged(params["tail"], cfg, x, pool["tail"],
                                    table, ring_len, positions, True)
-    x = rmsnorm(params["final_norm"], x)
+    x = norm(cfg, params["final_norm"], x)
     logits = dense(params["lm_head"], x)
     return logits, {"body": body_p, "tail": tail_p}

@@ -81,7 +81,8 @@ class LoopEngine:
     jitted chunked prefill of the shared prefix)."""
 
     def __init__(self, model, params, prefill_chunk: int = 0):
-        self.model, self.params = model, params
+        # served weights: the compute-dtype copy of training's masters
+        self.model, self.params = model, model.compute_copy(params)
         self.prefill_chunk = int(prefill_chunk) \
             if model.prefill is not None else 0
         self._step = jax.jit(model.decode_step)
@@ -169,7 +170,8 @@ class PagedEngine:
             raise ValueError(
                 f"family {model.cfg.family!r} has no paged serving path "
                 f"(use LoopEngine)")
-        self.model, self.params = model, params
+        # served weights: the compute-dtype copy of training's masters
+        self.model, self.params = model, model.compute_copy(params)
         self.max_slots = int(max_slots)
         self.block_size = int(block_size)
         self.max_batch_tokens = int(max_batch_tokens)

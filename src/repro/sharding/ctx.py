@@ -3,7 +3,10 @@
 Model code is mesh-agnostic; ``constrain`` applies a
 with_sharding_constraint only when a mesh with the named axes is active
 and every named dim divides its axis — otherwise it is a no-op (CPU
-tests, reduced configs). The active mesh is the one entered with
+tests, reduced configs). On a sharded client axis the server's state
+lives split over it (``server_spec``): the round gathers it whole for
+local training and reduce-scatters the clients' weighted sum back onto
+the shards. The active mesh is the one entered with
 ``jax.set_mesh`` (the engine and the dry-run do), read through the
 public ``jax.sharding.get_abstract_mesh``; a bare ``with mesh:`` is not
 seen."""
@@ -51,6 +54,49 @@ def axis_size(name: str) -> int:
     return int(mesh.shape[name])
 
 
+def server_spec(shape, n: int, lead: int = 0) -> P:
+    """Where a leaf of the server's state lives over ``n`` client
+    shards: split on its largest dim that ``n`` divides (the first such
+    on a tie), past ``lead`` leading dims that stay whole; replicated
+    when ``n`` is 1 or no dim divides."""
+    axes = [None] * len(shape)
+    dims = [i for i in range(lead, len(shape)) if shape[i] % n == 0]
+    if n > 1 and dims:
+        axes[max(dims, key=lambda i: (shape[i], -i))] = "client"
+    return P(*axes)
+
+
+def server_shardings(tree, mesh):
+    """``NamedSharding`` per leaf of the server's state on ``mesh``."""
+    from jax.sharding import NamedSharding
+    n = int(mesh.shape["client"]) if "client" in mesh.axis_names else 1
+    return jax.tree.map(
+        lambda x: NamedSharding(mesh, server_spec(x.shape, n)), tree)
+
+
+def scatter_server(tree, lead: int = 0):
+    """Constrain each leaf to its shard of the server state
+    (``server_spec``) on the active mesh's client axis; a no-op where
+    that axis is 1 wide or no mesh is active."""
+    n = axis_size("client")
+    if n == 1:
+        return tree
+    return jax.tree.map(
+        lambda x: jax.lax.with_sharding_constraint(
+            x, server_spec(x.shape, n, lead)) if getattr(x, "ndim", 0)
+        else x, tree)
+
+
+def gather_server(tree):
+    """The whole server state on every client shard (an all-gather of
+    the ``scatter_server`` shards); a no-op off a sharded client axis."""
+    if axis_size("client") == 1:
+        return tree
+    return jax.tree.map(
+        lambda x: jax.lax.with_sharding_constraint(x, P())
+        if getattr(x, "ndim", 0) else x, tree)
+
+
 def reduce_leading(tree, weights):
     """Weighted sum over every leaf's LEADING (client) axis, f32.
 
@@ -59,10 +105,13 @@ def reduce_leading(tree, weights):
     aggregate + Q ring-buffer enqueue slots in one contraction). The
     input is constrained onto the mesh's "client" axis first, so on a
     sharded mesh XLA lowers this as a LOCAL partial sum followed by one
-    N-byte (or R x N) all-reduce — the per-round collective moves the
-    model size, not cohorts x model size. The contraction runs at
-    HIGHEST precision: a TPU's default f32 dot takes one bf16 pass,
-    which would round the aggregated model to 8 mantissa bits.
+    N-byte (or R x N) collective — the per-round collective moves the
+    model size, not cohorts x model size. The sum lands split over the
+    client axis (``scatter_server``), so that collective is a
+    reduce-scatter and each shard keeps its part of the server state.
+    The contraction runs at HIGHEST precision: a TPU's default f32 dot
+    takes one bf16 pass, which would round the aggregated model to 8
+    mantissa bits.
     """
     w = weights.astype(jnp.float32)
     eq = "c...,cr->r..." if w.ndim == 2 else "c...,c->..."
@@ -71,10 +120,13 @@ def reduce_leading(tree, weights):
         if not getattr(x, "ndim", 0):
             return x
         xc = constrain(x, "client", *([None] * (x.ndim - 1)))
-        return jnp.einsum(eq, xc.astype(jnp.float32), w,
-                          precision=jax.lax.Precision.HIGHEST)
+        y = jnp.einsum(eq, xc.astype(jnp.float32), w,
+                       precision=jax.lax.Precision.HIGHEST)
+        return scatter_server(y, lead=w.ndim - 1)
 
-    return jax.tree.map(red, tree)
+    # the span the trace reads the round's cross-chip reduction by
+    with jax.named_scope("client_reduce"):
+        return jax.tree.map(red, tree)
 
 
 def constrain_leading(tree, axis: str):

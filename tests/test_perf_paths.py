@@ -72,12 +72,20 @@ def test_fes_static_round_runs_and_freezes_body():
              "delays": jnp.ones((2,), jnp.int32),
              "data_sizes": jnp.ones((2,), jnp.float32)}
     p0 = jax.tree.map(jnp.copy, state["params"])
+    # the client plane leaves every cohort's body exactly as it was
+    from repro.core.client import make_fes_local_train
+    trained, _ = jax.jit(make_fes_local_train(model, fl))(p0, batch)
+    for c in range(2):
+        np.testing.assert_array_equal(
+            np.asarray(p0["embed"]["table"]),
+            np.asarray(trained["embed"]["table"][c]))
     state, metrics = step(state, batch, sched)
     assert np.isfinite(float(metrics["loss"]))
-    # body frozen up to the AMA mix with the (identical) prev body:
-    np.testing.assert_array_equal(
-        np.asarray(p0["embed"]["table"], np.float32),
-        np.asarray(state["params"]["embed"]["table"], np.float32))
+    # the AMA mix of that body with the (identical) prev body gives it
+    # back up to the rounding of Eq. 5 in the masters' f32: one ulp
+    body0 = np.asarray(p0["embed"]["table"])
+    body1 = np.asarray(state["params"]["embed"]["table"])
+    assert np.all(np.abs(body1 - body0) <= np.spacing(np.abs(body0)))
     assert not np.array_equal(
         np.asarray(p0["lm_head"]["w"], np.float32),
         np.asarray(state["params"]["lm_head"]["w"], np.float32))

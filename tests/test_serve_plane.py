@@ -24,8 +24,10 @@ from repro.serve import (KVPool, LoopEngine, PagedEngine, Request,
 
 # --------------------------------------------------------------- fixtures
 def _build(cfg):
+    """The model and its served weights: the compute-dtype copy of the
+    masters ``init`` makes (what the engines serve)."""
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.compute_copy(model.init(jax.random.PRNGKey(0)))
     return model, params
 
 
