@@ -7,10 +7,11 @@ fresh tokens of their own (``chipbench/gen/tokens.py``), one round a
 ``run_chunk``; a round is done when its loss is on the host.
 
 Set-up, in order: the seed's weights (made on a chip, kept on the host);
-the plain f32 reference's first rounds, its silos split over the chips,
-before the program's state is placed, so that the two never share a
-chip's memory; the same rounds through the program; then rounds until
-one loads no program. The window runs rounds until the deadline.
+the program's first rounds; then rounds until one loads no program. The
+window runs rounds until the deadline. The plain f32 reference replays
+the first rounds, its silos split over the chips, only when the numbers
+are asked for, after the program is released (``run.measure``): the two
+never share a chip's memory, and the reference is not set-up.
 """
 from __future__ import annotations
 
@@ -46,6 +47,8 @@ class Session:
         # the program's numpy streams take 32-bit seeds
         self.prog_seed = ctx.seed % 2**31
         self.devices = jax.devices()[:ctx.chips]
+        #: silos the reference trains at a time (all by default)
+        self.group = None
         self._ref = None
 
     def model_config(self):
@@ -63,15 +66,7 @@ class Session:
         from repro.launch.mesh import engine_mesh
         from repro.sharding.ctx import server_shardings
         mc, fl = self.model_config(), self.fl()
-        w = weights.make(ref_lm.param_specs(self.cfg), self.seed)
-        self.p0 = jax.device_get(w)
-        jax.tree.map(lambda a: a.delete(), w)
-        n = sum(x.size for x in jax.tree.leaves(self.p0))
-        self.ctx.log(f"weights: {n} a silo, on the host")
-        self._f32_reference()
-        gc.collect()
-        self.ctx.log(f"reference: {CHECK_ROUNDS} rounds, losses "
-                     f"{self._ref['loss']}")
+        self.make_weights()
         mesh = engine_mesh(fl.cohorts)
         self.pod = build_pod(mc, fl, mesh=mesh, params=jax.device_put(
             self.p0, server_shardings(self.p0, mesh)))
@@ -97,6 +92,14 @@ class Session:
             if compiles[-1] == compiles[-2]:
                 break
         self.ctx.log(f"warm: program loads after each round {compiles}")
+
+    def make_weights(self):
+        """The seed's weights, made on a chip and kept on the host."""
+        w = weights.make(ref_lm.param_specs(self.cfg), self.seed)
+        self.p0 = jax.device_get(w)
+        jax.tree.map(lambda a: a.delete(), w)
+        n = sum(x.size for x in jax.tree.leaves(self.p0))
+        self.ctx.log(f"weights: {n} a silo, on the host")
 
     def _round(self) -> dict:
         """One round of the program on fresh tokens: its metrics."""
@@ -149,7 +152,8 @@ class Session:
     # ------------------------------------------------------------------
     def reference(self, dtype=jnp.float32, fault=None) -> dict:
         return xsilo.run(self.traffic, self.cfg, self.seed, self.prog_seed,
-                         self.p0, CHECK_ROUNDS, self.devices, dtype, fault)
+                         self.p0, CHECK_ROUNDS, self.devices, dtype, fault,
+                         self.group)
 
     def numbers(self, got=None) -> dict:
         """The compared numbers of ``got`` (the program's first rounds
@@ -165,6 +169,9 @@ class Session:
     def _f32_reference(self) -> dict:
         if self._ref is None:
             self._ref = self.reference()
+            gc.collect()
+            self.ctx.log(f"reference: {CHECK_ROUNDS} rounds, losses "
+                         f"{self._ref['loss']}")
         return self._ref
 
     def control(self) -> dict:

@@ -1,10 +1,7 @@
-"""xsilo_round_mfu: FLOPs the window's cross-silo rounds require
-(chipbench/flops_lm.py) over the window's seconds, the chips and the
-chip's bf16 peak, in percent."""
+"""xsilo_round_mfu: ``round_mfu`` read in the cross-silo cell, whose
+driver counts the rounds' required FLOPs with chipbench/flops_lm.py. It
+is a metric of its own only because ``round_mfu``'s entry lists the
+cells it is read in; the reading is ``round_mfu``'s."""
+from chipbench import run
 
-
-def read(r):
-    if not r.rounds:
-        return None
-    return 100.0 * r.win["flops"] / (r.window_s * r.chips
-                                     * r.peaks["bf16_flops_per_s"])
+read = run.load_module(run.HERE / "metrics" / "round_mfu.py").read

@@ -160,21 +160,24 @@ def logits(params, c: dict, tokens):
     return layernorm1p(params["final_norm"], x) @ params["lm_head"]["w"]
 
 
-def loss(params, c: dict, tokens, shift=1):
+def loss(params, c: dict, tokens, shift=1, seqs=None):
     """Mean next-token cross entropy over a batch, tokens (B, S): each
     position predicts the token ``shift`` after it (1: the next one;
     another value, which may be traced, is a planted fault). Positions
-    with no token that far ahead are left out."""
-    S = tokens.shape[1]
+    with no token that far ahead are left out. Only the first ``seqs``
+    sequences count (all by default; fewer, which may be traced, is a
+    planted fault)."""
+    B, S = tokens.shape
+    seqs = B if seqs is None else seqs
     keep = (jnp.arange(S) < S - shift).astype(F32)
     total = 0.0
-    for b in range(tokens.shape[0]):
+    for b in range(B):
         z = logits(params, c, tokens[b]).astype(F32)
         y = jnp.roll(tokens[b], -shift)
         nll = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
             z, y[:, None], axis=-1)[:, 0]
-        total = total + jnp.sum(nll * keep)
-    return total / (tokens.shape[0] * jnp.sum(keep))
+        total = total + jnp.where(b < seqs, 1.0, 0.0) * jnp.sum(nll * keep)
+    return total / (jnp.asarray(seqs, F32) * jnp.sum(keep))
 
 
 def cast(params, dtype):
@@ -182,18 +185,19 @@ def cast(params, dtype):
 
 
 def sgd_step(params, c: dict, tokens, lr, limited, *, dtype=F32,
-             shift=1, bf16_carry=False):
+             shift=1, bf16_carry=False, seqs=None):
     """One local SGD step on a batch of sequences (B, S): -> (new
     weights, the step's loss, taken before it). ``limited`` (a traced
     bool) is the FES mask: the feature extractor's gradient is zero, so
     only the classifier moves. ``dtype`` is the arithmetic's precision
-    (f32; the control passes bfloat16 weights and bfloat16). ``shift``
-    and ``bf16_carry`` (both may be traced) plant faults: targets
-    ``shift`` tokens ahead, and the stepped weights rounded to bfloat16
-    before they are kept."""
+    (f32; the control passes bfloat16 weights and bfloat16). ``shift``,
+    ``bf16_carry`` and ``seqs`` (all may be traced) plant faults:
+    targets ``shift`` tokens ahead, the stepped weights rounded to
+    bfloat16 before they are kept, and only the first ``seqs``
+    sequences of the batch trained on."""
     with jax.default_matmul_precision("highest"):
         val, g = jax.value_and_grad(
-            lambda p: loss(cast(p, dtype), c, tokens, shift))(params)
+            lambda p: loss(cast(p, dtype), c, tokens, shift, seqs))(params)
 
     def update(path, p, gi):
         if path[0].key not in CLASSIFIER:

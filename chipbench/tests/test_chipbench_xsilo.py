@@ -16,7 +16,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 import xsilo_tiny  # noqa: E402
 
-from chipbench import control, flops_lm, run, trace  # noqa: E402
+from chipbench import control, control_xsilo, flops_lm, run, trace  # noqa: E402
 from chipbench.gen import tokens  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -123,6 +123,13 @@ def _read(name, rec):
     return run.load_module(run.HERE / "metrics" / f"{name}.py").read(rec)
 
 
+def test_xsilo_round_mfu_reads_as_round_mfu():
+    rec = _Rec(None)
+    rec.win["flops"] = 3.5e14
+    want = 100.0 * 3.5e14 / (rec.window_s * 2 * 197e12)
+    assert _read("xsilo_round_mfu", rec) == _read("round_mfu", rec) == want
+
+
 def test_reduce_ops_found_by_scope():
     mod = run.load_module(run.HERE / "metrics"
                           / "client_reduce_ms_per_round.py")
@@ -169,6 +176,37 @@ def test_sound_run_is_correct_and_control_and_faults_are_not():
     assert _fails(r["control"], ctx.limits), r["control"]
     for fault, numbers in r["faults"].items():
         assert _fails(numbers, ctx.limits), (fault, numbers)
+
+
+def test_reference_one_silo_at_a_time_reads_as_all_at_once():
+    """The reference training one silo at a time (one chip a silo's f32
+    step) differs from the stacked one only by the order of the f32 sum
+    of the silos' updates: far under every limit."""
+    from chipbench.reference import xsilo
+    ctx = xsilo_tiny.xsilo_context(7, seq=32)
+    s = run.load_module(run.HERE / "drivers" / "cohorts.py").Session(ctx)
+    s.make_weights()
+    stacked = s.reference()
+    s.group = 1
+    one = s.reference()
+    np.testing.assert_allclose(one["loss"], stacked["loss"], rtol=1e-6)
+    gaps = s.numbers(one)
+    assert all(v <= lim / 100 for v, lim in
+               ((gaps[k], ctx.limits[k]) for k in ctx.limits)), gaps
+    with pytest.raises(ValueError):
+        s.group = 3
+        s.reference(fault=xsilo.FAULTS[0])
+
+
+def test_readings_without_the_program_fail_every_fault(tmp_path):
+    """On fewer chips than the cell's the script reads the control and
+    each planted fault alone, one silo at a time; each fails a limit."""
+    from chipbench.reference import xsilo
+    ctx = xsilo_tiny.xsilo_context(12, seq=32)
+    rows = list(control_xsilo.readings(ctx, True, program=False))
+    assert [r["run"] for r in rows] == ["control", *xsilo.FAULTS]
+    for r in rows:
+        assert _fails(r["numbers"], ctx.limits), r
 
 
 FOUR = textwrap.dedent("""

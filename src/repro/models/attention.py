@@ -155,21 +155,25 @@ def attention_fwd(p, cfg, x, positions, *, causal=True, kv_x=None,
 
 # ------------------------------------------------------------- decoding ----
 
-def _row_scores(q, k):
-    """Scores (B, H, Sq, Skv) of q (B, Sq, H, hd) against k (B, Skv, H,
-    hd), as products summed over hd: each query row reduces its own
-    products in one order whatever the number of rows, so the per-token
-    decode and the chunked prefill give bitwise the same scores (a dot's
-    summation order follows its shape)."""
-    return jnp.sum(jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None]
-                   * jnp.transpose(k, (0, 2, 1, 3))[:, :, None], axis=-1)
+def _decode_rows(q, kk, vv, mask):
+    """One query row q (B, 1, H, hd) attending over kk, vv (B, Skv, H, hd)
+    where mask (B, Skv) holds -> (B, 1, H, hd). The row is doubled so that
+    both products are matrix products, summed in the order the chunked
+    prefill's are (a dot with one row may lower to a matrix-vector
+    product that sums in another order): decode and prefill then stay
+    bitwise equal."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", jnp.concatenate([q, q], axis=1), kk)
+    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+    a = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", a, vv)[:, :1]
 
 
-def _row_values(a, v):
-    """Weights a (B, H, Sq, Skv) over values v (B, Skv, H, hd) ->
-    (B, Sq, H, hd), summed per query row as ``_row_scores`` is."""
-    return jnp.sum(jnp.transpose(a, (0, 2, 1, 3))[..., None]
-                   * jnp.transpose(v, (0, 2, 1, 3))[:, None], axis=3)
+def _ring_values(a, v):
+    """Weights a (B, H, Sq, Skv) over each query row's own ring v (B, Sq,
+    Skv, H, hd) -> (B, Sq, H, hd), each row doubled as in
+    ``_decode_rows`` so that it sums in the decode's order."""
+    out = jnp.einsum("bhqrk,bqkhd->bqrhd", jnp.stack([a, a], axis=3), v)
+    return out[:, :, 0]
 
 
 def init_kv_cache(cfg, batch, max_len, dtype):
@@ -204,15 +208,13 @@ def attention_decode(p, cfg, x, cache, position):
 
     kk = _repeat_kv(cache["k"], n_rep).astype(jnp.float32)
     vv = _repeat_kv(cache["v"], n_rep).astype(jnp.float32)
-    s = _row_scores((q * hd**-0.5).astype(jnp.float32), kk)
     valid = cache["pos"] >= 0
     mask = jnp.logical_and(valid, cache["pos"] <= position[:, None])
     if cfg.sliding_window:
         mask = jnp.logical_and(
             mask, cache["pos"] > position[:, None] - cfg.sliding_window)
-    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-    a = jax.nn.softmax(s, axis=-1)
-    out = _row_values(a, vv).astype(x.dtype)
+    out = _decode_rows((q * hd**-0.5).astype(jnp.float32), kk, vv,
+                       mask).astype(x.dtype)
     out = out.reshape(B, 1, cfg.num_heads * hd)
     return dense(p["wo"], out), cache
 
@@ -301,17 +303,17 @@ def attention_prefill(p, cfg, x, cache, positions):
     kk = _repeat_kv(new["k"], n_rep).astype(jnp.float32)
     vv = _repeat_kv(new["v"], n_rep).astype(jnp.float32)
     if not cfg.sliding_window:
-        s = _row_scores(qf, kk)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
         mask = jnp.logical_and(new["pos"][:, None, :] >= 0,
                                new["pos"][:, None, :] <= positions[..., None])
         s = jnp.where(mask[:, None], s, NEG_INF)
         a = jax.nn.softmax(s, axis=-1)
-        out = _row_values(a, vv).astype(x.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", a, vv).astype(x.dtype)
     else:
         kk_old = _repeat_kv(cache["k"], n_rep).astype(jnp.float32)
         vv_old = _repeat_kv(cache["v"], n_rep).astype(jnp.float32)
-        s_new = _row_scores(qf, kk)
-        s_old = _row_scores(qf, kk_old)
+        s_new = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
+        s_old = jnp.einsum("bqhd,bkhd->bhqk", qf, kk_old)
         # written[t, s]: slot s's in-chunk write happened at position <= t
         # (untouched slots keep new == old, so either branch is fine)
         written = jnp.logical_and(
@@ -327,8 +329,7 @@ def attention_prefill(p, cfg, x, cache, positions):
         a = jax.nn.softmax(s, axis=-1)
         v_eff = jnp.where(written[..., None, None],
                           vv[:, None], vv_old[:, None])
-        out = jnp.sum(jnp.transpose(a, (0, 2, 3, 1))[..., None] * v_eff,
-                      axis=2).astype(x.dtype)
+        out = _ring_values(a, v_eff).astype(x.dtype)
     out = out.reshape(B, c, cfg.num_heads * hd)
     return dense(p["wo"], out), new
 
@@ -395,14 +396,12 @@ def attention_decode_paged(p, cfg, x, pool, table, ring_len, position):
     kk, vv, kpos = paged_view(pool, table)
     kk = _repeat_kv(kk, n_rep).astype(jnp.float32)
     vv = _repeat_kv(vv, n_rep).astype(jnp.float32)
-    s = _row_scores((q * hd**-0.5).astype(jnp.float32), kk)
     mask = jnp.logical_and(kpos >= 0, kpos <= position[:, None])
     if cfg.sliding_window:
         mask = jnp.logical_and(
             mask, kpos > position[:, None] - cfg.sliding_window)
-    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
-    a = jax.nn.softmax(s, axis=-1)
-    out = _row_values(a, vv).astype(x.dtype)
+    out = _decode_rows((q * hd**-0.5).astype(jnp.float32), kk, vv,
+                       mask).astype(x.dtype)
     out = out.reshape(B, 1, cfg.num_heads * hd)
     return dense(p["wo"], out), pool
 
@@ -442,17 +441,17 @@ def attention_prefill_paged(p, cfg, x, pool, table, ring_len, positions):
     kk = _repeat_kv(new_k, n_rep).astype(jnp.float32)
     vv = _repeat_kv(new_v, n_rep).astype(jnp.float32)
     if not cfg.sliding_window:
-        s = _row_scores(qf, kk)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
         mask = jnp.logical_and(new_pos[:, None, :] >= 0,
                                new_pos[:, None, :] <= positions[..., None])
         s = jnp.where(mask[:, None], s, NEG_INF)
         a = jax.nn.softmax(s, axis=-1)
-        out = _row_values(a, vv).astype(x.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", a, vv).astype(x.dtype)
     else:
         kk_old = _repeat_kv(old_k, n_rep).astype(jnp.float32)
         vv_old = _repeat_kv(old_v, n_rep).astype(jnp.float32)
-        s_new = _row_scores(qf, kk)
-        s_old = _row_scores(qf, kk_old)
+        s_new = jnp.einsum("bqhd,bkhd->bhqk", qf, kk)
+        s_old = jnp.einsum("bqhd,bkhd->bhqk", qf, kk_old)
         written = jnp.logical_and(
             new_pos[:, None, :] != old_pos[:, None, :],
             new_pos[:, None, :] <= positions[..., None])
@@ -466,7 +465,6 @@ def attention_prefill_paged(p, cfg, x, pool, table, ring_len, positions):
         a = jax.nn.softmax(s, axis=-1)
         v_eff = jnp.where(written[..., None, None],
                           vv[:, None], vv_old[:, None])
-        out = jnp.sum(jnp.transpose(a, (0, 2, 3, 1))[..., None] * v_eff,
-                      axis=2).astype(x.dtype)
+        out = _ring_values(a, v_eff).astype(x.dtype)
     out = out.reshape(B, c, cfg.num_heads * hd)
     return dense(p["wo"], out), pool

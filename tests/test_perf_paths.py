@@ -59,9 +59,9 @@ def test_chunked_attention_unaligned_cross():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_fes_static_round_runs_and_freezes_body():
-    """H3-it1: the fes_static round trains only the classifier."""
-    cfg = reduced(ARCHS["minitron-8b"])
+def _fes_static_round(cfg):
+    """One fes_static round of ``cfg``: the params before, the state and
+    metrics after, and the model and batch it ran on."""
     model = build_model(cfg)
     fl = FLConfig(algorithm="ama_fes", fes_static=True, lr=0.05)
     state = init_state(model, fl, jax.random.PRNGKey(0))
@@ -72,23 +72,42 @@ def test_fes_static_round_runs_and_freezes_body():
              "delays": jnp.ones((2,), jnp.int32),
              "data_sizes": jnp.ones((2,), jnp.float32)}
     p0 = jax.tree.map(jnp.copy, state["params"])
-    # the client plane leaves every cohort's body exactly as it was
+    state, metrics = step(state, batch, sched)
+    assert np.isfinite(float(metrics["loss"]))
+    assert not np.array_equal(
+        np.asarray(p0["lm_head"]["w"], np.float32),
+        np.asarray(state["params"]["lm_head"]["w"], np.float32))
+    return p0, state, model, fl, batch
+
+
+def test_fes_static_round_runs_and_freezes_body():
+    """H3-it1: the fes_static round trains only the classifier."""
+    # masters in the compute dtype, as this test has always pinned
+    p0, state, *_ = _fes_static_round(
+        reduced(ARCHS["minitron-8b"]).with_(param_dtype=""))
+    # body frozen up to the AMA mix with the (identical) prev body:
+    np.testing.assert_array_equal(
+        np.asarray(p0["embed"]["table"], np.float32),
+        np.asarray(state["params"]["embed"]["table"], np.float32))
+
+
+def test_fes_static_round_f32_masters_body_within_ulp():
+    """With f32 masters under bf16 compute the client plane leaves every
+    cohort's body exactly as it was, and the AMA mix of that body with
+    the (identical) prev body gives it back up to the rounding of Eq. 5
+    in f32: one ulp."""
     from repro.core.client import make_fes_local_train
+    p0, state, model, fl, batch = _fes_static_round(
+        reduced(ARCHS["minitron-8b"]))
+    assert p0["embed"]["table"].dtype == jnp.float32
     trained, _ = jax.jit(make_fes_local_train(model, fl))(p0, batch)
     for c in range(2):
         np.testing.assert_array_equal(
             np.asarray(p0["embed"]["table"]),
             np.asarray(trained["embed"]["table"][c]))
-    state, metrics = step(state, batch, sched)
-    assert np.isfinite(float(metrics["loss"]))
-    # the AMA mix of that body with the (identical) prev body gives it
-    # back up to the rounding of Eq. 5 in the masters' f32: one ulp
     body0 = np.asarray(p0["embed"]["table"])
     body1 = np.asarray(state["params"]["embed"]["table"])
     assert np.all(np.abs(body1 - body0) <= np.spacing(np.abs(body0)))
-    assert not np.array_equal(
-        np.asarray(p0["lm_head"]["w"], np.float32),
-        np.asarray(state["params"]["lm_head"]["w"], np.float32))
 
 
 def test_constrain_noop_without_mesh():
