@@ -36,15 +36,64 @@ def _conv(x, w):
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
+def _corners(x):
+    """The four (..., H/2, W/2, C) corners of the 2x2 windows of
+    (..., H, W, C), in row-major window order: (0,0), (0,1), (1,0), (1,1).
+    Taken from a (..., H/2, 2, W/2, 2, C) view, not by strided slices,
+    which lower to extra layout copies on a TPU."""
+    *lead, h, w, c = x.shape
+    if h % 2 or w % 2:
+        raise ValueError(f"2x2 pooling needs even H and W, got {h}x{w}")
+    win = x.reshape(*lead, h // 2, 2, w // 2, 2, c)
+    return (win[..., 0, :, 0, :], win[..., 0, :, 1, :],
+            win[..., 1, :, 0, :], win[..., 1, :, 1, :])
+
+
+@jax.custom_vjp
+def max_pool_2x2(x):
+    """2x2 / stride-2 max-pool of (..., H, W, C) with even H and W.
+
+    Equal to ``lax.reduce_window(max)`` bit for bit, and so is its
+    gradient: each window's output gradient goes to the first position,
+    in row-major window order, that holds the maximum, as the
+    ``select_and_scatter`` gradient of ``reduce_window`` routes it
+    (autodiff of a plain max would split a tie). Both directions are
+    elementwise ops on the windows' corners, which XLA fuses on a TPU;
+    ``reduce_window`` and ``select_and_scatter`` are slow there.
+    """
+    a, b, c, d = _corners(x)
+    return jnp.maximum(jnp.maximum(jnp.maximum(a, b), c), d)
+
+
+def _max_pool_fwd(x):
+    y = max_pool_2x2(x)
+    return y, (x, y)
+
+
+def _max_pool_bwd(res, g):
+    x, y = res
+    taken = jnp.zeros(y.shape, bool)
+    grads = []
+    for corner in _corners(x):
+        hit = (corner == y) & ~taken
+        grads.append(jnp.where(hit, g, jnp.zeros((), g.dtype)))
+        taken = taken | hit
+    # back to (..., H/2, 2, W/2, 2, C): each window row, then the two rows
+    top = jnp.stack(grads[:2], axis=-2)
+    bottom = jnp.stack(grads[2:], axis=-2)
+    return (jnp.stack([top, bottom], axis=-4).reshape(x.shape),)
+
+
+max_pool_2x2.defvjp(_max_pool_fwd, _max_pool_bwd)
+
+
 def forward(params, cfg, batch):
     """batch: {"image": (B, 28, 28, 1)} -> logits (B, n_classes)."""
     x = batch["image"].astype(jnp.float32)
     x = jax.nn.relu(_conv(x, params["body"]["conv1"]["w"]))     # (B,24,24,10)
-    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                              (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    x = max_pool_2x2(x)
     x = jax.nn.relu(_conv(x, params["body"]["conv2"]["w"]))     # (B,8,8,20)
-    x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                              (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    x = max_pool_2x2(x)
     x = x.reshape(x.shape[0], -1)                               # (B, 320)
     x = jax.nn.relu(dense(params["fc1"], x))
     x = jax.nn.relu(dense(params["fc2"], x))
