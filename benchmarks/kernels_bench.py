@@ -2,11 +2,9 @@
 fused oracles (the TPU timing story lives in the roofline; these numbers
 prove the kernels run and give a per-call CSV).
 
-The server-side rows go through the SAME entry points the round engine
-dispatches (``repro.kernels.server_plane``): the jitted fused oracle for
-CPU timing and the interpret-mode Pallas kernels for body validation —
-the deep (K, N)-swept fused-vs-unfused comparison is
-``benchmarks/server_plane.py``.
+The rows go through the SAME entry points the round engine dispatches
+(``repro.kernels.server_plane``): the jitted fused oracle for CPU timing
+and the interpret-mode Pallas kernels for body validation.
 """
 from __future__ import annotations
 
@@ -16,9 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.rwkv6_scan import rwkv6_scan
 from repro.kernels.server_plane import (server_adam_flat, server_async_flat,
                                         server_mix_flat, _ref_adam,
                                         _ref_async, _ref_mix)
@@ -86,34 +81,6 @@ def run(quick=False, smoke=False):
                          scalars, block=8192, interpret=True),
         _ref_adam(sl(prev), sl(stacked), sl(m), sl(v), sizes, keep,
                   scalars)), "allclose"))
-
-    # --- flash attention ---
-    B, S, H, hd = 1, (128 if smoke else 256 if quick else 512), 4, 64
-    q = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.3
-    k = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.3
-    vv = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32)
-    ref_attn = jax.jit(lambda q, k, v: ref.flash_attention_ref(q, k, v))
-    us = _time(ref_attn, q, k, vv)
-    rows.append((f"attention_ref_cpu_S{S}", us, ""))
-    got = flash_attention(q, k, vv, interpret=True)
-    err = float(jnp.max(jnp.abs(got - ref_attn(q, k, vv))))
-    rows.append(("flash_attention_interpret_maxerr", err, "allclose"))
-
-    # --- rwkv6 scan ---
-    B, S, H, hd = 2, (64 if smoke else 128 if quick else 512), 4, 64
-    r = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.5
-    kk = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.5
-    vv = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32)
-    ww = jnp.asarray(rng.rand(B, S, H, hd) * 0.5 + 0.4, jnp.float32)
-    u = jnp.asarray(rng.randn(H, hd) * 0.1, jnp.float32)
-    s0 = jnp.zeros((B, H, hd, hd), jnp.float32)
-    ref_scan = jax.jit(lambda *a: ref.rwkv6_scan_ref(*a))
-    us = _time(lambda *a: ref_scan(*a)[0], r, kk, vv, ww, u, s0)
-    rows.append((f"rwkv6_scan_ref_cpu_S{S}", us, ""))
-    y, _ = rwkv6_scan(r, kk, vv, ww, u, s0, chunk=64, interpret=True)
-    y2, _ = ref_scan(r, kk, vv, ww, u, s0)
-    err = float(jnp.max(jnp.abs(y - y2)))
-    rows.append(("rwkv6_scan_interpret_maxerr", err, "allclose"))
 
     for name, val, extra in rows:
         print(f"kernel,{name},{val},{extra}")

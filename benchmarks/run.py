@@ -7,9 +7,9 @@
 
 Every sub-benchmark routes through the current registries (server
 strategies, environments) and the fused server-plane API — the engine
-throughput and server-plane sweeps with committed baselines are
-``benchmarks/sim_engine.py`` and ``benchmarks/server_plane.py``
-(gated in CI by ``scripts/check_bench.py``). The roofline sweep
+throughput sweep with a committed baseline is
+``benchmarks/sim_engine.py`` (gated in CI by
+``scripts/check_bench.py``). The roofline sweep
 (40 pairs, heavy compiles) stays separate:
 ``python benchmarks/roofline.py``.
 """
@@ -52,9 +52,8 @@ def main() -> None:
         from benchmarks import ablation_alpha
         ablation_alpha.run()
 
-    print("# done. engine/server-plane sweeps: benchmarks/sim_engine.py, "
-          "benchmarks/server_plane.py; roofline: experiments/roofline.md "
-          "(python benchmarks/roofline.py)")
+    print("# done. engine sweep: benchmarks/sim_engine.py; roofline: "
+          "experiments/roofline.md (python benchmarks/roofline.py)")
 
 
 if __name__ == "__main__":

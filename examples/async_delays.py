@@ -15,7 +15,7 @@ import numpy as np
 from repro import env as env_mod
 from repro.configs.base import FLConfig
 from repro.configs.registry import ARCHS
-from repro.core.async_ama import mixing_weights
+from repro.core.strategies.async_ama import mixing_weights
 from repro.core.simulation import FederatedSimulation
 from repro.data.partition import shard_partition
 from repro.data.pipeline import build_clients

@@ -290,10 +290,8 @@ def check_kernel_oracles(kernel_modules=None,
     identical positional signature. Both the kernel module list and the
     oracle module are injectable so a fixture can rename an oracle."""
     if kernel_modules is None:
-        from repro.kernels import (ama_mix, flash_attention, rwkv6_scan,
-                                   server_plane)
-        kernel_modules = [ama_mix, flash_attention, rwkv6_scan,
-                          server_plane]
+        from repro.kernels import server_plane
+        kernel_modules = [server_plane]
     if ref_module is None:
         from repro.kernels import ref as ref_module
     findings = []

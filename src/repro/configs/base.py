@@ -163,11 +163,6 @@ class FLConfig:
     server_b1: float = 0.9
     server_b2: float = 0.99
     server_tau: float = 1e-3
-    # route every strategy's mix step through the fused Pallas ama_mix
-    # kernel (interpret-mode off-TPU; see repro.kernels.ops). Applies to
-    # the LEGACY aggregate() path only; the round engine dispatches the
-    # fused server plane below.
-    use_kernel: bool = False
     # the server-plane implementation the round engine dispatches
     # (core.round.make_round_step -> ServerStrategy.fused_server_update):
     #   "fused"     — one fused pass per round (weights, delta
@@ -177,7 +172,6 @@ class FLConfig:
     #   "ref"       — always the flat jnp oracle (kernels/ref.py)
     #   "interpret" — the Pallas kernel through the interpreter
     #                 (kernel-body validation; slow, tests only)
-    #   "legacy"    — the original per-leaf aggregate() chain
     server_plane: str = "fused"
     # compressed client->server uplink (repro.comm registry):
     #   "none" — dense full-precision deltas (bit-identical legacy path)

@@ -1,5 +1,3 @@
-from repro.core.ama import ama_aggregate, ama_mix, alpha_schedule, fedavg_aggregate
-from repro.core.async_ama import async_ama_aggregate, init_queue, enqueue, mixing_weights
 from repro.core.client import make_local_train, make_fes_local_train
 from repro.core.round import (make_round_step, make_train_loop,
                               make_train_step_for_lowering, init_state)

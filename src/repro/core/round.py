@@ -4,8 +4,9 @@
 suitable for pjit on the production mesh: C client cohorts train in
 parallel on the "client" mesh axis with NO cross-client collectives
 during local steps; the server aggregation — one fused server-plane
-kernel pass over the client axis (``strategy.fused_server_update``) —
-is the only cross-cohort communication of the round — the paper's
+kernel pass over the client axis (``strategy.fused_server_update``), or
+its pre-reduced form on a sharded client axis — is the only
+cross-cohort communication of the round — the paper's
 rare-global-aggregation pattern, TPU-native.
 
 ``make_train_loop`` goes one step further: it rolls N rounds into one
@@ -181,35 +182,28 @@ def make_round_step(model, fl: FLConfig, strategy=None):
                 groups, new_res = comm_plane.compress(
                     t, prev_global, client_params,
                     state["aux"].get("comm", {}))
-        new_params = aux = None
+        # the one place that chooses the server update, from what the
+        # round can observe: a sharded client axis pre-reduces; a comm
+        # plane feeds its payload straight to a rule that consumes it,
+        # and is densified for any other; otherwise ONE fused
+        # server-plane pass (fl.server_plane selects the impl)
         if reduce:
             cp = client_params
             if comm_plane is not None:
                 with jax.named_scope("comm_plane"):
                     cp = comm_plane.reconstruct(prev_global, groups)
             with jax.named_scope("server_plane"):
-                out = strategy.reduced_server_update(
+                new_params, aux = strategy.reduced_server_update(
                     t, prev_global, cp, sched, srv_aux)
-            if out is not NotImplemented:
-                new_params, aux = out
-        if new_params is None and comm_plane is not None:
-            # fused dequantize-accumulate: the mix family consumes the
-            # compressed payload in-kernel; strategies whose update is
-            # not linear in the deltas return NotImplemented and take
-            # the densified fallback below
+        elif comm_plane is not None and strategy.consumes_compressed:
             with jax.named_scope("server_plane"):
-                out = strategy.compressed_server_update(
+                new_params, aux = strategy.compressed_server_update(
                     t, prev_global, groups, sched, srv_aux)
-            if out is not NotImplemented:
-                new_params, aux = out
-            else:
+        else:
+            if comm_plane is not None:
                 with jax.named_scope("comm_plane"):
                     client_params = comm_plane.reconstruct(prev_global,
                                                            groups)
-        if new_params is None:
-            # ONE fused server-plane pass: staleness weights, delta
-            # accumulation, ring-buffer mix and (fedopt) server-Adam in
-            # a single kernel dispatch (fl.server_plane selects the impl)
             with jax.named_scope("server_plane"):
                 new_params, aux = strategy.fused_server_update(
                     t, prev_global, client_params, sched, srv_aux)

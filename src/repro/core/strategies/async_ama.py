@@ -1,18 +1,74 @@
-"""Asynchronous AMA (paper Eqs. 6-11) as a ServerStrategy.
+"""Asynchronous AMA (paper §IV-B, Eqs. 6-11) as a ServerStrategy.
 
-The O(max_delay) ring buffer of gamma^- pre-weighted pending updates is
+Delayed updates from round n arriving at round t enter the aggregation with
+a staleness weight
+
+    gamma_i^- = b * (1 - sigmoid(t - n))          (Eq. 9)
+    alpha^-   = 1 - sigmoid(1)
+
+normalised so the "old knowledge" budget alpha + sum(gamma_i) equals the AMA
+schedule alpha0 + eta*t (Eq. 8) and alpha + beta + sum(gamma) = 1 (Eq. 7):
+
+    alpha   = alpha^- / (alpha^- + sum_i gamma_i^-) * (alpha0 + eta t)
+    gamma_i = gamma_i^- / (alpha^- + sum_i gamma_i^-) * (alpha0 + eta t)
+
+Server-side state is a RING BUFFER over arrival rounds: an update sent at
+round n with delay d arrives at n+d; its staleness d is known at send time,
+so the server accumulates gamma^-(d) * omega into slot (n+d) % Q together
+with the scalar sum of gamma^-. At round t the slot t % Q holds exactly
+sum_i gamma_i^- omega_ni and sum_i gamma_i^- — O(max_delay) parameter
+buffers regardless of client count, which is what makes the paper's scheme
+feasible when omega is billions of parameters. The buffer is
 strategy-owned aux state: it rides the round-loop carry (including
-through the fused ``lax.scan`` engine) instead of living as a special
-"queue" field the round loop has to know about.
+through the fused ``lax.scan`` engine).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core import async_ama
+from repro.configs.base import FLConfig
 from repro.core.strategies.ama import AMAStrategy
 from repro.core.strategies.base import register
+from repro.core.strategies.mix import alpha_schedule
+
+#: alpha^- = 1 - sigmoid(1) (paper Eq. 9), computed as sigmoid(-1): the
+#: same float32 expression ``gamma_unnorm`` gives at staleness 1, b = 1
+ALPHA_UNNORM = jax.nn.sigmoid(-1.0)
+
+
+def gamma_unnorm(fl: FLConfig, staleness):
+    """gamma_i^- = b * (1 - sigmoid(staleness)); staleness = t - n >= 1.
+
+    Computed as b * sigmoid(-s): algebraically identical, but avoids the
+    catastrophic cancellation of 1 - sigmoid(s) for stale updates (f32
+    1-sigmoid(15) loses all significant digits)."""
+    s = jnp.asarray(staleness, jnp.float32)
+    return fl.staleness_b * jax.nn.sigmoid(-s)
+
+
+def init_queue(fl: FLConfig, params_like):
+    """Ring buffer of gamma^- pre-weighted pending sums.
+
+    Q = max_delay + 1 slots so an update with the maximum delay, enqueued
+    at round t, never collides with the slot being drained at round t.
+    """
+    Q = max(fl.max_delay, 1) + 1
+    zeros = jax.tree.map(
+        lambda x: jnp.zeros((Q,) + x.shape, jnp.float32), params_like)
+    return {"sum": zeros, "gamma": jnp.zeros((Q,), jnp.float32)}
+
+
+def mixing_weights(fl: FLConfig, t, staleness_list):
+    """Reference computation of (alpha, beta, gammas) for a set of stale
+    updates — used by tests/benchmarks to check Eqs. 7-11 analytically."""
+    A = float(alpha_schedule(fl, t))
+    g_un = [float(gamma_unnorm(fl, s)) for s in staleness_list]
+    denom = float(ALPHA_UNNORM) + sum(g_un)
+    alpha = float(ALPHA_UNNORM) / denom * A
+    gammas = [g / denom * A for g in g_un]
+    beta = 1.0 - A
+    return alpha, beta, gammas
 
 
 @register
@@ -20,9 +76,13 @@ class AsyncAMAStrategy(AMAStrategy):
     name = "async_ama"
     aliases = ()
     stateful = True
+    # the ring-buffer enqueue needs the DENSE delayed updates (they
+    # persist across rounds at full precision): the round densifies a
+    # compressed payload before ``fused_server_update``
+    consumes_compressed = False
 
     def init_state(self, params):
-        return {"queue": async_ama.init_queue(self.fl, params)}
+        return {"queue": init_queue(self.fl, params)}
 
     def mix_coefficient(self, t, sched, aux_state):
         """The REALIZED Eq. 10 alpha of this round: the Eq. 8 budget
@@ -35,41 +95,15 @@ class AsyncAMAStrategy(AMAStrategy):
         Q = aux_state["queue"]["gamma"].shape[0]
         delays = sched["delays"]
         arrival = (jnp.asarray(t, jnp.int32) + delays) % Q
-        g = (async_ama.gamma_unnorm(fl, delays)
-             * sched["delayed"].astype(jnp.float32))
+        g = gamma_unnorm(fl, delays) * sched["delayed"].astype(jnp.float32)
         onehot = jax.nn.one_hot(arrival, Q, dtype=jnp.float32) * g[:, None]
         qgamma = aux_state["queue"]["gamma"] + jnp.sum(onehot, axis=0)
         stale_gamma = qgamma[jnp.asarray(t, jnp.int32) % Q]
-        A = jnp.minimum(fl.alpha0 + fl.eta * jnp.asarray(t, jnp.float32),
-                        fl.alpha_cap)
-        return async_ama.ALPHA_UNNORM / (async_ama.ALPHA_UNNORM
-                                         + stale_gamma) * A
-
-    def aggregate(self, t, prev_global, client_params, sched, aux_state):
-        on_time = jnp.logical_not(sched["delayed"])
-        queue = async_ama.enqueue(self.fl, aux_state["queue"], t,
-                                  client_params, sched["delayed"],
-                                  sched["delays"])
-        new_global, queue = async_ama.async_ama_aggregate(
-            self.fl, t, prev_global, client_params, sched["data_sizes"],
-            on_time, queue, use_kernel=self.fl.use_kernel)
-        return new_global, {"queue": queue}
-
-    def compressed_server_update(self, t, prev_global, groups, sched,
-                                 aux_state):
-        """The ring-buffer enqueue needs the DENSE delayed updates (they
-        persist across rounds at full precision), so the AMA-family
-        compressed hook this class inherits does not apply — revert to
-        NotImplemented and let the round engine densify the payload
-        before ``fused_server_update``."""
-        del t, prev_global, groups, sched, aux_state
-        return NotImplemented
+        A = alpha_schedule(fl, t)
+        return ALPHA_UNNORM / (ALPHA_UNNORM + stale_gamma) * A
 
     def fused_server_update(self, t, prev_global, client_params, sched,
                             aux_state):
-        if self.server_impl == "legacy":
-            return self.aggregate(t, prev_global, client_params, sched,
-                                  aux_state)
         from repro.kernels.server_plane import server_async_tree
         new_global, queue = server_async_tree(
             prev_global, client_params, aux_state["queue"],

@@ -1,66 +1,20 @@
 """Naive FL baseline (the paper's "FedAvg"): weighted average of the
 clients that both finished (not computing-limited) and arrived on time;
-no mixing with the previous model, no staleness handling."""
+no mixing with the previous model, no staleness handling — the alpha=0
+corner of the mix (``strategies.mix``)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.ama import fedavg_aggregate
-from repro.core.strategies.base import (ServerStrategy,
-                                        reduced_mix_update, register)
+from repro.core.strategies.base import register
+from repro.core.strategies.mix import MixStrategy, on_time
 
 
 @register
-class FedAvgStrategy(ServerStrategy):
+class FedAvgStrategy(MixStrategy):
     name = "fedavg"
+    adaptive = False
 
-    def aggregate(self, t, prev_global, client_params, sched, aux_state):
-        del t
-        on_time = jnp.logical_not(sched["delayed"])
-        keep = jnp.logical_and(on_time, jnp.logical_not(sched["limited"]))
-        new_global = fedavg_aggregate(prev_global, client_params,
-                                      sched["data_sizes"], keep,
-                                      use_kernel=self.fl.use_kernel)
-        return new_global, aux_state
-
-    def fused_server_update(self, t, prev_global, client_params, sched,
-                            aux_state):
-        if self.server_impl == "legacy":
-            return self.aggregate(t, prev_global, client_params, sched,
-                                  aux_state)
-        from repro.kernels.server_plane import mix_coefs, server_mix_tree
-        keep = jnp.logical_and(
-            jnp.logical_not(sched["delayed"]),
-            jnp.logical_not(sched["limited"])).astype(jnp.float32)
-        # adaptive=False zeroes the alpha schedule: the plain weighted
-        # average is the alpha=0 corner of the same fused pass
-        new_global = server_mix_tree(
-            prev_global, client_params, sched["data_sizes"], keep,
-            mix_coefs(self.fl, t, adaptive=False), impl=self.server_impl)
-        return new_global, aux_state
-
-    def compressed_server_update(self, t, prev_global, groups, sched,
-                                 aux_state):
-        """The alpha=0 corner of the compressed mix: keep drops limited
-        AND delayed clients, schedule zeroed."""
-        if self.server_impl == "legacy":
-            return NotImplemented
-        from repro.kernels.server_plane import (mix_coefs,
-                                                server_mix_compressed_tree)
-        keep = jnp.logical_and(
-            jnp.logical_not(sched["delayed"]),
-            jnp.logical_not(sched["limited"])).astype(jnp.float32)
-        new_global = server_mix_compressed_tree(
-            prev_global, groups, sched["data_sizes"], keep,
-            mix_coefs(self.fl, t, adaptive=False), impl=self.server_impl)
-        return new_global, aux_state
-
-    def reduced_server_update(self, t, prev_global, client_params, sched,
-                              aux_state):
-        from repro.kernels.server_plane import mix_coefs
-        keep = jnp.logical_and(
-            jnp.logical_not(sched["delayed"]),
-            jnp.logical_not(sched["limited"])).astype(jnp.float32)
-        return reduced_mix_update(
-            prev_global, client_params, sched["data_sizes"], keep,
-            mix_coefs(self.fl, t, adaptive=False)), aux_state
+    def keep(self, sched):
+        return jnp.logical_and(on_time(sched),
+                               jnp.logical_not(sched["limited"]))

@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.ama import normalize_weights, weighted_client_sum
 from repro.core.strategies.ama import AMAStrategy
 from repro.core.strategies.base import register
 
@@ -25,6 +24,9 @@ class FedOptStrategy(AMAStrategy):
     name = "fedopt"
     aliases = ()
     stateful = True
+    # server-Adam is nonlinear in the aggregated pseudo-gradient (second
+    # moment, rsqrt): the round densifies a compressed payload first
+    consumes_compressed = False
 
     def init_state(self, params):
         zeros = lambda: jax.tree.map(
@@ -39,59 +41,8 @@ class FedOptStrategy(AMAStrategy):
         del t, sched, aux_state
         return jnp.float32(0.0)
 
-    def aggregate(self, t, prev_global, client_params, sched, aux_state):
-        del t  # fedopt keys its schedule on its own step counter
-        fl = self.fl
-        on_time = jnp.logical_not(sched["delayed"])
-        w, tot = normalize_weights(sched["data_sizes"], on_time)
-        agg = weighted_client_sum(client_params, w)
-        agg = jax.tree.map(lambda a, p: jnp.where(tot > 0, a, p),
-                           agg, prev_global)
-
-        delta = jax.tree.map(
-            lambda a, p: a.astype(jnp.float32) - p.astype(jnp.float32),
-            agg, prev_global)
-        step = aux_state["step"] + 1
-        m = jax.tree.map(lambda mm, d: fl.server_b1 * mm
-                         + (1.0 - fl.server_b1) * d, aux_state["m"], delta)
-        v = jax.tree.map(lambda vv, d: fl.server_b2 * vv
-                         + (1.0 - fl.server_b2) * d * d, aux_state["v"], delta)
-        sf = step.astype(jnp.float32)
-        bc1 = 1.0 - fl.server_b1 ** sf
-        bc2 = 1.0 - fl.server_b2 ** sf
-        update = jax.tree.map(
-            lambda mm, vv: (mm / bc1)
-            / (jnp.sqrt(vv / bc2) + fl.server_tau), m, v)
-
-        if fl.use_kernel:
-            # prev + lr*update == 1.0*prev + sum_k w_k stacked_k with
-            # K=1, w=[lr]: the general fused-mix kernel, not a special case
-            from repro.kernels.ops import ama_mix_tree
-            stacked = jax.tree.map(lambda u: u[None], update)
-            new_global = ama_mix_tree(prev_global, stacked, 1.0,
-                                      jnp.full((1,), fl.server_lr))
-        else:
-            new_global = jax.tree.map(
-                lambda p, u: (p.astype(jnp.float32)
-                              + fl.server_lr * u).astype(p.dtype),
-                prev_global, update)
-        return new_global, {"m": m, "v": v, "step": step}
-
-    def compressed_server_update(self, t, prev_global, groups, sched,
-                                 aux_state):
-        """Server-Adam is nonlinear in the aggregated pseudo-gradient
-        (second moment, rsqrt), so the linear compressed mix this class
-        inherits from AMA does not describe it — revert to
-        NotImplemented; the round engine densifies the payload and
-        dispatches the fused Adam plane."""
-        del t, prev_global, groups, sched, aux_state
-        return NotImplemented
-
     def fused_server_update(self, t, prev_global, client_params, sched,
                             aux_state):
-        if self.server_impl == "legacy":
-            return self.aggregate(t, prev_global, client_params, sched,
-                                  aux_state)
         from repro.kernels.server_plane import server_adam_tree
         keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
         step = aux_state["step"] + 1

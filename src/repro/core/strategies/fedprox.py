@@ -1,19 +1,24 @@
 """FedProx baseline (paper Eq. 4): proximal gradient pull toward the
 global model plus "partial work" — computing-limited devices run a
-fraction of the local steps instead of masking gradients."""
+fraction of the local steps instead of masking gradients. Server side it
+is the on-time weighted average, the alpha=0 corner of the mix
+(``strategies.mix``)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.ama import fedavg_aggregate
-from repro.core.strategies.base import (ServerStrategy,
-                                        reduced_mix_update, register)
+from repro.core.strategies.base import register
+from repro.core.strategies.mix import MixStrategy, on_time
 
 
 @register
-class FedProxStrategy(ServerStrategy):
+class FedProxStrategy(MixStrategy):
     name = "fedprox"
+    adaptive = False
+
+    def keep(self, sched):
+        return on_time(sched)
 
     def local_grad_transform(self, grads, params, global_params, fes_mask,
                              limited):
@@ -34,44 +39,3 @@ class FedProxStrategy(ServerStrategy):
         cohort's program scans only this many steps — the masked plane
         computes the full scan and discards the gradients instead."""
         return max(1, int(self.fl.fedprox_partial * n_steps))
-
-    def aggregate(self, t, prev_global, client_params, sched, aux_state):
-        del t
-        on_time = jnp.logical_not(sched["delayed"])
-        new_global = fedavg_aggregate(prev_global, client_params,
-                                      sched["data_sizes"], on_time,
-                                      use_kernel=self.fl.use_kernel)
-        return new_global, aux_state
-
-    def fused_server_update(self, t, prev_global, client_params, sched,
-                            aux_state):
-        if self.server_impl == "legacy":
-            return self.aggregate(t, prev_global, client_params, sched,
-                                  aux_state)
-        from repro.kernels.server_plane import mix_coefs, server_mix_tree
-        keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
-        new_global = server_mix_tree(
-            prev_global, client_params, sched["data_sizes"], keep,
-            mix_coefs(self.fl, t, adaptive=False), impl=self.server_impl)
-        return new_global, aux_state
-
-    def compressed_server_update(self, t, prev_global, groups, sched,
-                                 aux_state):
-        """On-time weighted average (alpha=0) over compressed deltas."""
-        if self.server_impl == "legacy":
-            return NotImplemented
-        from repro.kernels.server_plane import (mix_coefs,
-                                                server_mix_compressed_tree)
-        keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
-        new_global = server_mix_compressed_tree(
-            prev_global, groups, sched["data_sizes"], keep,
-            mix_coefs(self.fl, t, adaptive=False), impl=self.server_impl)
-        return new_global, aux_state
-
-    def reduced_server_update(self, t, prev_global, client_params, sched,
-                              aux_state):
-        from repro.kernels.server_plane import mix_coefs
-        keep = jnp.logical_not(sched["delayed"]).astype(jnp.float32)
-        return reduced_mix_update(
-            prev_global, client_params, sched["data_sizes"], keep,
-            mix_coefs(self.fl, t, adaptive=False)), aux_state

@@ -31,7 +31,7 @@ ring-buffer mix, server-Adam — dispatches as ONE fused server-plane
 kernel call (``ServerStrategy.fused_server_update`` →
 ``repro.kernels.server_plane``) on both the chunked-scan path and the
 ``--no-scan`` per-round path; ``fl.server_plane`` selects the impl
-("fused" | "ref" | "legacy").
+("fused" | "ref" | "interpret").
 """
 from __future__ import annotations
 

@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels a benchmark cell runs (server_plane.py) and their
+# pure-jnp oracles (ref.py). A kernel stays only while a cell runs it.

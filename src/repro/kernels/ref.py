@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth).
+"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth),
+and plain attention, the reference of the model's chunked attention.
 
 Each ``server_*_math`` oracle is two shared halves that the fused
 server-plane kernels (``kernels/server_plane.py``) call too:
@@ -25,17 +26,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-
-def ama_mix_ref(prev, stacked, alpha, weights):
-    """alpha*prev + sum_k weights[k]*stacked[k], f32 accumulation.
-
-    prev: (N,) or any shape; stacked: (K, *prev.shape); weights: (K,).
-    """
-    acc = alpha.astype(jnp.float32) * prev.astype(jnp.float32)
-    acc = acc + jnp.einsum(
-        "k...,k->...", stacked.astype(jnp.float32), weights.astype(jnp.float32))
-    return acc.astype(prev.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +149,7 @@ def server_async_coefs(qgamma, sizes, delayed, delays, tq, hyp):
     offsets)."""
     K, Q = sizes.shape[0], qgamma.shape[0]
     t, pop = tq[0], tq[1]
-    alpha_un = 1.0 - jax.nn.sigmoid(1.0)                    # Eq. 9
+    alpha_un = jax.nn.sigmoid(-1.0)             # Eq. 9: 1 - sigmoid(1)
     g = (hyp[3] * jax.nn.sigmoid(-delays.astype(jnp.float32))
          * delayed.astype(jnp.float32))                     # (K,) gamma^-
     arrival = (t + delays) % Q                              # (K,)
@@ -301,21 +291,3 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-def rwkv6_scan_ref(r, k, v, w, u, s0):
-    """RWKV-6 recurrence oracle.
-
-    r/k/v/w: (B, S, H, hd) f32 (w in (0,1)); u: (H, hd); s0: (B, H, hd, hd).
-    Returns (y (B,S,H,hd), s_final).
-    """
-    def step(S_, inp):
-        r_t, k_t, v_t, w_t = inp
-        kv = k_t[..., :, None] * v_t[..., None, :]
-        y = jnp.einsum("bhi,bhij->bhj", r_t, S_ + u[..., None] * kv)
-        S_ = w_t[..., :, None] * S_ + kv
-        return S_, y
-
-    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (r, k, v, w))
-    s_fin, ys = jax.lax.scan(step, s0, xs)
-    return jnp.moveaxis(ys, 0, 1), s_fin

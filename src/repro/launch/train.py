@@ -275,18 +275,12 @@ def build_parser() -> argparse.ArgumentParser:
                          "fused chunked scan (both scales)")
     ap.add_argument("--eval-every", type=int, default=1,
                     help="paper scale: eval cadence == scan chunk length")
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="route the LEGACY aggregate path's mix through "
-                         "the fused Pallas ama_mix (interpret-mode "
-                         "off-TPU); only meaningful with "
-                         "--server-plane legacy")
     ap.add_argument("--server-plane", default="fused",
-                    choices=("fused", "ref", "interpret", "legacy"),
+                    choices=("fused", "ref", "interpret"),
                     help="server-update implementation: one fused pass "
                          "per round (default; pallas on TPU, flat oracle "
-                         "off-TPU), the flat jnp oracle, the Pallas "
-                         "interpreter (validation only), or the "
-                         "pre-fusion per-leaf aggregate chain")
+                         "off-TPU), the flat jnp oracle, or the Pallas "
+                         "interpreter (validation only)")
     ap.add_argument("--client-plane", default="masked",
                     choices=("masked", "partitioned"),
                     help="mixed-cohort client execution: one masked "
@@ -363,7 +357,6 @@ def fl_config(args) -> FLConfig:
                   p_limited=args.p_limited,
                   p_delay=args.p_delay, max_delay=args.max_delay,
                   trace_path=args.trace_path,
-                  use_kernel=args.use_kernel,
                   server_plane=args.server_plane,
                   client_plane=args.client_plane,
                   population=args.population,

@@ -2,13 +2,11 @@
 ``cfg.rotary_dim``) + optional sliding window. The query width is
 ``num_heads * head_dim``, which need not equal ``d_model``.
 
-Two execution paths:
-  * ``chunked_attention`` — XLA-native online-softmax over KV chunks
-    (lax.scan). O(S * chunk) transient memory, compiles on any backend;
-    this is what the multi-pod dry-run lowers.
-  * ``kernels.flash_attention`` — a Pallas TPU kernel of the same math,
-    tested in interpret mode only. No model imports it: every model
-    path, on a TPU too, runs ``chunked_attention``.
+Every model path, on a TPU too, runs ``chunked_attention``: an
+XLA-native online-softmax over KV chunks (lax.scan), O(S * chunk)
+transient memory, compiling on any backend; this is what the multi-pod
+dry-run lowers. ``kernels.ref.flash_attention_ref`` is its plain
+softmax reference.
 
 Decode uses a KV cache; sliding-window archs use a ring-buffer cache of
 size ``window`` so the long_500k cache is O(window), not O(S).

@@ -10,7 +10,6 @@ Faithful to arXiv:2404.05892 at the block level:
 
 The recurrence runs as a lax.scan over time (projections are computed for
 the whole sequence in parallel; only the O(d*hd) state update is serial).
-``kernels/rwkv6_scan.py`` provides the Pallas chunked version for TPU.
 """
 from __future__ import annotations
 

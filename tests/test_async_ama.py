@@ -1,7 +1,8 @@
 """Asynchronous AMA (paper Eqs. 6-11): weighting scheme + ring buffer.
 
-The ring buffer is validated against a NAIVE event-list simulator that
-literally keeps every delayed update and applies Eqs. 9-11 at arrival.
+The ring buffer of the fused server plane is validated against a NAIVE
+event-list simulator that literally keeps every delayed update and
+applies Eqs. 9-11 at arrival.
 """
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,8 @@ hyp = pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.configs.base import FLConfig
-from repro.core import async_ama as aa
+from repro.core import strategies
+from repro.core.strategies import async_ama as aa
 
 
 def test_gamma_matches_paper_formula():
@@ -55,9 +57,10 @@ def test_ring_buffer_vs_event_list():
     fl = FLConfig(alpha0=0.1, eta=2.5e-3, staleness_b=0.6, max_delay=4,
                   clients_per_round=3)
     C = fl.clients_per_round
+    strat = strategies.resolve(fl)
     prev_rb = _params(rng)
     prev_ev = jax.tree.map(jnp.copy, prev_rb)
-    queue = aa.init_queue(fl, prev_rb)
+    aux = strat.init_state(prev_rb)
     pending_events = []   # (arrival_t, sent_t, params)
 
     for t in range(12):
@@ -65,13 +68,13 @@ def test_ring_buffer_vs_event_list():
         sizes = jnp.asarray(rng.rand(C) + 0.5, jnp.float32)
         delayed = rng.rand(C) < 0.5
         delays = np.where(delayed, rng.randint(1, fl.max_delay + 1, C), 1)
-        on_time = jnp.asarray(~delayed)
 
         # --- ring buffer path
-        queue = aa.enqueue(fl, queue, t, client_params,
-                           jnp.asarray(delayed), jnp.asarray(delays))
-        prev_rb, queue = aa.async_ama_aggregate(
-            fl, t, prev_rb, client_params, sizes, on_time, queue)
+        sched = {"data_sizes": sizes, "delayed": jnp.asarray(delayed),
+                 "delays": jnp.asarray(delays, jnp.int32),
+                 "limited": jnp.zeros((C,), bool)}
+        prev_rb, aux = strat.fused_server_update(t, prev_rb, client_params,
+                                                 sched, aux)
 
         # --- event list path
         for i in range(C):
@@ -102,17 +105,20 @@ def test_ring_buffer_vs_event_list():
 
 def test_sync_limit_no_delays_equals_plain_ama():
     """With no delayed updates the async path must reduce to Eq. 5."""
-    from repro.core.ama import ama_aggregate
     rng = np.random.RandomState(1)
     fl = FLConfig(alpha0=0.15, eta=1e-3, max_delay=5)
     prev = _params(rng)
     C = 4
     cp = {"w": jnp.asarray(rng.randn(C, 4, 3), jnp.float32)}
-    sizes = jnp.ones((C,), jnp.float32)
-    on_time = jnp.ones((C,), bool)
-    queue = aa.init_queue(fl, prev)
-    got, _ = aa.async_ama_aggregate(fl, 3, prev, cp, sizes, on_time, queue)
-    want = ama_aggregate(fl.with_(max_delay=0) if hasattr(fl, "with_")
-                         else fl, 3, prev, cp, sizes, on_time)
+    sched = {"data_sizes": jnp.ones((C,), jnp.float32),
+             "delayed": jnp.zeros((C,), bool),
+             "delays": jnp.ones((C,), jnp.int32),
+             "limited": jnp.zeros((C,), bool)}
+    strat = strategies.resolve(fl)
+    assert isinstance(strat, strategies.AsyncAMAStrategy)
+    got, _ = strat.fused_server_update(3, prev, cp, sched,
+                                       strat.init_state(prev))
+    want, _ = strategies.resolve(fl.with_(max_delay=0)).fused_server_update(
+        3, prev, cp, sched, {})
     np.testing.assert_allclose(np.asarray(got["w"]), np.asarray(want["w"]),
                                rtol=1e-5)

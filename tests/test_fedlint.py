@@ -166,8 +166,8 @@ def test_fed104_print_in_pallas_kernel_fires():
 
 
 def test_fed104_ref_store_from_nested_loop_body_is_clean():
-    # regression: rwkv6's fori step writes the enclosing kernel's output
-    # ref — the kernel write idiom, not a closure mutation
+    # regression: a fori step that writes the enclosing kernel's output
+    # ref is the kernel write idiom, not a closure mutation
     src = """
         import jax
         import jax.experimental.pallas as pl

@@ -1,111 +1,31 @@
-"""Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles
-(interpret mode — executes the kernel body on CPU)."""
+"""The sync server-plane kernel body (interpret mode — executes the
+Pallas kernel on CPU) against its jnp oracle over a sweep of sizes:
+padding (N not a multiple of 128 lanes), one block and many blocks;
+K = 1, a few, and the paper CNN cell's 10 clients a round."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ama_mix import ama_mix_flat
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ops import ama_mix_tree
-from repro.kernels.rwkv6_scan import rwkv6_scan
+from repro.kernels.server_plane import server_mix_flat
 
 
 @pytest.mark.parametrize("N", [100, 1024, 4096 + 17])
 @pytest.mark.parametrize("K", [1, 4, 10])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ama_mix_sweep(N, K, dtype):
+def test_server_mix_sweep(N, K, dtype):
     rng = np.random.RandomState(N + K)
     prev = jnp.asarray(rng.randn(N), dtype)
     stacked = jnp.asarray(rng.randn(K, N), dtype)
-    alpha = jnp.float32(rng.rand())
-    w = jnp.asarray(rng.rand(K), jnp.float32)
-    got = ama_mix_flat(prev, stacked, alpha, w, block=1024, interpret=True)
-    want = ref.ama_mix_ref(prev, stacked, alpha, w)
-    tol = 1e-3 if dtype == jnp.float32 else 2e-2
+    sizes = jnp.asarray(rng.rand(K) + 0.5, jnp.float32)
+    keep = jnp.asarray((rng.rand(K) < 0.7).astype(np.float32))
+    coefs = jnp.asarray([0.1, 2.5e-3, 0.95, 7.0], jnp.float32)
+    got = server_mix_flat(prev, stacked, sizes, keep, coefs, block=1024,
+                          interpret=True)
+    want = ref.server_mix_math(prev, stacked, sizes, keep, coefs)
+    assert got.dtype == prev.dtype and got.shape == (N,)
+    # the same op sequence on both sides: 1-2 ulp of the dtype
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
-
-
-def test_ama_mix_tree_matches_eq5():
-    """Kernel tree-mix == alpha*prev + (1-alpha)*weighted avg (Eq. 5)."""
-    rng = np.random.RandomState(0)
-    prev = {"w": jnp.asarray(rng.randn(7, 9), jnp.float32),
-            "b": jnp.asarray(rng.randn(13), jnp.float32)}
-    K = 3
-    stacked = {"w": jnp.asarray(rng.randn(K, 7, 9), jnp.float32),
-               "b": jnp.asarray(rng.randn(K, 13), jnp.float32)}
-    alpha = jnp.float32(0.25)
-    wts = jnp.asarray([0.2, 0.3, 0.5], jnp.float32) * (1 - 0.25)
-    got = ama_mix_tree(prev, stacked, alpha, wts, interpret=True)
-    for kk in prev:
-        want = 0.25 * np.asarray(prev[kk]) + np.einsum(
-            "k...,k->...", np.asarray(stacked[kk]), np.asarray(wts))
-        np.testing.assert_allclose(np.asarray(got[kk]), want, rtol=1e-4,
-                                   atol=1e-5)
-
-
-@pytest.mark.parametrize("S,block", [(128, 64), (256, 128), (384, 128)])
-@pytest.mark.parametrize("window", [0, 96])
-@pytest.mark.parametrize("hd", [64, 128])
-def test_flash_attention_sweep(S, block, window, hd):
-    if S % block:
-        pytest.skip("block must divide S")
-    rng = np.random.RandomState(S + window + hd)
-    B, H = 2, 2
-    q = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.3
-    k = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.3
-    v = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32)
-    got = flash_attention(q, k, v, causal=True, window=window,
-                          block_q=block, block_k=block, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=3e-4, atol=3e-4)
-
-
-def test_flash_attention_bf16():
-    rng = np.random.RandomState(0)
-    B, S, H, hd = 1, 128, 2, 64
-    q = jnp.asarray(rng.randn(B, S, H, hd), jnp.bfloat16) * 0.3
-    k = jnp.asarray(rng.randn(B, S, H, hd), jnp.bfloat16) * 0.3
-    v = jnp.asarray(rng.randn(B, S, H, hd), jnp.bfloat16)
-    got = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
-    want = ref.flash_attention_ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=3e-2, atol=3e-2)
-
-
-@pytest.mark.parametrize("S,chunk", [(64, 16), (128, 128), (96, 32)])
-@pytest.mark.parametrize("hd", [16, 64])
-def test_rwkv6_scan_sweep(S, chunk, hd):
-    rng = np.random.RandomState(S + hd)
-    B, H = 2, 2
-    r = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.5
-    k = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.5
-    v = jnp.asarray(rng.randn(B, S, H, hd), jnp.float32)
-    w = jnp.asarray(rng.rand(B, S, H, hd) * 0.5 + 0.4, jnp.float32)
-    u = jnp.asarray(rng.randn(H, hd) * 0.1, jnp.float32)
-    s0 = jnp.asarray(rng.randn(B, H, hd, hd) * 0.1, jnp.float32)
-    y, sf = rwkv6_scan(r, k, v, w, u, s0, chunk=chunk, interpret=True)
-    y2, sf2 = ref.rwkv6_scan_ref(r, k, v, w, u, s0)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y2), rtol=1e-4,
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(sf), np.asarray(sf2), rtol=1e-4,
-                               atol=1e-5)
-
-
-def test_rwkv6_kernel_state_carries_across_chunks():
-    """Chunked kernel result must be invariant to the chunk size."""
-    rng = np.random.RandomState(7)
-    B, S, H, hd = 1, 64, 1, 16
-    args = [jnp.asarray(rng.randn(B, S, H, hd), jnp.float32) * 0.4
-            for _ in range(3)]
-    w = jnp.asarray(rng.rand(B, S, H, hd) * 0.4 + 0.5, jnp.float32)
-    u = jnp.asarray(rng.randn(H, hd) * 0.1, jnp.float32)
-    s0 = jnp.zeros((B, H, hd, hd), jnp.float32)
-    y16, _ = rwkv6_scan(*args[:3], w, u, s0, chunk=16, interpret=True)
-    y64, _ = rwkv6_scan(*args[:3], w, u, s0, chunk=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(y16), np.asarray(y64), rtol=1e-5,
-                               atol=1e-6)

@@ -245,6 +245,43 @@ def test_paper_cnn_round_program_is_unchanged():
     assert hashlib.sha256(text.encode()).hexdigest() == CNN_ROUND_SHA256
 
 
+#: sha256 of the lowered text of the cross-silo round with the client
+#: axis pre-reduced (``client_reduce="force"``): the tiny Minitron block
+#: in bf16 compute over f32 masters, 4 silos of 2 steps of 2 x 32
+#: tokens, the cell's own AMA-FES traffic, on a one-device
+#: ("client", "dsub", "model") mesh. It is the server path the
+#: four-chip cell runs (``reduced_server_update``); a deliberate change
+#: to that program updates this digest.
+REDUCED_ROUND_SHA256 = ("c84d0dfc5b4415baeb6791e53f28eb28"
+                        "b31fa11299caa3af61a78ef7d663831d")
+
+
+def _reduced_round_lowered():
+    tr = xsilo_tiny.xsilo_context(seq=32).traffic
+    fl = FLConfig(**{**tr["fl"], "client_reduce": "force"})
+    model = build_model(_program_cfg("bfloat16"))
+    state = jax.eval_shape(lambda: init_state(model, fl,
+                                              jax.random.PRNGKey(0)))
+    C, steps = fl.cohorts, fl.local_steps
+    batch = {"tokens": jax.ShapeDtypeStruct((C, steps, 2, 32), jnp.int32)}
+    sched = {"limited": jax.ShapeDtypeStruct((C,), jnp.bool_),
+             "delayed": jax.ShapeDtypeStruct((C,), jnp.bool_),
+             "delays": jax.ShapeDtypeStruct((C,), jnp.int32),
+             "data_sizes": jax.ShapeDtypeStruct((C,), jnp.float32)}
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
+                             ("client", "dsub", "model"))
+    with jax.set_mesh(mesh):
+        return jax.jit(make_round_step(model, fl)).lower(
+            state, batch, sched)
+
+
+def test_reduced_round_program_is_unchanged():
+    lowered = _reduced_round_lowered()
+    assert "client_reduce" in lowered.as_text(debug_info=True)
+    text = lowered.as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == REDUCED_ROUND_SHA256
+
+
 def test_paper_cnn_round_program_pools_without_window_ops():
     """Max-pooling lowers to elementwise ops (``models/cnn.py``
     ``max_pool_2x2``): no ``reduce_window`` forward, no

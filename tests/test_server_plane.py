@@ -1,14 +1,16 @@
 """The fused server-plane kernel suite (repro.kernels.server_plane).
 
 Three layers of nets:
-  * kernel-body parity — every server-plane Pallas kernel (and the
-    pre-existing ama_mix) against its jnp oracle in interpret mode on
-    CPU: f32 AND bf16 inputs, non-multiple-of-block N (the padding
-    path), K=1 edge case. Tolerances are 1-2 ulp: the op sequence is
-    shared, only XLA's shape-dependent FMA contraction differs.
+  * kernel-body parity — every server-plane Pallas kernel against its
+    jnp oracle in interpret mode on CPU: f32 AND bf16 inputs,
+    non-multiple-of-block N (the padding path), K=1 edge case.
+    Tolerances are 1-2 ulp: the op sequence is shared, only XLA's
+    shape-dependent FMA contraction differs.
   * strategy routing — all five registered strategies produce the same
     update through every ``fl.server_plane`` impl ("fused" == "ref"
-    bit-identical off-TPU; "interpret" and "legacy" allclose).
+    bit-identical off-TPU; "interpret" allclose), and the sync plane
+    over a real model's multi-dtype parameter tree is the plain
+    per-leaf rule (tests/plain_server.py).
   * engine — the fused plane inside the real chunked-scan engine
     matches the per-round loop bit-identically (the main nets live in
     tests/test_engine.py; here the interpret-mode kernel rides the scan
@@ -22,9 +24,10 @@ import pytest
 from repro.configs.base import FLConfig
 from repro.core import strategies
 from repro.kernels import ref
-from repro.kernels.ama_mix import ama_mix_flat
 from repro.kernels.server_plane import (server_adam_flat, server_async_flat,
                                         server_mix_flat)
+from repro.models.api import CLASSIFIER_KEYS
+from plain_server import SCHEDULES, PlainServer, schedule
 
 TOL = {jnp.float32: dict(rtol=2e-6, atol=2e-6),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
@@ -102,24 +105,6 @@ def test_server_adam_kernel_matches_oracle(N, block, K, dtype):
     _close(got, want, dtype)
 
 
-@pytest.mark.parametrize("N", [100, 4096 + 17])   # padding / block > N
-@pytest.mark.parametrize("K", [1, 7])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ama_mix_kernel_dtype_parity(N, K, dtype):
-    """The pre-existing fused mix keeps the same dtype/padding contract
-    as the new suite (complements the sweep in test_kernels.py)."""
-    rng = np.random.RandomState(N * K)
-    prev = jnp.asarray(rng.randn(N), dtype)
-    stacked = jnp.asarray(rng.randn(K, N), dtype)
-    alpha = jnp.float32(0.35)
-    wts = jnp.asarray(rng.rand(K), jnp.float32)
-    got = ama_mix_flat(prev, stacked, alpha, wts, block=1024,
-                       interpret=True)
-    want = ref.ama_mix_ref(prev, stacked, alpha, wts)
-    assert got.dtype == prev.dtype and got.shape == (N,)
-    _close(got, want, dtype)
-
-
 def test_mix_empty_round_falls_back_to_prev():
     """keep == 0 for everyone: the whole beta budget reverts to the
     previous model (no NaNs from the 0/0 weight normalisation)."""
@@ -152,45 +137,54 @@ def _sched(rng, C, max_delay=0):
                                      ("fedopt", 0)])
 def test_every_strategy_consistent_across_impls(algo, md):
     """fused == ref bit-identically off-TPU (same dispatch); interpret
-    (the real Pallas body) and legacy (the pre-fusion chain) allclose —
-    params AND aux state (ring buffer, moments)."""
+    (the real Pallas body) allclose — params AND aux state (ring buffer,
+    moments)."""
     rng = np.random.RandomState(42)
     base = dict(algorithm=algo, max_delay=md, p_delay=0.4 if md else 0.0)
     prev, cp = _tree(rng), _tree(rng, C=4)
     sched = _sched(rng, 4, max_delay=md)
     outs = {}
-    for impl in ("fused", "ref", "interpret", "legacy"):
+    for impl in ("fused", "ref", "interpret"):
         s = strategies.resolve(FLConfig(server_plane=impl, **base))
         outs[impl] = s.fused_server_update(2, prev, cp, sched,
                                            s.init_state(prev))
     for g, w in zip(jax.tree.leaves(outs["fused"]),
                     jax.tree.leaves(outs["ref"])):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-    for other in ("interpret", "legacy"):
-        for g, w in zip(jax.tree.leaves(outs["fused"]),
-                        jax.tree.leaves(outs[other])):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-5, atol=1e-6)
+    for g, w in zip(jax.tree.leaves(outs["fused"]),
+                    jax.tree.leaves(outs["interpret"])):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-6)
 
 
-def test_base_strategy_fallback_routes_to_aggregate():
-    """Out-of-tree strategies that only define aggregate() keep working
-    through the fused_server_update entry point."""
-    calls = []
-
-    class Custom(strategies.ServerStrategy):
-        name = "custom-test"
-
-        def aggregate(self, t, prev, cp, sched, aux):
-            calls.append(int(t))
-            return prev, aux
-
-    s = Custom(FLConfig())
-    rng = np.random.RandomState(0)
-    prev = _tree(rng)
-    out, aux = s.fused_server_update(5, prev, _tree(rng, C=3),
-                                     _sched(rng, 3), {})
-    assert calls == [5] and out is prev and aux == {}
+@pytest.mark.parametrize("kind", SCHEDULES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_server_mix_tree_over_the_paper_cnn(dtype, kind):
+    """The sync plane over the paper CNN's own parameter tree: with the
+    body in bf16 and the classifier in f32 the tree is two dtype groups,
+    each flattened to one kernel call (interpret mode) and split back
+    into its leaves; the result is the plain per-leaf AMA update."""
+    from repro.configs.registry import ARCHS
+    from repro.models.api import build_model
+    rng = np.random.RandomState(13)
+    params = build_model(ARCHS["paper-cnn"]).init(jax.random.PRNGKey(0))
+    prev = {k: jax.tree.map(lambda x: x.astype(
+        dtype if k not in CLASSIFIER_KEYS else jnp.float32), v)
+        for k, v in params.items()}
+    cp = jax.tree.map(lambda p: (p.astype(jnp.float32) + jnp.asarray(
+        rng.randn(4, *p.shape) * 0.1, jnp.float32)).astype(p.dtype), prev)
+    sched = schedule(rng, 4, kind)
+    fl = FLConfig(algorithm="ama", alpha0=0.2, eta=0.05,
+                  server_plane="interpret")
+    got, _ = strategies.resolve(fl).fused_server_update(3, prev, cp, sched,
+                                                        {})
+    want = PlainServer(fl).step(3, prev, cp, sched)
+    for g, w, p in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                       jax.tree.leaves(prev)):
+        assert g.dtype == p.dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32), w,
+                                   **TOL[p.dtype.type])
 
 
 # ------------------------------------------------------- engine net ----

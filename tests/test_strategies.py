@@ -1,6 +1,6 @@
-"""Server-strategy subsystem: registry, seed-equivalence of every ported
-strategy, the fedopt extension point, kernel-path parity, and the fused
-scan engine vs the sequential per-round loop."""
+"""Server-strategy subsystem: registry, every rule's server paths against
+its plain statement (tests/plain_server.py), the fedopt extension point,
+and the fused scan engine vs the sequential per-round loop."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,11 +8,10 @@ import pytest
 
 from repro.configs.base import FLConfig
 from repro.configs.registry import ARCHS
-from repro.core import async_ama as aa
 from repro.core import strategies
-from repro.core.ama import ama_aggregate, fedavg_aggregate
 from repro.core.round import init_state, make_round_step, make_train_loop
 from repro.models.api import build_model
+from plain_server import SCHEDULES, PlainServer, schedule
 
 
 def tree(rng, C=None):
@@ -65,61 +64,63 @@ def test_no_dispatch_chains_left():
             assert "fl.algorithm ==" not in f.read(), mod.__name__
 
 
-# ------------------------------------------- seed equivalence per rule ----
+# ------------------------------------ every rule vs its plain statement ----
 
-def test_ama_strategy_matches_seed_aggregate():
-    rng = np.random.RandomState(0)
-    fl = FLConfig(algorithm="ama", alpha0=0.2, eta=1e-3)
-    prev, cp = tree(rng), tree(rng, C=4)
-    sched = sched_for(rng, 4)
-    got, aux = strategies.resolve(fl).aggregate(3, prev, cp, sched, {})
-    want = ama_aggregate(fl, 3, prev, cp, sched["data_sizes"],
-                         jnp.logical_not(sched["delayed"]))
-    assert aux == {}
-    assert_trees_close(got, want)
-
-
-def test_async_ama_strategy_matches_seed_over_rounds():
-    rng = np.random.RandomState(1)
-    fl = FLConfig(algorithm="ama_fes", max_delay=3, p_delay=0.4)
+RULES = [("ama", 0), ("async_ama", 3), ("fedavg", 0), ("fedprox", 0),
+         ("fedopt", 0)]
+@pytest.mark.parametrize("kind", SCHEDULES)
+@pytest.mark.parametrize("algo,md", RULES)
+def test_fused_server_update_matches_plain_reference(algo, md, kind):
+    """Three rounds of each rule's fused server plane against the plain
+    per-leaf statement of the rule (tests/plain_server.py): params stay
+    within f32 rounding of the float64 reference."""
+    rng = np.random.RandomState(11)
+    fl = FLConfig(algorithm=algo, max_delay=md, p_delay=0.4 if md else 0.0,
+                  alpha0=0.2, eta=0.05)
     strat = strategies.resolve(fl)
-    prev_s = prev_m = tree(rng)
-    aux = strat.init_state(prev_s)
-    queue = aa.init_queue(fl, prev_m)
-    for t in range(6):
+    plain = PlainServer(fl)
+    prev = want = tree(rng)
+    aux = strat.init_state(prev)
+    for t in range(3):
         cp = tree(rng, C=4)
-        sched = sched_for(rng, 4, max_delay=3)
-        prev_s, aux = strat.aggregate(t, prev_s, cp, sched, aux)
-        queue = aa.enqueue(fl, queue, t, cp, sched["delayed"],
-                           sched["delays"])
-        prev_m, queue = aa.async_ama_aggregate(
-            fl, t, prev_m, cp, sched["data_sizes"],
-            jnp.logical_not(sched["delayed"]), queue)
-        assert_trees_close(prev_s, prev_m, err_msg=f"round {t}")
-    assert_trees_close(aux["queue"], queue)
+        sched = schedule(rng, 4, kind, max_delay=md)
+        prev, aux = strat.fused_server_update(t, prev, cp, sched, aux)
+        want = plain.step(t, want, cp, sched)
+        assert_trees_close(prev, want, err_msg=f"round {t}")
 
 
-def test_fedavg_strategy_matches_seed_aggregate():
-    rng = np.random.RandomState(2)
-    fl = FLConfig(algorithm="fedavg")
+def _bf16_wire(prev, cp):
+    """What a bf16 uplink delivers: each client's delta rounded to bf16."""
+    return jax.tree.map(
+        lambda p, x: p + (x - p).astype(jnp.bfloat16).astype(jnp.float32),
+        prev, cp)
+
+
+@pytest.mark.parametrize("path", ["fused", "reduced", "compressed"])
+@pytest.mark.parametrize("algo", ["ama", "fedavg", "fedprox"])
+def test_mix_paths_match_plain_reference(algo, path):
+    """Each server path of the mix family — the fused plane, the
+    pre-reduced update the round takes on a sharded client axis
+    (``client_reduce="force"``), and the update that consumes a bf16
+    uplink's payload — against the plain statement of the rule."""
+    from repro import comm
+    rng = np.random.RandomState(12)
+    fl = FLConfig(algorithm=algo, alpha0=0.2, eta=0.05,
+                  client_reduce="force" if path == "reduced" else "off",
+                  comm_plane="bf16" if path == "compressed" else "none")
+    strat = strategies.resolve(fl)
     prev, cp = tree(rng), tree(rng, C=4)
-    sched = sched_for(rng, 4)
-    got, _ = strategies.resolve(fl).aggregate(0, prev, cp, sched, {})
-    keep = jnp.logical_and(jnp.logical_not(sched["delayed"]),
-                           jnp.logical_not(sched["limited"]))
-    want = fedavg_aggregate(prev, cp, sched["data_sizes"], keep)
-    assert_trees_close(got, want)
-
-
-def test_fedprox_strategy_matches_seed_aggregate():
-    rng = np.random.RandomState(3)
-    fl = FLConfig(algorithm="fedprox")
-    prev, cp = tree(rng), tree(rng, C=4)
-    sched = sched_for(rng, 4)
-    got, _ = strategies.resolve(fl).aggregate(0, prev, cp, sched, {})
-    want = fedavg_aggregate(prev, cp, sched["data_sizes"],
-                            jnp.logical_not(sched["delayed"]))
-    assert_trees_close(got, want)
+    sched = schedule(rng, 4, "mixed")
+    if path == "fused":
+        got, _ = strat.fused_server_update(3, prev, cp, sched, {})
+    elif path == "reduced":
+        got, _ = strat.reduced_server_update(3, prev, cp, sched, {})
+    else:
+        plane = comm.resolve(fl)
+        groups, _ = plane.compress(3, prev, cp, plane.init_residual(prev, 4))
+        got, _ = strat.compressed_server_update(3, prev, groups, sched, {})
+        cp = _bf16_wire(prev, cp)
+    assert_trees_close(got, PlainServer(fl).step(3, prev, cp, sched))
 
 
 # ------------------------------------------------ fedopt extension point ----
@@ -131,10 +132,10 @@ def test_fedopt_aggregates_and_carries_moments():
     prev = tree(rng)
     aux = strat.init_state(prev)
     assert int(aux["step"]) == 0
-    p1, aux = strat.aggregate(0, prev, tree(rng, C=4),
-                              sched_for(rng, 4), aux)
-    p2, aux = strat.aggregate(1, p1, tree(rng, C=4),
-                              sched_for(rng, 4), aux)
+    p1, aux = strat.fused_server_update(0, prev, tree(rng, C=4),
+                                        sched_for(rng, 4), aux)
+    p2, aux = strat.fused_server_update(1, p1, tree(rng, C=4),
+                                        sched_for(rng, 4), aux)
     assert int(aux["step"]) == 2
     assert any(float(jnp.max(jnp.abs(l))) > 0
                for l in jax.tree.leaves(aux["m"]))
@@ -157,27 +158,10 @@ def test_fedopt_first_step_is_sign_like_adam():
              "delayed": jnp.zeros((3,), bool),
              "delays": jnp.ones((3,), jnp.int32),
              "data_sizes": jnp.ones((3,), jnp.float32)}
-    p1, _ = strat.aggregate(0, prev, cp, sched, strat.init_state(prev))
+    p1, _ = strat.fused_server_update(0, prev, cp, sched,
+                                      strat.init_state(prev))
     step = jax.tree.map(lambda a, b: np.abs(np.asarray(a - b)), p1, prev)
     assert max(float(s.max()) for s in jax.tree.leaves(step)) <= 0.05 + 1e-6
-
-
-# ----------------------------------------------------- kernel-path parity ----
-
-@pytest.mark.parametrize("algo,md", [("ama", 0), ("ama_fes", 3),
-                                     ("fedavg", 0), ("fedopt", 0)])
-def test_use_kernel_matches_jnp_path(algo, md):
-    rng = np.random.RandomState(6)
-    base = dict(algorithm=algo, max_delay=md, p_delay=0.4 if md else 0.0)
-    fl_j = FLConfig(**base)
-    fl_k = FLConfig(use_kernel=True, **base)
-    prev = tree(rng)
-    cp = tree(rng, C=3)
-    sched = sched_for(rng, 3, max_delay=md)
-    sj, sk = strategies.resolve(fl_j), strategies.resolve(fl_k)
-    got_j, _ = sj.aggregate(2, prev, cp, sched, sj.init_state(prev))
-    got_k, _ = sk.aggregate(2, prev, cp, sched, sk.init_state(prev))
-    assert_trees_close(got_k, got_j)
 
 
 # -------------------------------------------------- fused scan vs loop ----

@@ -21,7 +21,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import server_plane as sp
-from repro.kernels.ama_mix import ama_mix_flat
 
 K, Q = 10, 16
 SIZES = {"paper_cnn": 54_784, "mlp_group": 4096 * 16384}
@@ -61,18 +60,16 @@ def _kernel_args(kernel, N, dtype, S):
         return sp.server_async_flat, (
             S((N,), dtype), S((K, N), dtype), S((Q, N), F32), S((Q,), F32),
             vec, vec, S((K,), jnp.int32), S((2,), jnp.int32), S((4,), F32))
-    if kernel == "adam":
-        return sp.server_adam_flat, (S((N,), dtype), S((K, N), dtype),
-                                     S((N,), F32), S((N,), F32), vec, vec,
-                                     S((5,), F32))
-    assert kernel == "ama_mix"          # the legacy per-leaf mix
-    return ama_mix_flat, (S((N,), dtype), S((K, N), dtype), S((), F32), vec)
+    assert kernel == "adam"
+    return sp.server_adam_flat, (S((N,), dtype), S((K, N), dtype),
+                                 S((N,), F32), S((N,), F32), vec, vec,
+                                 S((5,), F32))
 
 
 @pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("kernel", ["mix", "delta_int8", "delta_same",
-                                    "async", "adam", "ama_mix"])
+                                    "async", "adam"])
 def test_server_plane_kernel_compiles_for_v5e(one_chip, kernel, size,
                                               dtype):
     def S(shape, dt):
