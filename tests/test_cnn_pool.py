@@ -88,9 +88,10 @@ def test_pool_rejects_odd_sizes():
 
 
 def _forward_reduce_window(params, cfg, batch):
-    """``cnn.forward`` as it pooled with ``reduce_window``."""
+    """``cnn.forward`` as it pooled with ``reduce_window``, with the
+    same convolutions, so that only the pooling differs."""
     x = batch["image"].astype(jnp.float32)
-    x = jax.nn.relu(cnn._conv(x, params["body"]["conv1"]["w"]))
+    x = jax.nn.relu(cnn.conv_valid(x, params["body"]["conv1"]["w"]))
     x = _pool_reduce_window(x)
     x = jax.nn.relu(cnn._conv(x, params["body"]["conv2"]["w"]))
     x = _pool_reduce_window(x)

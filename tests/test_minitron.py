@@ -10,6 +10,7 @@ each gradient leaf's norm; 1e-5 leaves that room tenfold.
 """
 import hashlib
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -218,8 +219,8 @@ def test_reference_forward_matches_transformers_nemotron():
 #: so keeping masters in another dtype must leave it as it was before
 #: master weights existed. A deliberate change to that program updates
 #: this digest.
-CNN_ROUND_SHA256 = ("5fe64227927067218a1026d54e9f7084"
-                    "359b811f6955c960d5c9185ada32525b")
+CNN_ROUND_SHA256 = ("b295ea408e6a4b55c3a118b4679be893"
+                    "bb0be499ea30e2422f4b5075069b7847")
 
 
 def _cnn_round_text() -> str:
@@ -289,3 +290,24 @@ def test_paper_cnn_round_program_pools_without_window_ops():
     text = _cnn_round_text()
     assert "reduce_window" not in text
     assert "select_and_scatter" not in text
+
+
+#: a convolution op of the lowered text whose result is a 5x5x1x... f32
+#: tensor: conv1's filter gradient under the clients' vmap
+CONV1_FILTER_GRAD = re.compile(
+    r"stablehlo\.convolution.*-> tensor<5x5x1x\d+xf32>")
+#: a precision of a dot or a convolution, as the lowered text names it
+PRECISION = re.compile(r"\b(DEFAULT|HIGHEST|HIGH)\b")
+
+
+def test_paper_cnn_round_program_takes_conv1_filter_grad_as_a_dot():
+    """conv1's filter gradient is a batched dot of the images' row
+    slices (``models/cnn.py`` ``conv_valid``), not the grouped
+    convolution that autodiff of the vmapped convolution gives: the
+    round keeps the two forward convolutions and conv2's two gradients,
+    all at the default precision, as before."""
+    text = _cnn_round_text()
+    assert not CONV1_FILTER_GRAD.search(text)
+    assert text.count("stablehlo.convolution") == 4
+    assert "bf16" not in text
+    assert set(PRECISION.findall(text)) == {"DEFAULT"}
